@@ -164,7 +164,7 @@ def run_refresh_sharding() -> None:
     from repro.core.precondition import kfac_pi_damping
     from repro.schedule import ownership
     from repro.schedule import runtime as schedrt
-    from repro.sharding import compat
+    from repro.launch.mesh import make_mesh
 
     cfg = _bench_config()
     model = build_model(cfg)
@@ -201,15 +201,15 @@ def run_refresh_sharding() -> None:
     if jax.device_count() < 2:
         raise SystemExit('refresh-sharding cell needs multiple host devices '
                          f'(got {jax.device_count()}; check XLA_FLAGS)')
-    mesh = compat.make_mesh((jax.device_count(),), ('data',))
+    mesh = make_mesh((jax.device_count(),), ('data',))
 
     def refresh(shard, comm=None):
         def body(s, o):
             return schedrt.sharded_refresh(
                 plan, jnp.asarray(True), one, s, o,
                 cost=ownership.inverse_cost('both'), shard=shard, comm=comm)
-        return jax.jit(compat.shard_map(body, mesh=mesh, in_specs=(P(), P()),
-                                        out_specs=P(), check=False))
+        return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(), P()),
+                                     out_specs=P(), check_vma=False))
 
     t_red = time_fn(refresh(False), stats, old)
     t_shard = time_fn(refresh(True), stats, old)           # default: gather
@@ -263,12 +263,12 @@ def run_factor_sharding() -> None:
 
     from repro.core import factor_sharded as fsh
     from repro.core.precondition import kfac_pi_damping
-    from repro.sharding import compat
+    from repro.launch.mesh import make_mesh
 
     if jax.device_count() < 2:
         raise SystemExit('factor-sharding cell needs multiple host devices '
                          f'(got {jax.device_count()}; check XLA_FLAGS)')
-    mesh = compat.make_mesh((jax.device_count(),), ('data',))
+    mesh = make_mesh((jax.device_count(),), ('data',))
     world = jax.device_count()
 
     key = jax.random.PRNGKey(0)
@@ -288,8 +288,8 @@ def run_factor_sharding() -> None:
     gamma = 0.03
 
     def smap(body):
-        return jax.jit(compat.shard_map(body, mesh=mesh, in_specs=(P(),),
-                                        out_specs=P(), check=False))
+        return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(),),
+                                     out_specs=P(), check_vma=False))
 
     def sharded(method, power, solver, iters):
         cfg = fsh.FactorShardConfig(head_policy='shard', shard_threshold=256,
@@ -375,13 +375,13 @@ def run_pipeline(check_overlap: bool = False) -> None:
 
     from repro.launch import hlo_analysis
     from repro.schedule.runtime import RefreshRuntime
-    from repro.sharding import compat
+    from repro.launch.mesh import make_mesh
     from repro.train.compression import make_dp_train_step
 
     if jax.device_count() < 2:
         raise SystemExit('pipeline cell needs multiple host devices '
                          f'(got {jax.device_count()}; check XLA_FLAGS)')
-    mesh = compat.make_mesh((jax.device_count(),), ('data',))
+    mesh = make_mesh((jax.device_count(),), ('data',))
     world = jax.device_count()
 
     cases = []

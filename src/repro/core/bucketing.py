@@ -134,3 +134,15 @@ def is_bucketed(plan: BucketPlan, mapping: Mapping[str, Any]) -> bool:
     gathered) rather than by parameter paths."""
     keys = {b.key for b in plan.buckets}
     return bool(mapping) and set(mapping) <= keys
+
+
+def kernel_shapes(plan: BucketPlan) -> list[tuple[int, int, int]]:
+    """``(L, d_in, d_out)`` of each bucket as one kernel launch sees it: the
+    stack axis and any leading (scan) dims folded into L."""
+    out = []
+    for b in plan.buckets:
+        lead = len(b.paths)
+        for d in b.shape[:-2]:
+            lead *= d
+        out.append((lead,) + tuple(b.shape[-2:]))
+    return out

@@ -207,8 +207,7 @@ def precondition_tree(updates: dict, aux: dict, method: str, gamma: float, *,
     bit-identical to per-item calls of the same form, but the stacked
     (compiled scan body) and unstacked (eager) paths may differ in the
     last ulp, so across *different* thresholds they only agree to float
-    tolerance (see tests/test_bucketing.py).  Runs on jax 0.4.37 through
-    current jax — mesh interaction goes through ``repro.sharding.compat``.
+    tolerance (see tests/test_bucketing.py).
     """
     from repro.core import bucketing
 

@@ -20,8 +20,26 @@ import jax.numpy as jnp
 import numpy as np
 
 
+def _entropy(p: np.ndarray) -> np.ndarray:
+    """Elementwise -p log p (0 at p = 0)."""
+    return -p * np.log(np.maximum(p, 1e-12))
+
+
 @dataclasses.dataclass
 class LMStream:
+    """Bigram-chain token stream.  Each token has ``min(vocab, SUCCESSORS)``
+    possible next tokens with peaky random probabilities; at or below
+    ``SUCCESSORS`` that is the dense (vocab, vocab) chain.
+
+    Above it a (vocab, SUCCESSORS) table of uniformly drawn successors keeps
+    the host memory O(vocab) — a 151936-token vocab would need a 185 GB
+    dense table.  Such a chain alone has a flat unigram, which no text has
+    and which leaves nothing to learn in a few steps, so there each next
+    token is, with probability ``ZIPF_MIX``, drawn from a Zipf(1) unigram
+    over the token ids instead."""
+    SUCCESSORS = 512
+    ZIPF_MIX = 0.5
+
     vocab: int
     seq_len: int
     batch: int
@@ -30,20 +48,35 @@ class LMStream:
 
     def __post_init__(self):
         rng = np.random.default_rng(self.seed)
-        logits = rng.gumbel(size=(self.vocab, self.vocab)) / self.concentration
-        self._probs = np.exp(logits - logits.max(-1, keepdims=True))
-        self._probs /= self._probs.sum(-1, keepdims=True)
-        self._cum = np.cumsum(self._probs, axis=-1)
+        k = min(self.vocab, self.SUCCESSORS)
+        logits = rng.gumbel(size=(self.vocab, k)) / self.concentration
+        probs = np.exp(logits - logits.max(-1, keepdims=True))
+        probs /= probs.sum(-1, keepdims=True)
+        self._cum = np.cumsum(probs, axis=-1)
+        self._succ = None if k == self.vocab else \
+            rng.integers(0, self.vocab, (self.vocab, k)).astype(np.int32)
 
     def batch_at(self, step: int) -> dict:
         rng = np.random.default_rng((self.seed, step))
         toks = np.empty((self.batch, self.seq_len + 1), np.int32)
         toks[:, 0] = rng.integers(0, self.vocab, self.batch)
         u = rng.random((self.batch, self.seq_len))
+        if self._succ is not None:
+            # exp(U ln V) has density 1/(x ln V) on [1, V): its floor - 1
+            # is Zipf(1) over the ids 0..V-2
+            zipf = np.exp(rng.random((self.batch, self.seq_len))
+                          * np.log(self.vocab)).astype(np.int32) - 1
+            from_zipf = rng.random((self.batch, self.seq_len)) \
+                < self.ZIPF_MIX
         # vectorized bigram sampling: invert the per-row CDF
         for t in range(self.seq_len):
-            rows = self._cum[toks[:, t]]                   # (B, V)
-            toks[:, t + 1] = (rows < u[:, t:t + 1]).sum(-1)
+            prev = toks[:, t]
+            idx = (self._cum[prev] < u[:, t:t + 1]).sum(-1)
+            if self._succ is None:
+                toks[:, t + 1] = idx
+            else:
+                toks[:, t + 1] = np.where(from_zipf[:, t], zipf[:, t],
+                                          self._succ[prev, idx])
         return {'tokens': jnp.asarray(toks[:, :-1]),
                 'labels': jnp.asarray(toks[:, 1:])}
 
@@ -60,9 +93,19 @@ class LMStream:
     @property
     def bigram_ce(self) -> float:
         """Entropy of the generating chain — the achievable CE floor."""
-        p = self._probs
-        h = -(p * np.log(np.maximum(p, 1e-12))).sum(-1)
-        return float(h.mean())
+        probs = np.diff(self._cum, axis=-1, prepend=0.0)
+        if self._succ is None:
+            return float(_entropy(probs).sum(-1).mean())
+        # each row is the mixture q z + (1-q) p, z the Zipf unigram: the
+        # q z terms off the row's successors plus the mixed ones on them
+        # (a successor drawn twice counts as two: a slight overestimate)
+        q = self.ZIPF_MIX
+        z = np.log1p(1.0 / np.arange(1, self.vocab + 1)) / np.log(self.vocab)
+        z[-1] = 0.0                      # id V-1 is never drawn
+        zs = q * z[self._succ]
+        rows = (_entropy(q * z).sum() - _entropy(zs).sum(-1)
+                + _entropy(zs + (1.0 - q) * probs).sum(-1))
+        return float(rows.mean())
 
 
 @dataclasses.dataclass

@@ -37,7 +37,7 @@ from repro.kernels import matvec as _mv
 from repro.kernels import rank1_update as _r1
 from repro.kernels import ref
 from repro.kernels.dispatch import DEFAULT_BLOCK, backend, cache_key
-from repro.kernels.tiles import fit_block
+from repro.kernels.tiles import fit_tiles
 
 OPS = ('bilinear', 'matvec', 'rank1_update')
 FUSED_OPS = ('eva_fused', 'eva_f_fused')
@@ -101,7 +101,8 @@ def _candidate_fn(op: str, impl: str, g, a, b, m, bm: int, bn: int,
     return lambda: jax.block_until_ready(jitted())
 
 
-def _candidates(op: str, d_in: int, d_out: int, grid, impls):
+def _candidates(op: str, d_in: int, d_out: int, grid, impls,
+                itemsize: int = 4, interpret: bool = True):
     """Fixed-order (impl, block_in, block_out) list; fitted duplicates
     collapse to the first occurrence so the sweep stays deterministic."""
     seen, out = set(), []
@@ -111,7 +112,7 @@ def _candidates(op: str, d_in: int, d_out: int, grid, impls):
         else:
             pairs = grid
         for bi, bo in pairs:
-            bm, bn = fit_block(d_in, bi), fit_block(d_out, bo)
+            bm, bn = fit_tiles(d_in, d_out, bi, bo, itemsize, interpret)
             key = (impl, bm, bn)
             if key not in seen:
                 seen.add(key)
@@ -138,7 +139,8 @@ def tune(shapes: Iterable[tuple[int, int]], *, ops=OPS,
                 g, a, b, m = _operands(op, d_in, d_out, dt)
                 best = None
                 for impl, bm, bn in _candidates(op, d_in, d_out, grid,
-                                                impls):
+                                                impls, dt.itemsize,
+                                                interpret):
                     fn = _candidate_fn(op, impl, g, a, b, m, bm, bn,
                                        interpret)
                     us = float(bench(fn))

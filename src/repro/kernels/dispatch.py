@@ -25,8 +25,9 @@ Per-call overrides thread through ``Extras.kernel`` (a ``KernelConfig``)
 or the explicit ``impl=`` argument on each wrapper.
 
 Tile sizes come from the autotune cache (``kernels/autotune.py``; shipped
-defaults in ``tile_defaults.json`` warm-start it), falling back to the
-waste-aware ``tiles.fit_block`` clamp of the 512-tile default.  Every
+defaults in ``tile_defaults.json`` warm-start it), else the 512-tile
+default, fitted to the shape (and, compiled, to the TPU tiling) by
+``tiles.fit_tiles``.  Every
 resolution is recorded and exposed via ``choices_snapshot()`` so the
 trainer can emit the chosen impl + tiles as optional obs fields.
 """
@@ -71,7 +72,7 @@ class Choice:
     """One resolved dispatch decision."""
     impl: str            # 'pallas' | 'xla'
     interpret: bool      # meaningful only for impl='pallas'
-    block_in: int
+    block_in: int        # requested blocks (kernels fit them per shape)
     block_out: int
 
 
@@ -174,8 +175,9 @@ def resolve(op: str, d_in: int, d_out: int, dtype,
 
     Order: explicit ``impl`` arg > process default; ``'auto'`` consults the
     autotune cache for this (backend, op, shape, dtype) and falls back to
-    the backend rule (TPU -> pallas, else xla).  Tiles: cache entry, else
-    the waste-aware clamp of the 512 default.
+    the backend rule (TPU -> pallas, else xla).  Blocks: cache entry, else
+    the 512 default — the requested sizes, which each kernel fits to the
+    shape and (compiled) to the TPU tiling via ``tiles.fit_tiles``.
     """
     req = impl or _state['impl']
     _check_impl(req)
@@ -189,13 +191,14 @@ def resolve(op: str, d_in: int, d_out: int, dtype,
         else backend() != 'tpu'
     if concrete == 'pallas_interpret':
         concrete = 'pallas'
-    align = 8 if (concrete == 'pallas' and not interpret) else 1
-    bm = tiles.fit_block(d_in, int(entry.get('block_in', DEFAULT_BLOCK)),
-                         align)
-    bn = tiles.fit_block(d_out, int(entry.get('block_out', DEFAULT_BLOCK)),
-                         align)
+    block_in = int(entry.get('block_in', DEFAULT_BLOCK))
+    block_out = int(entry.get('block_out', DEFAULT_BLOCK))
+    # the kernels fit the requested blocks themselves (same rule), so the
+    # recorded tiles are the ones that run
+    bm, bn = tiles.fit_tiles(d_in, d_out, block_in, block_out,
+                             jnp.dtype(dtype).itemsize, interpret)
     choice = Choice(impl=concrete, interpret=interpret,
-                    block_in=bm, block_out=bn)
+                    block_in=block_in, block_out=block_out)
     label = concrete + ('/interpret' if concrete == 'pallas' and interpret
                         else '')
     _choices[op] = f'{label} {bm}x{bn} @ {d_in}x{d_out}'

@@ -29,7 +29,11 @@ sequential per core, so every phase-0 tile of a stack item completes before
 its phase-1 tiles read the reduction back.  G is read twice from HBM — the
 reduction output is far too small to carry tile partials for a one-read
 formulation — so the win over the composed path is the dropped P/m/vdot
-round trips, not the G reads.
+round trips, not the G reads.  The reduction lives in VMEM scratch (a
+revisited *output* block is not re-read from HBM on the TPU), the per-item
+scalars in SMEM, and ``aux`` in lanes 0..2 of a (1, 128) block; the output
+and momentum blocks stay parked on tile (0, 0) through phase 0, so phase 0
+neither fetches m nor writes back an untouched output tile.
 
 "Bit-identical" above holds per tile formula; across a whole launch the
 in-kernel coeff division (``dot/denom``) can contract differently from the
@@ -44,24 +48,29 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.bilinear import _tile_bilinear
+from repro.kernels.bilinear import _tile_bilinear, as_col, as_row, pad_mat
 from repro.kernels.matvec import _tile_matvec
-from repro.kernels.rank1_update import _rank1_tile
-from repro.kernels.tiles import fit_block
+from repro.kernels.rank1_update import SMEM_SPEC, _rank1_tile
+from repro.kernels.tiles import LANE, fit_tiles
 
 
 def _epilogue_tile(g, p, m, mu, fold, o_ref, aux_ref):
-    """Shared phase-1 tail: momentum fold + output write + aux partials."""
+    """Shared phase-1 tail: momentum fold + output write + aux partials
+    accumulated into lanes 0..2 of the item's (1, 128) aux block."""
     out = mu * m + p if fold else p
     o_ref[0] = out
-    aux_ref[0, 0] += jnp.sum(out * g)
-    aux_ref[0, 1] += jnp.sum(out * out)
-    aux_ref[0, 2] += jnp.sum(g * g)
+    lane = jax.lax.broadcasted_iota(jnp.int32, aux_ref.shape[1:], 1)
+    sums = (jnp.sum(out * g), jnp.sum(out * out), jnp.sum(g * g))
+    aux_ref[0] += jnp.where(lane == 0, sums[0],
+                            jnp.where(lane == 1, sums[1],
+                                      jnp.where(lane == 2, sums[2], 0.0)))
 
 
 def _make_eva_fused_kernel(fold: bool):
-    def kernel(g_ref, a_ref, b_ref, sc_ref, m_ref, o_ref, dot_ref, aux_ref):
+    def kernel(g_ref, a_ref, b_ref, sc_ref, m_ref, o_ref, aux_ref, dot_ref):
+        l = pl.program_id(0)
         ph = pl.program_id(1)
         i = pl.program_id(2)
         j = pl.program_id(3)
@@ -72,68 +81,81 @@ def _make_eva_fused_kernel(fold: bool):
             aux_ref[...] = jnp.zeros_like(aux_ref)
 
         g = g_ref[0].astype(jnp.float32)
-        a = a_ref[0].astype(jnp.float32)
-        b = b_ref[0].astype(jnp.float32)
+        a = a_ref[0]
+        b = b_ref[0]
 
         @pl.when(ph == 0)
         def _reduce():
-            dot_ref[0, 0] += _tile_bilinear(g, a, b)
+            dot_ref[...] += _tile_bilinear(g, a, b)
 
         @pl.when(ph == 1)
         def _emit():
-            denom = sc_ref[0, 0]
-            scale = sc_ref[0, 1]
-            mu = sc_ref[0, 2]
-            p = _rank1_tile(g, a, b, dot_ref[0, 0] / denom, scale)
-            _epilogue_tile(g, p, m_ref[0], mu, fold, o_ref, aux_ref)
+            coeff = dot_ref[:, :1] / sc_ref[l, 0]
+            p = _rank1_tile(g, a, b, coeff, sc_ref[l, 1])
+            _epilogue_tile(g, p, m_ref[0], sc_ref[l, 2], fold, o_ref,
+                           aux_ref)
 
     return kernel
 
 
-def _make_eva_f_fused_kernel(fold: bool):
-    def kernel(g_ref, a_ref, sc_ref, m_ref, o_ref, u_ref, aux_ref):
+def _make_eva_f_fused_kernel(fold: bool, bn: int, n_col_blocks: int):
+    def u_block(u_ref, j):
+        if n_col_blocks == 1:
+            return u_ref
+        return u_ref.at[:, pl.ds(pl.multiple_of(j * bn, bn), bn)]
+
+    def kernel(g_ref, a_ref, sc_ref, m_ref, o_ref, aux_ref, u_ref):
+        l = pl.program_id(0)
         ph = pl.program_id(1)
         j = pl.program_id(2)
         i = pl.program_id(3)
 
-        # u_ref's block follows j, so each column block zeroes at the start
-        # of ITS reduction; aux_ref is one shared block per stack item
-        @pl.when((ph == 0) & (i == 0))
-        def _init_u():
-            u_ref[...] = jnp.zeros_like(u_ref)
-
         @pl.when((ph == 0) & (j == 0) & (i == 0))
-        def _init_aux():
+        def _init():
+            u_ref[...] = jnp.zeros_like(u_ref)
             aux_ref[...] = jnp.zeros_like(aux_ref)
 
         g = g_ref[0].astype(jnp.float32)
-        a = a_ref[0].astype(jnp.float32)
+        a = a_ref[0]
+        u = u_block(u_ref, j)
 
         @pl.when(ph == 0)
         def _reduce():
-            u_ref[0] += _tile_matvec(g, a)
+            u[...] += _tile_matvec(g, a)
 
         @pl.when(ph == 1)
         def _emit():
-            denom = sc_ref[0, 0]
-            scale = sc_ref[0, 1]
-            mu = sc_ref[0, 2]
-            p = _rank1_tile(g, a, u_ref[0], 1.0 / denom, scale)
-            _epilogue_tile(g, p, m_ref[0], mu, fold, o_ref, aux_ref)
+            p = _rank1_tile(g, a, u[...], 1.0 / sc_ref[l, 0], sc_ref[l, 1])
+            _epilogue_tile(g, p, m_ref[0], sc_ref[l, 2], fold, o_ref,
+                           aux_ref)
 
     return kernel
 
 
-def _pad_stacked(g, vecs_in, vecs_out, m, bm, bn):
+def _scalars(denom, gamma, mu):
+    """(L, 3) SMEM operand: [denom, 1/γ, μ] per stack item."""
+    L = denom.shape[0]
+    return jnp.stack([denom,
+                      jnp.full((L,), 1.0 / gamma, jnp.float32),
+                      jnp.full((L,), mu, jnp.float32)], axis=-1)
+
+
+def _prep(g, m, block_in, block_out, interpret):
     d_in, d_out = g.shape[1:]
-    pad_in = (-d_in) % bm
-    pad_out = (-d_out) % bn
-    if pad_in or pad_out:
-        g = jnp.pad(g, ((0, 0), (0, pad_in), (0, pad_out)))
-        m = jnp.pad(m, ((0, 0), (0, pad_in), (0, pad_out)))
-        vecs_in = [jnp.pad(v, ((0, 0), (0, pad_in))) for v in vecs_in]
-        vecs_out = [jnp.pad(v, ((0, 0), (0, pad_out))) for v in vecs_out]
-    return g, vecs_in, vecs_out, m, (d_in, d_out)
+    bm, bn = fit_tiles(d_in, d_out, block_in, block_out, g.dtype.itemsize,
+                       interpret)
+    pad_in, pad_out = (-d_in) % bm, (-d_out) % bn
+    return (pad_mat(g, pad_in, pad_out),
+            pad_mat(m.astype(jnp.float32), pad_in, pad_out),
+            bm, bn, pad_in, pad_out)
+
+
+def _tile_spec(bm, bn, phase_axis):
+    """G-shaped block that parks on tile (0, 0) while phase 0 runs
+    (``phase_axis`` picks which of the two inner grid indices is i)."""
+    if phase_axis == 'ij':
+        return pl.BlockSpec((1, bm, bn), lambda l, p, i, j: (l, i * p, j * p))
+    return pl.BlockSpec((1, bm, bn), lambda l, p, j, i: (l, i * p, j * p))
 
 
 @functools.partial(jax.jit, static_argnames=('gamma', 'mu', 'fold_momentum',
@@ -149,43 +171,38 @@ def eva_fused_stacked(g, a, b, gamma: float, m, mu: float,
     Returns ``(out, aux)``: out (L, d_in, d_out) f32 = μ·m + P (P only when
     ``fold_momentum=False``); aux (L, 3) f32 = [⟨out,g⟩, ⟨out,out⟩, ⟨g,g⟩].
     """
-    L = g.shape[0]
+    L, d_in, d_out = g.shape
     a32 = a.astype(jnp.float32)
     b32 = b.astype(jnp.float32)
-    denom = gamma + jnp.sum(a32 * a32, -1) * jnp.sum(b32 * b32, -1)
-    sc = jnp.stack([denom,
-                    jnp.full((L,), 1.0 / gamma, jnp.float32),
-                    jnp.full((L,), mu, jnp.float32)], axis=-1)
-    bm = fit_block(g.shape[1], block_in)
-    bn = fit_block(g.shape[2], block_out)
-    g, (a32,), (b32,), m, (d_in, d_out) = _pad_stacked(
-        g, [a32], [b32], m.astype(jnp.float32), bm, bn)
+    sc = _scalars(gamma + jnp.sum(a32 * a32, -1) * jnp.sum(b32 * b32, -1),
+                  gamma, mu)
+    g, m, bm, bn, pad_in, pad_out = _prep(g, m, block_in, block_out,
+                                          interpret)
     mp, np_ = g.shape[1:]
-    out, _, aux = pl.pallas_call(
+    out, aux = pl.pallas_call(
         _make_eva_fused_kernel(fold_momentum),
         grid=(L, 2, mp // bm, np_ // bn),
         in_specs=[
             pl.BlockSpec((1, bm, bn), lambda l, p, i, j: (l, i, j)),
-            pl.BlockSpec((1, bm), lambda l, p, i, j: (l, i)),
-            pl.BlockSpec((1, bn), lambda l, p, i, j: (l, j)),
-            pl.BlockSpec((1, 3), lambda l, p, i, j: (l, 0)),
-            pl.BlockSpec((1, bm, bn), lambda l, p, i, j: (l, i, j)),
+            pl.BlockSpec((1, bm, 1), lambda l, p, i, j: (l, i, 0)),
+            pl.BlockSpec((1, 1, bn), lambda l, p, i, j: (l, 0, j)),
+            SMEM_SPEC,
+            _tile_spec(bm, bn, 'ij'),
         ],
         out_specs=[
-            pl.BlockSpec((1, bm, bn), lambda l, p, i, j: (l, i, j)),
-            pl.BlockSpec((1, 1), lambda l, p, i, j: (l, 0)),
-            pl.BlockSpec((1, 3), lambda l, p, i, j: (l, 0)),
+            _tile_spec(bm, bn, 'ij'),
+            pl.BlockSpec((1, 1, LANE), lambda l, p, i, j: (l, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((L, mp, np_), jnp.float32),
-            jax.ShapeDtypeStruct((L, 1), jnp.float32),
-            jax.ShapeDtypeStruct((L, 3), jnp.float32),
+            jax.ShapeDtypeStruct((L, 1, LANE), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((1, LANE), jnp.float32)],
         interpret=interpret,
-    )(g, a32, b32, sc, m)
+    )(g, as_col(a32, pad_in), as_row(b32, pad_out), sc, m)
     if (mp, np_) != (d_in, d_out):
         out = out[:, :d_in, :d_out]
-    return out, aux
+    return out, aux[:, 0, :3]
 
 
 @functools.partial(jax.jit, static_argnames=('gamma', 'mu', 'fold_momentum',
@@ -196,39 +213,34 @@ def eva_f_fused_stacked(g, a, gamma: float, m, mu: float,
                         block_in: int = 512, block_out: int = 512,
                         interpret: bool = True):
     """Fused Eva-f (Eq. 21) + epilogue; same contract as
-    :func:`eva_fused_stacked` with u = aᵀG accumulated in phase 0."""
-    L = g.shape[0]
+    :func:`eva_fused_stacked` with u = aᵀG accumulated in phase 0 into a
+    full-width VMEM row (each column block revisits its slice in phase 1)."""
+    L, d_in, d_out = g.shape
     a32 = a.astype(jnp.float32)
-    denom = gamma + jnp.sum(a32 * a32, -1)
-    sc = jnp.stack([denom,
-                    jnp.full((L,), 1.0 / gamma, jnp.float32),
-                    jnp.full((L,), mu, jnp.float32)], axis=-1)
-    bm = fit_block(g.shape[1], block_in)
-    bn = fit_block(g.shape[2], block_out)
-    g, (a32,), _, m, (d_in, d_out) = _pad_stacked(
-        g, [a32], [], m.astype(jnp.float32), bm, bn)
+    sc = _scalars(gamma + jnp.sum(a32 * a32, -1), gamma, mu)
+    g, m, bm, bn, pad_in, pad_out = _prep(g, m, block_in, block_out,
+                                          interpret)
     mp, np_ = g.shape[1:]
-    out, _, aux = pl.pallas_call(
-        _make_eva_f_fused_kernel(fold_momentum),
+    out, aux = pl.pallas_call(
+        _make_eva_f_fused_kernel(fold_momentum, bn, np_ // bn),
         grid=(L, 2, np_ // bn, mp // bm),
         in_specs=[
             pl.BlockSpec((1, bm, bn), lambda l, p, j, i: (l, i, j)),
-            pl.BlockSpec((1, bm), lambda l, p, j, i: (l, i)),
-            pl.BlockSpec((1, 3), lambda l, p, j, i: (l, 0)),
-            pl.BlockSpec((1, bm, bn), lambda l, p, j, i: (l, i, j)),
+            pl.BlockSpec((1, bm, 1), lambda l, p, j, i: (l, i, 0)),
+            SMEM_SPEC,
+            _tile_spec(bm, bn, 'ji'),
         ],
         out_specs=[
-            pl.BlockSpec((1, bm, bn), lambda l, p, j, i: (l, i, j)),
-            pl.BlockSpec((1, bn), lambda l, p, j, i: (l, j)),
-            pl.BlockSpec((1, 3), lambda l, p, j, i: (l, 0)),
+            _tile_spec(bm, bn, 'ji'),
+            pl.BlockSpec((1, 1, LANE), lambda l, p, j, i: (l, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((L, mp, np_), jnp.float32),
-            jax.ShapeDtypeStruct((L, np_), jnp.float32),
-            jax.ShapeDtypeStruct((L, 3), jnp.float32),
+            jax.ShapeDtypeStruct((L, 1, LANE), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((1, np_), jnp.float32)],
         interpret=interpret,
-    )(g, a32, sc, m)
+    )(g, as_col(a32, pad_in), sc, m)
     if (mp, np_) != (d_in, d_out):
         out = out[:, :d_in, :d_out]
-    return out, aux
+    return out, aux[:, 0, :3]

@@ -5,13 +5,13 @@ b̄ᵀGā = u·b̄).  Memory-bound: each G tile is read once; partial products
 accumulate in the f32 VMEM output block across the reduction grid axis
 (TPU grid iterations are sequential, so the j-major accumulation is safe).
 
-Tiles are 128-aligned for the 8×128 VPU; the (bm × bn) G tile multiplies a
-(bm,) a-slice and accumulates into a (bn,) output slice.  The tile product
-is an elementwise multiply + axis reduction (not ``a @ g``) so the lowering
-— and therefore the accumulation order — is identical inside and outside
-grid loops; this is what lets ``matvec_stacked`` (stack folded into the
-leading grid axis, one launch per parameter bucket) match per-item calls
-bit-for-bit.
+The (bm × bn) G tile multiplies a (bm, 1) column slice of ``a`` and
+accumulates into a (1, bn) output row — blocks whose last two dims Mosaic
+accepts (see ``tiles.fit_tiles``).  The tile product is an elementwise
+multiply + axis reduction (not ``a @ g``) so the lowering — and therefore
+the accumulation order — is identical inside and outside grid loops; this
+is what lets ``matvec_stacked`` (stack folded into the leading grid axis,
+one launch per parameter bucket) match per-item calls bit-for-bit.
 """
 from __future__ import annotations
 
@@ -21,12 +21,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.tiles import fit_block
+from repro.kernels.bilinear import as_col, pad_mat
+from repro.kernels.tiles import fit_tiles
 
 
-def _tile_matvec(g, a):
-    """(bm, bn) tile × (bm,) slice -> (bn,) partial products, f32."""
-    return jnp.sum(a[:, None] * g, axis=0)
+def _tile_matvec(g, a_col):
+    """(bm, bn) tile × (bm, 1) slice -> (1, bn) partial products, f32."""
+    return jnp.sum(a_col * g, axis=0, keepdims=True)
 
 
 def _matvec_kernel(g_ref, a_ref, o_ref):
@@ -37,8 +38,7 @@ def _matvec_kernel(g_ref, a_ref, o_ref):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     g = g_ref[...].astype(jnp.float32)
-    a = a_ref[...].astype(jnp.float32)
-    o_ref[...] += _tile_matvec(g, a)
+    o_ref[...] += _tile_matvec(g, a_ref[...])
 
 
 def _matvec_stacked_kernel(g_ref, a_ref, o_ref):
@@ -49,21 +49,23 @@ def _matvec_stacked_kernel(g_ref, a_ref, o_ref):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     g = g_ref[0].astype(jnp.float32)
-    a = a_ref[0].astype(jnp.float32)
-    o_ref[0] += _tile_matvec(g, a)
+    o_ref[0] += _tile_matvec(g, a_ref[0])
+
+
+def _prep(g, a, block_in, block_out, interpret):
+    d_in, d_out = g.shape[-2:]
+    bm, bn = fit_tiles(d_in, d_out, block_in, block_out, g.dtype.itemsize,
+                       interpret)
+    pad_in, pad_out = (-d_in) % bm, (-d_out) % bn
+    return pad_mat(g, pad_in, pad_out), as_col(a, pad_in), bm, bn
 
 
 @functools.partial(jax.jit, static_argnames=('block_in', 'block_out', 'interpret'))
 def matvec(g: jnp.ndarray, a: jnp.ndarray, block_in: int = 512,
            block_out: int = 512, interpret: bool = True) -> jnp.ndarray:
     """u = aᵀ G.  g: (d_in, d_out); a: (d_in,) -> (d_out,) f32."""
-    d_in, d_out = g.shape
-    bm, bn = fit_block(d_in, block_in), fit_block(d_out, block_out)
-    pad_in = (-d_in) % bm
-    pad_out = (-d_out) % bn
-    if pad_in or pad_out:
-        g = jnp.pad(g, ((0, pad_in), (0, pad_out)))
-        a = jnp.pad(a, (0, pad_in))
+    d_out = g.shape[1]
+    g, a, bm, bn = _prep(g, a, block_in, block_out, interpret)
     m, n = g.shape
     out = pl.pallas_call(
         _matvec_kernel,
@@ -71,13 +73,35 @@ def matvec(g: jnp.ndarray, a: jnp.ndarray, block_in: int = 512,
         grid=(n // bn, m // bm),
         in_specs=[
             pl.BlockSpec((bm, bn), lambda j, i: (i, j)),
-            pl.BlockSpec((bm,), lambda j, i: (i,)),
+            pl.BlockSpec((bm, 1), lambda j, i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((bn,), lambda j, i: (j,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
+        out_specs=pl.BlockSpec((1, bn), lambda j, i: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
         interpret=interpret,
-    )(g, a.astype(jnp.float32))
-    return out[:d_out] if pad_out else out
+    )(g, a)
+    return out[0, :d_out]
+
+
+@functools.partial(jax.jit, static_argnames=('block_in', 'block_out', 'interpret'))
+def matvec_stacked(g: jnp.ndarray, a: jnp.ndarray, block_in: int = 512,
+                   block_out: int = 512, interpret: bool = True) -> jnp.ndarray:
+    """Stacked u = aᵀ G.  g: (L, d_in, d_out); a: (L, d_in) -> (L, d_out)
+    f32.  One launch; the stack rides the leading grid axis."""
+    d_out = g.shape[2]
+    g, a, bm, bn = _prep(g, a, block_in, block_out, interpret)
+    L, m, n = g.shape
+    out = pl.pallas_call(
+        _matvec_stacked_kernel,
+        grid=(L, n // bn, m // bm),
+        in_specs=[
+            pl.BlockSpec((1, bm, bn), lambda l, j, i: (l, i, j)),
+            pl.BlockSpec((1, bm, 1), lambda l, j, i: (l, i, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, 1, bn), lambda l, j, i: (l, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((L, 1, n), jnp.float32),
+        interpret=interpret,
+    )(g, a)
+    return out[:, 0, :d_out]
 
 
 def _matvec_cols_kernel(g_ref, a_ref, o_ref):
@@ -88,8 +112,7 @@ def _matvec_cols_kernel(g_ref, a_ref, o_ref):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     g = g_ref[...].astype(jnp.float32)
-    a = a_ref[0].astype(jnp.float32)
-    o_ref[0] += _tile_matvec(g, a)
+    o_ref[0] += _tile_matvec(g, a_ref[0])
 
 
 @functools.partial(jax.jit, static_argnames=('block_in', 'block_out', 'interpret'))
@@ -111,13 +134,9 @@ def matvec_cols(g: jnp.ndarray, a: jnp.ndarray, block_in: int = 512,
     """
     R, m = a.shape
     m_g, n = g.shape
-    assert m == m_g, (a.shape, g.shape)
-    bm, bn = fit_block(m, block_in), fit_block(n, block_out)
-    pad_m = (-m) % bm
-    pad_n = (-n) % bn
-    if pad_m or pad_n:
-        g = jnp.pad(g, ((0, pad_m), (0, pad_n)))
-        a = jnp.pad(a, ((0, 0), (0, pad_m)))
+    if m != m_g:
+        raise ValueError(f'matvec_cols: a {a.shape} does not match g {g.shape}')
+    g, a, bm, bn = _prep(g, a, block_in, block_out, interpret)
     mp, np_ = g.shape
     out = pl.pallas_call(
         _matvec_cols_kernel,
@@ -125,13 +144,13 @@ def matvec_cols(g: jnp.ndarray, a: jnp.ndarray, block_in: int = 512,
         grid=(R, np_ // bn, mp // bm),
         in_specs=[
             pl.BlockSpec((bm, bn), lambda r, j, i: (i, j)),
-            pl.BlockSpec((1, bm), lambda r, j, i: (r, i)),
+            pl.BlockSpec((1, bm, 1), lambda r, j, i: (r, i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bn), lambda r, j, i: (r, j)),
-        out_shape=jax.ShapeDtypeStruct((R, np_), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, bn), lambda r, j, i: (r, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((R, 1, np_), jnp.float32),
         interpret=interpret,
-    )(g, a.astype(jnp.float32))
-    return out[:, :n] if pad_n else out
+    )(g, a)
+    return out[:, 0, :n]
 
 
 def _matvec_cols_stacked_kernel(g_ref, a_ref, o_ref):
@@ -142,8 +161,7 @@ def _matvec_cols_stacked_kernel(g_ref, a_ref, o_ref):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     g = g_ref[0].astype(jnp.float32)
-    a = a_ref[0, 0].astype(jnp.float32)
-    o_ref[0, 0] += _tile_matvec(g, a)
+    o_ref[0, 0] += _tile_matvec(g, a_ref[0, 0])
 
 
 @functools.partial(jax.jit, static_argnames=('block_in', 'block_out', 'interpret'))
@@ -157,50 +175,20 @@ def matvec_cols_stacked(g: jnp.ndarray, a: jnp.ndarray, block_in: int = 512,
     rides the leading grid axis exactly like :func:`matvec_stacked`."""
     L, R, m = a.shape
     Lg, m_g, n = g.shape
-    assert (L, m) == (Lg, m_g), (a.shape, g.shape)
-    bm, bn = fit_block(m, block_in), fit_block(n, block_out)
-    pad_m = (-m) % bm
-    pad_n = (-n) % bn
-    if pad_m or pad_n:
-        g = jnp.pad(g, ((0, 0), (0, pad_m), (0, pad_n)))
-        a = jnp.pad(a, ((0, 0), (0, 0), (0, pad_m)))
+    if (L, m) != (Lg, m_g):
+        raise ValueError(
+            f'matvec_cols_stacked: a {a.shape} does not match g {g.shape}')
+    g, a, bm, bn = _prep(g, a, block_in, block_out, interpret)
     mp, np_ = g.shape[1:]
     out = pl.pallas_call(
         _matvec_cols_stacked_kernel,
         grid=(L, R, np_ // bn, mp // bm),
         in_specs=[
             pl.BlockSpec((1, bm, bn), lambda l, r, j, i: (l, i, j)),
-            pl.BlockSpec((1, 1, bm), lambda l, r, j, i: (l, r, i)),
+            pl.BlockSpec((1, 1, bm, 1), lambda l, r, j, i: (l, r, i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, bn), lambda l, r, j, i: (l, r, j)),
-        out_shape=jax.ShapeDtypeStruct((L, R, np_), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, 1, bn), lambda l, r, j, i: (l, r, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((L, R, 1, np_), jnp.float32),
         interpret=interpret,
-    )(g, a.astype(jnp.float32))
-    return out[:, :, :n] if pad_n else out
-
-
-@functools.partial(jax.jit, static_argnames=('block_in', 'block_out', 'interpret'))
-def matvec_stacked(g: jnp.ndarray, a: jnp.ndarray, block_in: int = 512,
-                   block_out: int = 512, interpret: bool = True) -> jnp.ndarray:
-    """Stacked u = aᵀ G.  g: (L, d_in, d_out); a: (L, d_in) -> (L, d_out)
-    f32.  One launch; the stack rides the leading grid axis."""
-    L, d_in, d_out = g.shape
-    bm, bn = fit_block(d_in, block_in), fit_block(d_out, block_out)
-    pad_in = (-d_in) % bm
-    pad_out = (-d_out) % bn
-    if pad_in or pad_out:
-        g = jnp.pad(g, ((0, 0), (0, pad_in), (0, pad_out)))
-        a = jnp.pad(a, ((0, 0), (0, pad_in)))
-    m, n = g.shape[1:]
-    out = pl.pallas_call(
-        _matvec_stacked_kernel,
-        grid=(L, n // bn, m // bm),
-        in_specs=[
-            pl.BlockSpec((1, bm, bn), lambda l, j, i: (l, i, j)),
-            pl.BlockSpec((1, bm), lambda l, j, i: (l, i)),
-        ],
-        out_specs=pl.BlockSpec((1, bn), lambda l, j, i: (l, j)),
-        out_shape=jax.ShapeDtypeStruct((L, n), jnp.float32),
-        interpret=interpret,
-    )(g, a.astype(jnp.float32))
-    return out[:, :d_out] if pad_out else out
+    )(g, a)
+    return out[:, :, 0, :n]

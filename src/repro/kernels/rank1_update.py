@@ -4,13 +4,13 @@ This is the hot half of Eva's Sherman–Morrison step (Eq. 13): a purely
 memory-bound pass over the gradient (read G once, write P once, ~3 flops per
 element).  The roofline goal is streaming G at HBM bandwidth, so:
 
-  * G is tiled (block_in × block_out) — 128-aligned blocks so the VPU lanes
-    (8×128) are full and each tile sits in VMEM (default 512×512 f32 = 1 MiB
-    per operand buffer, well under the ~16 MiB/core VMEM budget with double
-    buffering);
-  * the KV slices a[i-block], b[j-block] are tiny VMEM residents;
-  * coeff/scale ride in as a (2,)-vector block broadcast to every tile
-    (computed on the host side of the op — see ops.eva_precondition).
+  * G is tiled (block_in × block_out) in blocks Mosaic accepts
+    (``tiles.fit_tiles``: lane tiles of 128, sublane tiles of 8 for f32 /
+    16 for bf16, or the full dim);
+  * the KV slices ride as a (bm, 1) column of a and a (1, bn) row of b —
+    tiny VMEM residents;
+  * coeff/scale ride in SMEM as scalars (computed on the host side of the
+    op — see ops.eva_precondition).
 
 Grid iteration order is (d_in/bm, d_out/bn), sequential per TPU core;
 the fused multiply-sub runs on the VPU while the next G tile streams in.
@@ -25,26 +25,28 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tiles import fit_block
+from repro.kernels.bilinear import fit_and_pad
+
+SMEM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-def _rank1_tile(g, a, b, coeff, scale):
-    return scale * (g - coeff * (a[:, None] * b[None, :]))
+def _rank1_tile(g, a_col, b_row, coeff, scale):
+    return scale * (g - coeff * (a_col * b_row))
 
 
 def _rank1_kernel(g_ref, a_ref, b_ref, cs_ref, o_ref):
     g = g_ref[...].astype(jnp.float32)
-    a = a_ref[...].astype(jnp.float32)
-    b = b_ref[...].astype(jnp.float32)
-    o_ref[...] = _rank1_tile(g, a, b, cs_ref[0], cs_ref[1]).astype(o_ref.dtype)
+    o_ref[...] = _rank1_tile(g, a_ref[...], b_ref[...], cs_ref[0],
+                             cs_ref[1]).astype(o_ref.dtype)
 
 
 def _rank1_stacked_kernel(g_ref, a_ref, b_ref, cs_ref, o_ref):
+    l = pl.program_id(0)
     g = g_ref[0].astype(jnp.float32)
-    a = a_ref[0].astype(jnp.float32)
-    b = b_ref[0].astype(jnp.float32)
-    o_ref[0] = _rank1_tile(g, a, b, cs_ref[0, 0], cs_ref[0, 1]).astype(o_ref.dtype)
+    o_ref[0] = _rank1_tile(g, a_ref[0], b_ref[0], cs_ref[l, 0],
+                           cs_ref[l, 1]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=('block_in', 'block_out', 'interpret'))
@@ -58,13 +60,8 @@ def rank1_update(g: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray,
     garbage that is sliced off — cheaper than ragged BlockSpecs).
     """
     d_in, d_out = g.shape
-    bm, bn = fit_block(d_in, block_in), fit_block(d_out, block_out)
-    pad_in = (-d_in) % bm
-    pad_out = (-d_out) % bn
-    if pad_in or pad_out:
-        g = jnp.pad(g, ((0, pad_in), (0, pad_out)))
-        a = jnp.pad(a, (0, pad_in))
-        b = jnp.pad(b, (0, pad_out))
+    g, a, b, bm, bn = fit_and_pad(g, a, b, block_in, block_out,
+                                    interpret)
     m, n = g.shape
     cs = jnp.stack([jnp.asarray(coeff, jnp.float32),
                     jnp.asarray(scale, jnp.float32)])
@@ -73,15 +70,15 @@ def rank1_update(g: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray,
         grid=(m // bm, n // bn),
         in_specs=[
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
-            pl.BlockSpec((bn,), lambda i, j: (j,)),
-            pl.BlockSpec((2,), lambda i, j: (0,)),
+            pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
+            SMEM_SPEC,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), g.dtype),
         interpret=interpret,
-    )(g, a.astype(jnp.float32), b.astype(jnp.float32), cs)
-    if pad_in or pad_out:
+    )(g, a, b, cs)
+    if (m, n) != (d_in, d_out):
         out = out[:d_in, :d_out]
     return out
 
@@ -96,13 +93,8 @@ def rank1_update_stacked(g: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray,
     g: (L, d_in, d_out); a: (L, d_in); b: (L, d_out); coeff/scale: (L,).
     """
     L, d_in, d_out = g.shape
-    bm, bn = fit_block(d_in, block_in), fit_block(d_out, block_out)
-    pad_in = (-d_in) % bm
-    pad_out = (-d_out) % bn
-    if pad_in or pad_out:
-        g = jnp.pad(g, ((0, 0), (0, pad_in), (0, pad_out)))
-        a = jnp.pad(a, ((0, 0), (0, pad_in)))
-        b = jnp.pad(b, ((0, 0), (0, pad_out)))
+    g, a, b, bm, bn = fit_and_pad(g, a, b, block_in, block_out,
+                                    interpret)
     m, n = g.shape[1:]
     cs = jnp.stack([jnp.asarray(coeff, jnp.float32),
                     jnp.asarray(scale, jnp.float32)], axis=-1)   # (L, 2)
@@ -111,14 +103,14 @@ def rank1_update_stacked(g: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray,
         grid=(L, m // bm, n // bn),
         in_specs=[
             pl.BlockSpec((1, bm, bn), lambda l, i, j: (l, i, j)),
-            pl.BlockSpec((1, bm), lambda l, i, j: (l, i)),
-            pl.BlockSpec((1, bn), lambda l, i, j: (l, j)),
-            pl.BlockSpec((1, 2), lambda l, i, j: (l, 0)),
+            pl.BlockSpec((1, bm, 1), lambda l, i, j: (l, i, 0)),
+            pl.BlockSpec((1, 1, bn), lambda l, i, j: (l, 0, j)),
+            SMEM_SPEC,
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda l, i, j: (l, i, j)),
         out_shape=jax.ShapeDtypeStruct((L, m, n), g.dtype),
         interpret=interpret,
-    )(g, a.astype(jnp.float32), b.astype(jnp.float32), cs)
-    if pad_in or pad_out:
+    )(g, a, b, cs)
+    if (m, n) != (d_in, d_out):
         out = out[:, :d_in, :d_out]
     return out

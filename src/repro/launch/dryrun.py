@@ -32,7 +32,6 @@ from repro.configs.registry import ARCH_IDS
 from repro.core.registry import make_optimizer
 from repro.launch import hlo_analysis
 from repro.launch.mesh import make_production_mesh
-from repro.sharding import compat
 from repro.models import build_model, decode_specs, prefill_batch_specs, train_batch_specs
 from repro.models import module as M
 from repro.sharding import (cache_shardings, input_shardings,
@@ -120,7 +119,7 @@ def run_cell(arch_id: str, shape, multi_pod: bool, out_dir: Path,
     t0 = time.time()
     fn, args, shardings, donate, tokens, kind = build_cell(cfg, shape, mesh,
                                                            fallback_log)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn, in_shardings=shardings,
                           donate_argnums=donate).lower(*args)
         t_lower = time.time() - t0
@@ -129,7 +128,7 @@ def run_cell(arch_id: str, shape, multi_pod: bool, out_dir: Path,
         t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     hlo = hlo_analysis.analyze(compiled.as_text())
 
     specs = build_model(cfg).param_specs()

@@ -1,10 +1,10 @@
 """Production training launcher.
 
 Single-host execution of the full stack (config → model → Eva → trainer with
-checkpointing/preemption).  On a real multi-pod deployment the same entry
-point runs under ``jax.distributed.initialize()`` (one process per host —
-see ``launch/run_multipod.sh``); the step function, shardings and
-checkpoint protocol are host-count-agnostic.
+checkpointing/preemption).  On a multi-host deployment the same entry point
+runs under ``jax.distributed.initialize()`` (``--distributed``, one process
+per host); the step function, shardings and checkpoint protocol are
+host-count-agnostic.
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b --reduced \\
         --steps 50 --opt eva
@@ -12,6 +12,7 @@ checkpoint protocol are host-count-agnostic.
 from __future__ import annotations
 
 import argparse
+from typing import Optional, Sequence
 
 import jax
 
@@ -20,12 +21,15 @@ from repro.configs.registry import ARCH_IDS, demo_lm
 from repro.core import kv as kvlib
 from repro.core import make_optimizer
 from repro.data import LMStream, Prefetcher
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.models import module as M
 from repro.train import Trainer, TrainerConfig
 
 
-def main() -> None:
+def main(argv: Optional[Sequence[str]] = None) -> list:
+    """Parse ``argv`` (default: the command line), train, and return the
+    trainer's loss history."""
     ap = argparse.ArgumentParser()
     ap.add_argument('--arch', default='demo',
                     help=f'demo|demo-base|demo-100m|{"|".join(ARCH_IDS)}')
@@ -75,8 +79,9 @@ def main() -> None:
     ap.add_argument('--world', type=int, default=0,
                     help='data-parallel worker count for --elastic '
                          '(0 = every local device)')
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    enable_compile_cache()
     if args.distributed:
         jax.distributed.initialize()
 
@@ -91,7 +96,10 @@ def main() -> None:
                          'frontend archs; the LM trainer needs token input')
 
     model = build_model(cfg)
-    params = M.init_params(model.param_specs(), jax.random.PRNGKey(0))
+    init = lambda: M.init_params(model.param_specs(), jax.random.PRNGKey(0))
+    # shapes only: the arrays are made as they are handed to the trainer,
+    # so that its pre-donation copy leaves no second set alive here
+    shapes = kvlib.flatten_params(jax.eval_shape(init))
     print(f'{cfg.name}: {M.count_params(model.param_specs())/1e6:.2f}M params')
     stream = LMStream(vocab=cfg.vocab, seq_len=args.seq_len, batch=args.batch,
                       seed=0)
@@ -104,7 +112,7 @@ def main() -> None:
     if capture.b == 'outer':
         # K-FAC-style capture needs full z-shaped taps (kv.make_full_taps);
         # batch-aware so the elastic DP step sizes them to batch/W rows
-        paths = set(model.precon_paths()) & set(kvlib.flatten_params(params))
+        paths = set(model.precon_paths()) & set(shapes)
         taps_fn = lambda p, b: kvlib.make_full_taps(p, paths,
                                                     b['tokens'].shape)
     factor = None
@@ -124,12 +132,11 @@ def main() -> None:
         if args.autotune:
             # tune the distinct 2-D trailing shapes the preconditioner will
             # actually dispatch (bucketed layers share a shape = one entry)
-            flat = kvlib.flatten_params(params)
-            shapes = sorted({tuple(int(d) for d in flat[p].shape[-2:])
-                             for p in model.precon_paths()
-                             if p in flat and flat[p].ndim >= 2})
-            print(f'[launch] autotuning {len(shapes)} shapes: {shapes}')
-            cache = ktune.tune(shapes)
+            tuned = sorted({tuple(int(d) for d in shapes[p].shape[-2:])
+                            for p in model.precon_paths()
+                            if p in shapes and shapes[p].ndim >= 2})
+            print(f'[launch] autotuning {len(tuned)} shapes: {tuned}')
+            cache = ktune.tune(tuned)
             cache_path = str(ktune.write(
                 cache, f'{tc.out_dir}/tile_cache.json'))
             print(f'[launch] autotune cache -> {cache_path}')
@@ -138,10 +145,14 @@ def main() -> None:
                               autotune=args.autotune)
     trainer = Trainer(model, opt, capture, tc, taps_fn=taps_fn,
                       factor=factor, kernel=kernel)
-    if args.elastic:
-        trainer.fit_elastic(params, data, world=args.world or None)
-    else:
-        trainer.fit(params, data)
+    try:
+        if args.elastic:
+            return trainer.fit_elastic(init(), data,
+                                       world=args.world or None)[2]
+        return trainer.fit(init(), data)[2]
+    finally:
+        if data is not stream:
+            data.close()
 
 
 if __name__ == '__main__':

@@ -60,8 +60,8 @@ def _expert_linear(w: jnp.ndarray, x: jnp.ndarray, *, wpath: str, col,
 
 def _n_data_shards() -> int:
     """Data-axis size of the current mesh (1 outside a mesh context)."""
-    from repro.sharding.constraints import _current_mesh
-    m = _current_mesh()
+    from repro.sharding.constraints import current_mesh
+    m = current_mesh()
     if m is None:
         return 1
     n = 1

@@ -121,20 +121,13 @@ class StragglerWatchdog:
 
 def live_buffer_mb() -> float:
     """Total bytes of live device arrays in this process, in MiB."""
-    try:
-        arrays = jax.live_arrays()
-    except Exception:
-        return -1.0
-    return round(sum(getattr(a, 'nbytes', 0) for a in arrays) / 2 ** 20, 3)
+    return round(sum(a.nbytes for a in jax.live_arrays()) / 2 ** 20, 3)
 
 
 def device_bytes_in_use() -> Optional[int]:
     """Allocator bytes-in-use of device 0, where the backend reports it
     (TPU/GPU; the CPU backend returns None)."""
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-    except Exception:
-        return None
+    stats = jax.local_devices()[0].memory_stats()
     if not stats or 'bytes_in_use' not in stats:
         return None
     return int(stats['bytes_in_use'])

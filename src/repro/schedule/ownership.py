@@ -29,8 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.bucketing import Bucket, BucketPlan
-from repro.sharding import compat
-from repro.sharding.constraints import data_axes_in_scope
+from repro.sharding.constraints import bound_axis_sizes, data_axes_in_scope
 
 
 # ---------------------------------------------------------------------------
@@ -281,7 +280,7 @@ def world_and_rank(axes: Optional[tuple[str, ...]] = None):
         axes = data_axes_in_scope()
     if not axes:
         return 1, None
-    sizes = compat.bound_axis_sizes()
+    sizes = bound_axis_sizes()
     world = 1
     for a in axes:
         world *= int(sizes.get(a, 1))

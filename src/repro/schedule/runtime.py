@@ -41,8 +41,7 @@ from repro.core.bucketing import Bucket, BucketPlan
 from repro.schedule import ownership
 from repro.schedule import pipeline as pipeline_mod
 from repro.schedule import policy as policy_mod
-from repro.sharding import compat
-from repro.sharding.constraints import psum_tree
+from repro.sharding.constraints import bound_axis_sizes, psum_tree
 
 
 @dataclasses.dataclass(frozen=True)
@@ -200,7 +199,7 @@ def sharded_refresh(plan: BucketPlan, refresh: jnp.ndarray,
         pods = None
         if cfg.topology == 'pod' and cfg.exchange == 'gather' \
                 and len(axes) == 2:
-            sizes = compat.bound_axis_sizes()
+            sizes = bound_axis_sizes()
             pods = (int(sizes.get(axes[0], 1)), int(sizes.get(axes[1], 1)))
             if pods[0] <= 1 or pods[0] * pods[1] != world:
                 pods = None

@@ -15,30 +15,43 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding import compat
 
-
-def _current_mesh():
-    m = compat.current_mesh()
-    if m is None:
-        return None
-    # inside shard_map axes are Manual: constraints are illegal there.  New
-    # jax marks this via axis_types; old jax has no axis metadata, so detect
-    # the shard_map body by its bound axis names instead.
-    if not compat.axes_all_auto(m):
-        return None
-    if compat.bound_axis_names():
+def current_mesh():
+    """The mesh of the enclosing ``jax.set_mesh`` context when every axis
+    is Auto (constraints are legal), else None — outside any mesh, and
+    inside ``shard_map`` bodies, whose mapped axes are Manual."""
+    m = jax.sharding.get_abstract_mesh()
+    if m.empty or any(t != AxisType.Auto for t in m.axis_types):
         return None
     return m
+
+
+def bound_axis_sizes() -> dict:
+    """{axis name: size} for the mesh axes bound in the current tracing
+    scope (the Manual axes of an enclosing ``shard_map`` body); {} at top
+    level."""
+    m = jax.sharding.get_abstract_mesh()
+    return {str(a): int(m.shape[a]) for a in m.manual_axes}
+
+
+def _apply(x: jnp.ndarray, spec: list) -> jnp.ndarray:
+    """``with_sharding_constraint`` by spec under ``jit``; an eager
+    (uncommitted, single-device) array needs the concrete context mesh
+    to be placed rather than refused."""
+    if isinstance(x, jax.core.Tracer):
+        return jax.lax.with_sharding_constraint(x, P(*spec))
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(jax.sharding.get_mesh(), P(*spec)))
 
 
 def constrain(x: jnp.ndarray, *axes: Optional[str]) -> jnp.ndarray:
     """with_sharding_constraint by logical role per dim: each entry is
     'data' (→ (pod,data)), 'model', or None; silently dropped when the axis
     is missing, doesn't divide, or we're inside shard_map."""
-    mesh = _current_mesh()
+    mesh = current_mesh()
     if mesh is None:
         return x
     daxes = tuple(a for a in ('pod', 'data') if a in mesh.shape)
@@ -54,13 +67,13 @@ def constrain(x: jnp.ndarray, *axes: Optional[str]) -> jnp.ndarray:
             spec[i] = 'model'
     if all(s is None for s in spec):
         return x
-    return jax.lax.with_sharding_constraint(x, P(*spec))
+    return _apply(x, spec)
 
 
 def data_axes_in_scope() -> tuple[str, ...]:
     """The subset of the data-parallel axes ('pod', 'data') bound in the
     current tracing scope (inside shard_map/pmap bodies); () elsewhere."""
-    bound = compat.bound_axis_names()
+    bound = bound_axis_sizes()
     return tuple(a for a in ('pod', 'data') if a in bound)
 
 
@@ -169,7 +182,7 @@ def psum_tree(tree, axes: Optional[tuple[str, ...]] = None):
 def shard_activations(x: jnp.ndarray, seq: Optional[str] = None) -> jnp.ndarray:
     """Constrain dim0 (batch) to (pod,data); optionally dim1 (seq) to model.
     Falls back to sharding the sequence dim over 'data' for batch=1 cells."""
-    mesh = _current_mesh()
+    mesh = current_mesh()
     if mesh is None or x.ndim < 2:
         return x
     daxes = tuple(a for a in ('pod', 'data') if a in mesh.shape)
@@ -189,4 +202,4 @@ def shard_activations(x: jnp.ndarray, seq: Optional[str] = None) -> jnp.ndarray:
         spec[1] = 'data'
     else:
         return x
-    return jax.lax.with_sharding_constraint(x, P(*spec))
+    return _apply(x, spec)

@@ -28,7 +28,6 @@ from repro.comm import exchange
 from repro.core import kv as kvlib
 from repro.core.transform import Extras, apply_updates
 from repro.schedule import pipeline as pipemod
-from repro.sharding import compat
 from repro.train.step import _plan_for_stats, compute_grads_and_stats
 
 
@@ -109,8 +108,8 @@ def make_dp_train_step(model, opt, capture: kvlib.CaptureConfig, mesh,
 
     in_specs = (P(), P(), P(), P('data'))
     out_specs = (P(), P(), P(), P())
-    smapped = compat.shard_map(local_step, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check=False)
+    smapped = jax.shard_map(local_step, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)
 
     def init_error(params):
         return jax.tree_util.tree_map(

@@ -228,7 +228,6 @@ def make_dp_step(model, opt: GradientTransformation,
     from jax.sharding import PartitionSpec as P
 
     from repro.comm import exchange
-    from repro.sharding import compat
 
     def local_step(params, opt_state, batch):
         # NOTE: batch here is the per-worker shard — a batch-aware taps_fn
@@ -236,11 +235,19 @@ def make_dp_step(model, opt: GradientTransformation,
         loss, grads, stats = compute_grads_and_stats(
             model, params, batch, capture, make_taps(params, batch))
         loss = jax.lax.pmean(loss, 'data')
-        grads, _, _ = exchange.allreduce_mean_tree(
-            grads, codec='f32', axes=('data',), site='grads/dp')
+
+        def mean_over_workers(tree, site):
+            # the mean is taken in f32; handing it back in each leaf's own
+            # dtype keeps the optimizer's bucket keys (which name the dtype)
+            # those of the state built from bf16 params
+            mean, _, _ = exchange.allreduce_mean_tree(
+                tree, codec='f32', axes=('data',), site=site)
+            return jax.tree_util.tree_map(lambda m, x: m.astype(x.dtype),
+                                          mean, tree)
+
+        grads = mean_over_workers(grads, 'grads/dp')
         if stats is not None:
-            stats, _, _ = exchange.allreduce_mean_tree(
-                stats, codec='f32', axes=('data',), site='stats/dp')
+            stats = mean_over_workers(stats, 'stats/dp')
         updates, new_opt_state = opt.update(
             grads, opt_state, params=params,
             extras=Extras(stats=stats, loss=loss,
@@ -256,9 +263,9 @@ def make_dp_step(model, opt: GradientTransformation,
         metrics.update(fsh.step_metrics(new_opt_state))
         return new_params, new_opt_state, metrics
 
-    return compat.shard_map(local_step, mesh=mesh,
-                            in_specs=(P(), P(), P('data')),
-                            out_specs=(P(), P(), P()), check=False)
+    return jax.shard_map(local_step, mesh=mesh,
+                         in_specs=(P(), P(), P('data')),
+                         out_specs=(P(), P(), P()), check_vma=False)
 
 
 def make_phased_step(model, opt: GradientTransformation,

@@ -112,12 +112,9 @@ class Trainer:
     def _log_ownership(self, recorder, params, batch) -> None:
         """One startup record: the per-bucket refresh-owner map a W-worker
         data-parallel run of this model would use (W = local device count).
-        Purely informational — cheap (eval_shape only), never fatal."""
-        try:
-            plan = stats_plan_of(self.model, self.capture, params, batch,
-                                 taps_fn=self.taps_fn)
-        except Exception:
-            plan = None
+        Cheap (eval_shape only); None plan (first-order) logs nothing."""
+        plan = stats_plan_of(self.model, self.capture, params, batch,
+                             taps_fn=self.taps_fn)
         body = schedrt.ownership_event(plan)
         if body is None:
             return
@@ -189,13 +186,9 @@ class Trainer:
         if dev is not None:
             rec['device_bytes_in_use'] = dev
         if one_shot_hlo:
-            try:
-                rec['fns'] = {
-                    name: obs_spans.compiled_fn_costs(fn, *args)
-                    for name, (fn, args) in phase_args.items()}
-            except Exception as e:  # never fatal: HLO text formats drift
-                print(f'[trainer] profile: HLO cost pass skipped ({e})',
-                      flush=True)
+            rec['fns'] = {
+                name: obs_spans.compiled_fn_costs(fn, *args)
+                for name, (fn, args) in phase_args.items()}
         recorder.emit('profile', **rec)
 
     # -- main loop ------------------------------------------------------------
@@ -389,12 +382,8 @@ class Trainer:
 
         # the bucket plan is the reshard key: ownership maps and the
         # checkpoint fingerprint both derive from it (None = first-order)
-        try:
-            plan = stats_plan_of(self.model, self.capture, params,
-                                 data.batch_at(start_step),
-                                 taps_fn=self.taps_fn)
-        except Exception:
-            plan = None
+        plan = stats_plan_of(self.model, self.capture, params,
+                             data.batch_at(start_step), taps_fn=self.taps_fn)
 
         def _init_state(step):
             return init_opt_state(self.model, self.opt, self.capture, params,

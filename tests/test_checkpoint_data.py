@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.sharding import compat
+from repro.launch.mesh import make_mesh
 
 from repro.data.memmap_loader import MemmapLM, write_tokens
 from repro.data.pipeline import Prefetcher
@@ -50,7 +50,7 @@ def test_elastic_reshard_restore(tmp_path):
     """Restore onto an explicit sharding (single-device 'mesh')."""
     t = _tree()
     ckpt.save(tmp_path, 1, t)
-    mesh = compat.make_mesh((1,), ('data',))
+    mesh = make_mesh((1,), ('data',))
     sh = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
     restored, _ = ckpt.restore(tmp_path, 1,
                                jax.tree_util.tree_map(jnp.zeros_like, t),

@@ -198,7 +198,7 @@ _EQUIV_SCRIPT = textwrap.dedent("""
     from repro.core.transform import Extras
     from repro.schedule.policy import every_k
     from repro.schedule.runtime import RefreshRuntime
-    from repro.sharding import compat
+    from repro.launch.mesh import make_mesh
 
     SHAPES = {'blk0/w': (8, 4), 'blk1/w': (8, 4), 'blk2/w': (8, 4),
               'head/w': (8, 3), 'stack/w': (2, 6, 4)}
@@ -235,10 +235,10 @@ _EQUIV_SCRIPT = textwrap.dedent("""
     }
     NEEDS_STATS = {'eva', 'eva_f', 'foof', 'kfac'}
     STEPS = 3
-    mesh = compat.make_mesh((4,), ('data',))
+    mesh = make_mesh((4,), ('data',))
     params = kvlib.unflatten_params(grads(0))
 
-    def run(method, comm):
+    def run(method, comm, states=None):
         opt = MAKERS[method]()
         rt = RefreshRuntime(shard_refresh=True)
         ex = lambda t: (Extras(stats=stats(t), sched=rt, comm=comm)
@@ -252,16 +252,19 @@ _EQUIV_SCRIPT = textwrap.dedent("""
             return opt.update(g, s, extras=e)
 
         in_specs = (P(), P(), P()) if method in NEEDS_STATS else (P(), P())
-        step = jax.jit(compat.shard_map(
+        step = jax.jit(jax.shard_map(
             (body if method in NEEDS_STATS
              else (lambda g, s: body(g, s, None))),
-            mesh=mesh, in_specs=in_specs, out_specs=(P(), P()), check=False))
+            mesh=mesh, in_specs=in_specs, out_specs=(P(), P()),
+            check_vma=False))
         outs = []
         for t in range(STEPS):
             args = (grads(t), state, stats(t)) if method in NEEDS_STATS \
                 else (grads(t), state)
             out, state = step(*args)
             outs.append(out)
+            if states is not None:
+                states.append(state)
         return outs, state
 
     def maxdiff(a, b):
@@ -275,17 +278,48 @@ _EQUIV_SCRIPT = textwrap.dedent("""
         return max(float(np.max(np.abs(np.asarray(x))))
                    for x in jax.tree_util.tree_leaves(a))
 
+    def shampoo_int8_bound(states):
+        # shampoo applies out = P_in G P_out with the exchanged inverse
+        # roots.  The int8 wire carries one max-scale per stack row, so each
+        # root entry lands within half a quantization step of its row,
+        # |E| <= d = max|P_row| / 254, and elementwise
+        #   |dout| <= d_in 1|G||P_out| + |P_in||G|1 d_out + d_in d_out 1|G|1
+        # (1 = all-ones).  The roots used at step t are those in the exact
+        # (psum) run's state after step t.
+        from repro.core.eva_s import default_precon_predicate
+        worst = 0.0
+        for t, st in enumerate(states):
+            flat = grads(t)
+            plan = bucketing.build_plan(flat, default_precon_predicate)
+            for b in plan.buckets:
+                for i, path in enumerate(b.paths):
+                    pi = jnp.abs(st.p_in[b.key][i])
+                    po = jnp.abs(st.p_out[b.key][i])
+                    g = jnp.abs(flat[path])
+                    d_in, d_out = jnp.max(pi) / 254.0, jnp.max(po) / 254.0
+                    bound = (d_in * (jnp.sum(g, -2, keepdims=True) @ po)
+                             + d_out * (pi @ jnp.sum(g, -1, keepdims=True))
+                             + d_in * d_out * jnp.sum(g, (-2, -1),
+                                                      keepdims=True))
+                    worst = max(worst, float(jnp.max(bound)))
+        return worst
+
     rec = {'devices': jax.device_count(), 'methods': {}}
     for method in sorted(MAKERS):
-        o_ps, s_ps = run(method, ExchangeConfig(exchange='psum'))
+        ps_states = []
+        o_ps, s_ps = run(method, ExchangeConfig(exchange='psum'), ps_states)
         o_ag, s_ag = run(method, ExchangeConfig(exchange='gather'))
         o_i8, s_i8 = run(method, ExchangeConfig(exchange='gather',
                                                 codec='int8'))
+        scale = max(maxabs(o_ps), 1e-12)
         rec['methods'][method] = {
             'ag_vs_psum_out': maxdiff(o_ag, o_ps),
             'ag_vs_psum_state': maxdiff(s_ag, s_ps),
-            'int8_vs_psum_rel': maxdiff(o_i8, o_ps) / max(maxabs(o_ps), 1e-12),
+            'int8_vs_psum_rel': maxdiff(o_i8, o_ps) / scale,
         }
+        if method == 'shampoo':
+            rec['methods'][method]['int8_bound_rel'] = \
+                shampoo_int8_bound(ps_states) / scale
 
     # int8 gradient all-reduce under shard_map: mean within half a step of
     # exact, saturation identically zero
@@ -296,9 +330,9 @@ _EQUIV_SCRIPT = textwrap.dedent("""
         return allreduce_mean_tree(gs, es, codec='int8', axes=('data',),
                                    site='grads/test')
 
-    red = jax.jit(compat.shard_map(
+    red = jax.jit(jax.shard_map(
         reduce_body, mesh=mesh, in_specs=(P(), P()),
-        out_specs=(P(), P(), P()), check=False))
+        out_specs=(P(), P(), P()), check_vma=False))
     mean, new_err, info = red(g, err0)
     exact = jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), g)
     rec['grad_int8_err'] = maxdiff(mean, exact)
@@ -322,8 +356,8 @@ _EQUIV_SCRIPT = textwrap.dedent("""
             out = allgather_owned_slices(plan2, owners2, w, r, {key2: s},
                                          codec=codec, axes=('data',))
             return out[key2]
-        return jax.jit(compat.shard_map(body, mesh=mesh, in_specs=(P(),),
-                                        out_specs=P(), check=False))
+        return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(),),
+                                     out_specs=P(), check_vma=False))
 
     # every worker holds the full true stack; non-owned rows are never read,
     # so the reconstruction must equal the input exactly
@@ -334,7 +368,7 @@ _EQUIV_SCRIPT = textwrap.dedent("""
 
     # --- topology='pod' on a (2,2) ('pod','data') mesh: the two-stage
     # (ICI slice gather + DCN bucket psum) exchange ≡ full-stack psum ---
-    mesh22 = compat.make_mesh((2, 2), ('pod', 'data'))
+    mesh22 = make_mesh((2, 2), ('pod', 'data'))
 
     def run22(method, comm):
         opt = MAKERS[method]()
@@ -345,9 +379,9 @@ _EQUIV_SCRIPT = textwrap.dedent("""
             return opt.update(g, s, extras=Extras(stats=st, sched=rt,
                                                   comm=comm))
 
-        step = jax.jit(compat.shard_map(
+        step = jax.jit(jax.shard_map(
             body, mesh=mesh22, in_specs=(P(), P(), P()),
-            out_specs=(P(), P()), check=False))
+            out_specs=(P(), P()), check_vma=False))
         outs = []
         for t in range(STEPS):
             out, state = step(grads(t), state, stats(t))
@@ -383,8 +417,12 @@ def test_owned_slice_exchange_matches_psum_all_methods():
         # owned-slice all-gather ≡ full-stack psum, bit-exact, state included
         assert r['ag_vs_psum_out'] == 0.0, (method, r)
         assert r['ag_vs_psum_state'] == 0.0, (method, r)
-        # int8 refresh wire: within 1e-2 relative of the exact exchange
-        assert r['int8_vs_psum_rel'] <= 1e-2, (method, r)
+        # int8 refresh wire: shampoo's two-sided root product is held to
+        # the bound its per-row quantization step implies (computed in the
+        # script, see shampoo_int8_bound); the others to 1e-2 relative of
+        # the exact exchange
+        bound = r.get('int8_bound_rel', 1e-2)
+        assert r['int8_vs_psum_rel'] <= bound, (method, r)
     # replicated inputs: the int8+EF mean must sit within half a
     # quantization step of the exact value, with zero saturation
     assert rec['grad_int8_err'] <= 0.5 * rec['grad_int8_scale'] + 1e-7

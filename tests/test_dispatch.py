@@ -45,10 +45,16 @@ def test_fit_block_balances_tiles():
 
 
 def test_fit_block_alignment_rounds_up():
-    b = fit_block(1000, 512, align=8)
+    # 1001 has no 8-aligned divisor: the least-padded aligned block wins
+    b = fit_block(1001, 512, align=8)
     assert b % 8 == 0 and b >= 500
     # align never exceeds max(block, align)
     assert fit_block(7, 4, align=8) <= 8
+    # an aligned exact divisor beats a padded larger block (a pad is an
+    # extra HBM copy of the operand on the chip)
+    assert fit_block(1000, 512, align=8) == 200
+    assert fit_block(4864, 512, align=128) == 256
+    assert fit_block(896, 512, align=16) == 448
 
 
 def test_fit_block_rejects_nonpositive():

@@ -20,12 +20,12 @@ SCRIPT = textwrap.dedent("""
     from repro.launch.dryrun import build_cell
     from repro.models import build_model
 
-    from repro.sharding import compat
-    mesh = compat.make_mesh((2, 2, 2), ('pod', 'data', 'model'))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 2, 2), ('pod', 'data', 'model'))
     cfg = get_reduced(sys.argv[1])
     shape = ShapeCell('mini_train', seq_len=16, global_batch=8, kind=sys.argv[2])
     fn, args, shardings, donate, tokens, kind = build_cell(cfg, shape, mesh, [])
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn, in_shardings=shardings,
                           donate_argnums=donate).lower(*args)
         compiled = lowered.compile()
@@ -35,7 +35,7 @@ SCRIPT = textwrap.dedent("""
         'flops': costs.flops, 'traffic': costs.traffic_bytes,
         'collective': costs.collective_bytes,
         'temp': mem.temp_size_in_bytes,
-        'cost_flops': float(compat.cost_analysis(compiled).get('flops', 0)),
+        'cost_flops': float(compiled.cost_analysis().get('flops', 0)),
     }))
 """)
 

@@ -273,7 +273,7 @@ _SHARD_SCRIPT = textwrap.dedent("""
     from repro.core.factor_sharded import FactorShardConfig
     from repro.core.transform import Extras
     from repro.schedule.runtime import RefreshRuntime
-    from repro.sharding import compat
+    from repro.launch.mesh import make_mesh
 
     PATHS = {'blk/w': (8, 6), 'head/w': (8, 40)}
     rng = np.random.default_rng(0)
@@ -288,7 +288,7 @@ _SHARD_SCRIPT = textwrap.dedent("""
 
     stats = {p: kvlib.LayerStats(a_outer=psd(s[0]), b_outer=psd(s[1]))
              for p, s in PATHS.items()}
-    mesh = compat.make_mesh((4,), ('data',))
+    mesh = make_mesh((4,), ('data',))
     rt = RefreshRuntime(shard_refresh=True)
 
     def run(opt_factory, factor, steps=3):
@@ -300,9 +300,9 @@ _SHARD_SCRIPT = textwrap.dedent("""
             return opt.update(g, s, extras=Extras(stats=st, factor=factor,
                                                   sched=rt))
 
-        step = jax.jit(compat.shard_map(body, mesh=mesh,
+        step = jax.jit(jax.shard_map(body, mesh=mesh,
                                         in_specs=(P(), P(), P()),
-                                        out_specs=(P(), P()), check=False))
+                                        out_specs=(P(), P()), check_vma=False))
         out = None
         for _ in range(steps):
             out, state = step(grads, state, stats)

@@ -1,33 +1,30 @@
-"""repro.sharding.compat: the version-tolerant mesh shim must work under
-BOTH jax API generations — the real installed one, and the other generation
-simulated via monkeypatching (so a single CI matrix cell covers both
-code paths)."""
+"""Mesh helpers on the installed jax: ``launch.mesh.make_mesh`` (all-Auto
+axes) and the mesh-context queries in ``sharding.constraints``."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
-from repro.sharding import compat
-from repro.sharding.constraints import (constrain, data_axes_in_scope,
+from repro.launch.mesh import make_mesh
+from repro.sharding.constraints import (bound_axis_sizes, constrain,
+                                        current_mesh, data_axes_in_scope,
                                         pmean_stats, shard_activations)
-
-HAS_NEW_API = getattr(jax.sharding, 'AxisType', None) is not None \
-    and hasattr(jax.sharding, 'get_abstract_mesh')
 
 
 def test_make_mesh_installed_api():
-    mesh = compat.make_mesh((1, 1), ('data', 'model'))
+    mesh = make_mesh((1, 1), ('data', 'model'))
     assert tuple(mesh.axis_names) == ('data', 'model')
-    assert compat.axes_all_auto(mesh)
+    assert all(t == AxisType.Auto for t in mesh.axis_types)
 
 
 def test_current_mesh_none_outside_context():
-    assert compat.current_mesh() is None
+    assert current_mesh() is None
 
 
 def test_current_mesh_inside_context():
-    mesh = compat.make_mesh((1,), ('data',))
-    with compat.set_mesh(mesh):
-        m = compat.current_mesh()
+    mesh = make_mesh((1,), ('data',))
+    with jax.set_mesh(mesh):
+        m = current_mesh()
         assert m is not None
         assert 'data' in m.shape
 
@@ -39,68 +36,17 @@ def test_constrain_noop_outside_mesh():
 
 
 def test_constrain_under_mesh_context():
-    mesh = compat.make_mesh((1, 1), ('data', 'model'))
+    mesh = make_mesh((1, 1), ('data', 'model'))
     x = jnp.ones((2, 4, 8))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         y = shard_activations(x)
+        z = constrain(x, 'data', None, 'model')
     np.testing.assert_array_equal(np.asarray(y), np.asarray(x))
-
-
-# ---------------------------------------------------------------------------
-# Simulate the OTHER jax generation via monkeypatching
-
-
-def test_current_mesh_new_api_path(monkeypatch):
-    """Exercise the get_abstract_mesh branch even on old jax."""
-    mesh = compat.make_mesh((1,), ('data',))
-    monkeypatch.setattr(jax.sharding, 'get_abstract_mesh', lambda: mesh,
-                        raising=False)
-    m = compat.current_mesh()
-    assert m is mesh
-
-
-def test_current_mesh_new_api_empty(monkeypatch):
-    class _Empty:
-        empty = True
-    monkeypatch.setattr(jax.sharding, 'get_abstract_mesh', lambda: _Empty(),
-                        raising=False)
-    assert compat.current_mesh() is None
-
-
-def test_old_api_path(monkeypatch):
-    """Force the 0.4.x fallback branch even on new jax."""
-    if HAS_NEW_API:
-        monkeypatch.delattr(jax.sharding, 'get_abstract_mesh', raising=False)
-    assert compat.current_mesh() is None  # no mesh context active
-    mesh = compat.make_mesh((1,), ('data',))
-    with mesh:  # 0.4.x context mechanism: Mesh is a context manager
-        m = compat.current_mesh()
-        assert m is not None and 'data' in m.shape
-
-
-def test_axes_all_auto_without_axis_types():
-    class _NoTypes:
-        pass
-    assert compat.axes_all_auto(_NoTypes())
-
-
-def test_make_mesh_passes_axis_types_on_new_api(monkeypatch):
-    """When AxisType exists, make_mesh must request all-Auto axes."""
-    sentinel = object()
-    seen = {}
-
-    def fake_make_mesh(shapes, names, **kw):
-        seen.update(kw)
-        return 'mesh'
-
-    monkeypatch.setattr(compat, 'AXIS_TYPE_AUTO', sentinel)
-    monkeypatch.setattr(jax, 'make_mesh', fake_make_mesh)
-    assert compat.make_mesh((2,), ('data',)) == 'mesh'
-    assert seen['axis_types'] == (sentinel,)
+    np.testing.assert_array_equal(np.asarray(z), np.asarray(x))
 
 
 def test_bound_axis_names_and_pmean_stats():
-    assert compat.bound_axis_names() == ()
+    assert bound_axis_sizes() == {}
     assert data_axes_in_scope() == ()
     # pmean_stats is the identity outside any shard_map scope
     tree = {'b': jnp.arange(3.0)}
@@ -110,14 +56,17 @@ def test_bound_axis_names_and_pmean_stats():
 
 
 def test_pmean_stats_inside_shard_map():
-    mesh = compat.make_mesh((1,), ('data',))
+    mesh = make_mesh((1,), ('data',))
     from jax.sharding import PartitionSpec as P
 
     def body(x):
         assert data_axes_in_scope() == ('data',)
+        assert bound_axis_sizes() == {'data': 1}
+        # Manual axes: constraints are illegal here, so no mesh is current
+        assert current_mesh() is None
         return pmean_stats({'s': x})['s']
 
-    f = compat.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                         check=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
+                      check_vma=False)
     out = jax.jit(f)(jnp.arange(4.0))
     np.testing.assert_allclose(np.asarray(out), np.arange(4.0))

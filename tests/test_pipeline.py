@@ -502,7 +502,7 @@ _MESH_SCRIPT = textwrap.dedent("""
     from repro.schedule import pipeline as pipemod
     from repro.schedule.policy import every_k
     from repro.schedule.runtime import RefreshRuntime
-    from repro.sharding import compat
+    from repro.launch.mesh import make_mesh
 
     SHAPES = {'blk0/w': (8, 4), 'blk1/w': (8, 4), 'blk2/w': (8, 4),
               'head/w': (8, 3), 'stack/w': (2, 6, 4)}
@@ -534,14 +534,14 @@ _MESH_SCRIPT = textwrap.dedent("""
     def run(rt, meshed):
         state = opt.init(params, Extras(stats=stats(0), sched=rt))
         if meshed:
-            mesh = compat.make_mesh((4,), ('data',))
+            mesh = make_mesh((4,), ('data',))
 
             def body(g, s, st):
                 return opt.update(g, s, extras=Extras(stats=st, sched=rt))
 
-            step = jax.jit(compat.shard_map(
+            step = jax.jit(jax.shard_map(
                 body, mesh=mesh, in_specs=(P(), P(), P()),
-                out_specs=(P(), P()), check=False))
+                out_specs=(P(), P()), check_vma=False))
         else:
             def step(g, s, st):
                 return opt.update(g, s, extras=Extras(stats=st, sched=rt))
@@ -569,14 +569,14 @@ _MESH_SCRIPT = textwrap.dedent("""
     for mode in ('sync', 'onestep'):
         rt = RefreshRuntime(pipeline=mode, shard_refresh=True)
         st = opt.init(params, Extras(stats=stats(0), sched=rt))
-        mesh = compat.make_mesh((4,), ('data',))
+        mesh = make_mesh((4,), ('data',))
 
         def body(g, s, stt):
             return opt.update(g, s, extras=Extras(stats=stt, sched=rt))
 
-        step = jax.jit(compat.shard_map(
+        step = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=(P(), P(), P()),
-            out_specs=(P(), P()), check=False))
+            out_specs=(P(), P()), check_vma=False))
         txt = step.lower(grads(0), st, stats(0)).compile().as_text()
         frac[mode] = hlo_analysis.collective_overlap(txt).dependent_fraction
 

@@ -97,8 +97,8 @@ def test_sharding_resolver_always_valid(seed, dims):
     from repro.sharding.logical import RULES, resolve_pspec
     if jax.device_count() < 1:
         pytest.skip('no devices')
-    from repro.sharding import compat
-    mesh = compat.make_mesh((1, 1), ('data', 'model'))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ('data', 'model'))
     axes_pool = list(RULES.keys())
     rng = np.random.default_rng(seed)
     axes = tuple(axes_pool[rng.integers(len(axes_pool))] for _ in dims)

@@ -483,7 +483,7 @@ _SHARD_SCRIPT = textwrap.dedent("""
     from repro.core.kfac import kfac_preconditioner
     from repro.core.transform import Extras
     from repro.schedule.policy import every_k
-    from repro.sharding import compat
+    from repro.launch.mesh import make_mesh
 
     SHAPES = {'blk0/w': (8, 4), 'blk1/w': (8, 4), 'blk2/w': (8, 4),
               'head/w': (8, 3), 'stack/w': (2, 6, 4)}
@@ -524,15 +524,15 @@ _SHARD_SCRIPT = textwrap.dedent("""
 
     def run_meshed(shard):
         rt = RefreshRuntime(shard_refresh=shard)
-        mesh = compat.make_mesh((4,), ('data',))
+        mesh = make_mesh((4,), ('data',))
         state = opt.init(params, Extras(stats=stats(0), sched=rt))
 
         def body(g, s, st):
             return opt.update(g, s, extras=Extras(stats=st, sched=rt))
 
-        step = jax.jit(compat.shard_map(
+        step = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=(P(), P(), P()), out_specs=(P(), P()),
-            check=False))
+            check_vma=False))
         outs = []
         for t in range(STEPS):
             out, state = step(grads(t), state, stats(t))

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.sharding import compat
+from repro.launch.mesh import make_mesh
 
 from repro.configs.registry import demo_lm
 from repro.core.registry import make_optimizer
@@ -79,7 +79,7 @@ def test_preemption_checkpoints_and_exits(tmp_path):
 def test_compressed_dp_matches_uncompressed_closely():
     cfg, model, params, data = _setup()
     opt, capture = make_optimizer('eva', lr=0.05)
-    mesh = compat.make_mesh((1,), ('data',))
+    mesh = make_mesh((1,), ('data',))
     losses = {}
     for compress in (False, True):
         step_fn, init_err = make_dp_train_step(model, opt, capture, mesh,
