@@ -71,22 +71,23 @@ def kl_clip_trace(kappa: float = 1e-3, lr: Schedule = 0.1,
 
     def update(updates, state, params=None, extras: Extras | None = None):
         del params
-        m = jax.tree_util.tree_map(
-            lambda mm, g: momentum * mm + g.astype(jnp.float32),
-            state.trace, updates)
-        if nesterov:
-            u = jax.tree_util.tree_map(
-                lambda g, mm: g.astype(jnp.float32) + momentum * mm,
-                updates, m)
-        else:
-            u = m
-        alpha = _lr_at(lr, extras.step)
-        kl = jnp.maximum(tree_vdot(u, extras.raw_grads), 0.0)
-        nu = jnp.minimum(1.0, jnp.sqrt(
-            kappa / jnp.maximum(alpha * alpha * kl, 1e-20)))
-        out = jax.tree_util.tree_map(lambda x: x * nu, u)
-        stored = out if not nesterov else jax.tree_util.tree_map(
-            lambda x: x * nu, m)
+        with jax.named_scope('kl_clip'):
+            m = jax.tree_util.tree_map(
+                lambda mm, g: momentum * mm + g.astype(jnp.float32),
+                state.trace, updates)
+            if nesterov:
+                u = jax.tree_util.tree_map(
+                    lambda g, mm: g.astype(jnp.float32) + momentum * mm,
+                    updates, m)
+            else:
+                u = m
+            alpha = _lr_at(lr, extras.step)
+            kl = jnp.maximum(tree_vdot(u, extras.raw_grads), 0.0)
+            nu = jnp.minimum(1.0, jnp.sqrt(
+                kappa / jnp.maximum(alpha * alpha * kl, 1e-20)))
+            out = jax.tree_util.tree_map(lambda x: x * nu, u)
+            stored = out if not nesterov else jax.tree_util.tree_map(
+                lambda x: x * nu, m)
         return out, TraceState(trace=stored)
 
     return GradientTransformation(init, update)
@@ -121,12 +122,13 @@ def finish_kl_clip(u, kl, step, kappa: float, lr: Schedule, m=None):
     ⟨u, raw_grads⟩ scalar.  Returns ``(out, stored)`` = (ν·u, ν·(m or u))
     — exactly ``kl_clip_trace``'s tail (``m`` only differs under nesterov).
     """
-    alpha = _lr_at(lr, step)
-    kl = jnp.maximum(kl, 0.0)
-    nu = jnp.minimum(1.0, jnp.sqrt(
-        kappa / jnp.maximum(alpha * alpha * kl, 1e-20)))
-    out = _tree_map(lambda x: x * nu, u)
-    stored = out if m is None else _tree_map(lambda x: x * nu, m)
+    with jax.named_scope('kl_clip'):
+        alpha = _lr_at(lr, step)
+        kl = jnp.maximum(kl, 0.0)
+        nu = jnp.minimum(1.0, jnp.sqrt(
+            kappa / jnp.maximum(alpha * alpha * kl, 1e-20)))
+        out = _tree_map(lambda x: x * nu, u)
+        stored = out if m is None else _tree_map(lambda x: x * nu, m)
     return out, stored
 
 

@@ -127,12 +127,14 @@ def _kv_step(state, updates, extras, *, fields, site, policy, interval,
     flat = kvlib.flatten_params(updates)
     fresh_flat = _extract(extras.stats, fields)
     plan = _stats_plan(flat, fresh_flat, extras)
-    fresh, pipe_stats = pipemod.staged_pmean(
-        bucketing.gather_tree(plan, fresh_flat),
-        None if pipe is None else pipe['stats'], site=site)
-    stats, running = kvlib.update_running(state.running, fresh, kv_decay)
-    used, sched, cached = _refresh_snapshot(pol, state.sched, stats,
-                                            state.cached)
+    with jax.named_scope('kv'):
+        fresh, pipe_stats = pipemod.staged_pmean(
+            bucketing.gather_tree(plan, fresh_flat),
+            None if pipe is None else pipe['stats'], site=site)
+        stats, running = kvlib.update_running(state.running, fresh,
+                                              kv_decay)
+        used, sched, cached = _refresh_snapshot(pol, state.sched, stats,
+                                                state.cached)
     return flat, plan, used, dict(
         running=running, cached=cached, sched=sched,
         pipe=None if pipe is None else {'stats': pipe_stats})

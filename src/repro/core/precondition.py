@@ -259,24 +259,25 @@ def precondition_tree(updates: dict, aux: dict, method: str, gamma: float, *,
             return apply_two_sided(g, st.a_outer, st.b_outer)
         raise ValueError(f'unknown method {method!r}')
 
-    out = dict(updates)
-    big = [b for b in plan.buckets if b.stacked]
-    if big:
-        sub = bucketing.BucketPlan(buckets=tuple(big))
-        aux_b = {b.key: aux[b.key] for b in big} if aux_is_bucketed \
-            else bucketing.gather_tree(sub, aux)
-        g_b = bucketing.gather(sub, {p: updates[p] for p in sub.paths})
-        out_b = {b.key: one_bucket(b, g_b[b.key], aux_b[b.key], True)
-                 for b in big}
-        out.update(bucketing.scatter(sub, out_b))
-    for b in plan.buckets:
-        if b.stacked:
-            continue
-        for i, p in enumerate(b.paths):
-            st = jax.tree_util.tree_map(lambda x, i=i: x[i], aux[b.key]) \
-                if aux_is_bucketed else aux[p]
-            out[p] = one_bucket(b, updates[p], st, False)
-    return out
+    with jax.named_scope('precondition'):
+        out = dict(updates)
+        big = [b for b in plan.buckets if b.stacked]
+        if big:
+            sub = bucketing.BucketPlan(buckets=tuple(big))
+            aux_b = {b.key: aux[b.key] for b in big} if aux_is_bucketed \
+                else bucketing.gather_tree(sub, aux)
+            g_b = bucketing.gather(sub, {p: updates[p] for p in sub.paths})
+            out_b = {b.key: one_bucket(b, g_b[b.key], aux_b[b.key], True)
+                     for b in big}
+            out.update(bucketing.scatter(sub, out_b))
+        for b in plan.buckets:
+            if b.stacked:
+                continue
+            for i, p in enumerate(b.paths):
+                st = jax.tree_util.tree_map(lambda x, i=i: x[i], aux[b.key]) \
+                    if aux_is_bucketed else aux[p]
+                out[p] = one_bucket(b, updates[p], st, False)
+        return out
 
 
 def precondition_tree_fused(updates: dict, aux: dict, method: str,
@@ -335,40 +336,41 @@ def precondition_tree_fused(updates: dict, aux: dict, method: str,
         return kops.eva_fused(g, st.a_mean, st.b_mean, gamma, m, mu,
                               fold_momentum=fold_momentum, impl=impl)
 
-    out, partials = {}, {}
-    big = [b for b in plan.buckets if b.stacked]
-    if big:
-        sub = bucketing.BucketPlan(buckets=tuple(big))
-        aux_b = {b.key: aux[b.key] for b in big} if aux_is_bucketed \
-            else bucketing.gather_tree(sub, aux)
-        g_b = bucketing.gather(sub, {p: updates[p] for p in sub.paths})
-        m_b = bucketing.gather(sub, {p: m_for(p) for p in sub.paths})
-        for b in big:
-            o, ax = run(g_b[b.key], aux_b[b.key], m_b[b.key])
+    with jax.named_scope('precondition'):
+        out, partials = {}, {}
+        big = [b for b in plan.buckets if b.stacked]
+        if big:
+            sub = bucketing.BucketPlan(buckets=tuple(big))
+            aux_b = {b.key: aux[b.key] for b in big} if aux_is_bucketed \
+                else bucketing.gather_tree(sub, aux)
+            g_b = bucketing.gather(sub, {p: updates[p] for p in sub.paths})
+            m_b = bucketing.gather(sub, {p: m_for(p) for p in sub.paths})
+            for b in big:
+                o, ax = run(g_b[b.key], aux_b[b.key], m_b[b.key])
+                for i, p in enumerate(b.paths):
+                    out[p] = o[i]
+                    # scan-stacked leaves carry (S, 3) partials; the epilogue
+                    # scalars are per *tree leaf*, so sum the item dims away
+                    partials[p] = ax[i].reshape(-1, 3).sum(axis=0)
+        for b in plan.buckets:
+            if b.stacked:
+                continue
             for i, p in enumerate(b.paths):
-                out[p] = o[i]
-                # scan-stacked leaves carry (S, 3) partials; the epilogue
-                # scalars are per *tree leaf*, so sum the item dims away
-                partials[p] = ax[i].reshape(-1, 3).sum(axis=0)
-    for b in plan.buckets:
-        if b.stacked:
-            continue
-        for i, p in enumerate(b.paths):
-            st = jax.tree_util.tree_map(lambda x, i=i: x[i], aux[b.key]) \
-                if aux_is_bucketed else aux[p]
-            o, ax = run(updates[p], st, m_for(p))
+                st = jax.tree_util.tree_map(lambda x, i=i: x[i], aux[b.key]) \
+                    if aux_is_bucketed else aux[p]
+                o, ax = run(updates[p], st, m_for(p))
+                out[p] = o
+                partials[p] = ax.reshape(-1, 3).sum(axis=0)
+        pre_paths = set(plan.paths)
+        for p, g in updates.items():
+            if p in pre_paths:
+                continue
+            g32 = g.astype(jnp.float32)
+            o = mu * m_for(p) + g32 if fold_momentum else g32
             out[p] = o
-            partials[p] = ax.reshape(-1, 3).sum(axis=0)
-    pre_paths = set(plan.paths)
-    for p, g in updates.items():
-        if p in pre_paths:
-            continue
-        g32 = g.astype(jnp.float32)
-        o = mu * m_for(p) + g32 if fold_momentum else g32
-        out[p] = o
-        partials[p] = jnp.stack([jnp.sum(o * g32), jnp.sum(o * o),
-                                 jnp.sum(g32 * g32)])
-    return out, partials
+            partials[p] = jnp.stack([jnp.sum(o * g32), jnp.sum(o * o),
+                                     jnp.sum(g32 * g32)])
+        return out, partials
 
 
 def apply_left(g: jnp.ndarray, op_in: jnp.ndarray) -> jnp.ndarray:

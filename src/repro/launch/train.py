@@ -43,8 +43,9 @@ def main(argv: Optional[Sequence[str]] = None) -> list:
     ap.add_argument('--ckpt-every', type=int, default=25)
     ap.add_argument('--log-every', type=int, default=10)
     ap.add_argument('--profile', action='store_true',
-                    help='span-fenced phased step + memory/HLO telemetry '
-                         '(repro.obs; slight overhead, donation off)')
+                    help='span records (data/dispatch/wait/host per step) '
+                         '+ memory/HLO telemetry (repro.obs); the step and '
+                         'donation are unchanged')
     ap.add_argument('--head-policy', default='dense',
                     choices=['dense', 'exclude', 'shard'],
                     help='oversized-factor policy (core.factor_sharded): '

@@ -2,8 +2,9 @@
 
 ``events``  — versioned record schemas + the JSONL ``Recorder`` (owns the
               run-scoped comm-counter context).
-``spans``   — host-timed phase spans with ``block_until_ready`` fences,
-              the straggler watchdog, profile-mode samplers.
+``spans``   — the training loop's host spans on the profiler's trace
+              (``train.*``), the straggler watchdog, profile-mode
+              samplers.
 ``report``  — breakdown / A-vs-B diff / validation CLI core
               (``scripts/obs_report.py``).
 """

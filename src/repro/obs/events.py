@@ -123,7 +123,8 @@ SCHEMAS: dict[str, dict[str, Field]] = {
         'median_s': Field(_NUM, required=True, unit='s'),
         'factor': Field(_NUM, unit='trigger threshold x median'),
     },
-    # one host-timed phase span (block_until_ready-fenced)
+    # one host span of the training loop (profile mode): data, dispatch,
+    # wait (loss read-back) or host; nothing waits on the device for it
     'span': {
         'name': Field(_STR, required=True),
         'ms': Field(_NUM, required=True, unit='ms'),
@@ -132,7 +133,7 @@ SCHEMAS: dict[str, dict[str, Field]] = {
         'depth': Field(_INT, unit='nesting depth'),
         'parent': Field(_STR + (type(None),)),
     },
-    # profile-mode sample: live buffers + one-shot HLO costs per fn
+    # profile-mode sample: live buffers + one-shot HLO costs of the step
     'profile': {
         'step': Field(_INT, required=True, unit='index'),
         'live_buffer_mb': Field(_NUM, unit='MiB'),

@@ -16,10 +16,13 @@ Exit codes: 0 ok · 1 schema-validation errors · 2 gated regression.
 
 Phase-attribution notes (honest accounting, also in the README):
   * span times exist only for profiled runs; the first step's spans are
-    dropped (compile);
+    dropped (compile).  They are the loop's host phases (``data``,
+    ``dispatch``, ``wait`` for the device and the loss read-back, ``host``);
+    the device's own split (forward, backward, optimizer, ...) is in the
+    profiler's trace under the step's named scopes;
   * ``refresh`` time is the firing-vs-cached step-time differential — it
-    runs *inside* the precondition phase, so it is a sub-row, not an
-    addend;
+    runs on the device, *inside* the wait phase, so it is a sub-row, not
+    an addend;
   * ``exchange`` is reported in logical bytes (exact, from trace-time
     counters); its wall time on a single host is ~0 (no live mesh axes →
     no collectives) and on a real mesh is visible via the profile record's
@@ -194,8 +197,7 @@ def _mib(n_bytes: float) -> str:
     return f'{n_bytes / 2**20:.2f} MiB'
 
 
-_PHASE_ORDER = ('data', 'grad', 'precondition', 'refresh', 'exchange',
-                'apply', 'step')
+_PHASE_ORDER = ('data', 'dispatch', 'wait', 'refresh', 'exchange', 'host')
 
 
 def render(bd: dict, title: str = '') -> str:
@@ -227,8 +229,12 @@ def render(bd: dict, title: str = '') -> str:
     if phases or refresh or exch:
         out.append('')
         out.append(f"{'phase':<14} {'ms/step':>10} {'share':>7}   bytes")
+        # shares of the step: its own span where a run has one, else the
+        # loop's four phases together, else the dispatch-to-loss time
+        loop = [phases[n]['mean_ms'] for n in ('data', 'dispatch', 'wait',
+                                               'host') if n in phases]
         step_ms = (phases.get('step', {}).get('mean_ms')
-                   or bd.get('mean_step_ms'))
+                   or sum(loop) or bd.get('mean_step_ms'))
 
         def row(name, ms, byt='-', note=''):
             share = (f'{100 * ms / step_ms:.1f}%'
@@ -243,7 +249,7 @@ def render(bd: dict, title: str = '') -> str:
                     note = f"  ({refresh.get('count', '?')} realized"
                     if 'extra_ms_per_refresh' in refresh:
                         note += (f", +{refresh['extra_ms_per_refresh']:.3f}"
-                                 ' ms each, inside precondition')
+                                 ' ms each, inside wait')
                     note += ')'
                     byt = (_mib(exch['refresh_bytes']) + '/refresh'
                            if exch.get('refresh_bytes') else '-')
@@ -254,7 +260,7 @@ def render(bd: dict, title: str = '') -> str:
                     if exch.get('refresh_bytes'):
                         byt += f" + {_mib(exch['refresh_bytes'])}/refresh"
                     row('exchange', None, byt,
-                        '  (logical, traced; time inside grad+precondition)')
+                        '  (logical, traced; time inside wait)')
             elif name in phases:
                 row(name, phases[name]['mean_ms'])
         for name in sorted(set(phases) - set(_PHASE_ORDER)):

@@ -1,19 +1,21 @@
-"""Phase spans, the straggler watchdog, and profile-mode samplers.
+"""Host spans of the training loop, the straggler watchdog, and
+profile-mode samplers.
 
-Spans are host-timed phase windows (data/grad/precondition/refresh/
-exchange/apply/step) around pieces of the jitted step.  JAX dispatch is
-async, so a naive ``perf_counter`` pair around a jitted call measures
-dispatch, not compute — each span therefore carries an optional *fence*:
-the device outputs produced inside the span, passed to
-``jax.block_until_ready`` before the clock stops.  This is donate-safe
-(blocking reads nothing back; it only waits), but fencing at phase
-granularity does serialize phases the scheduler could otherwise overlap —
-which is why span timing lives behind the trainer's ``profile`` flag
-instead of always-on (README "Observability" has the measured overhead).
+``SpanTracker.span(name, step)`` enters a
+``jax.profiler.TraceAnnotation('train.<name>', step=step)``: under any
+``jax.profiler`` capture the span lands on the host plane of the same
+``.xplane.pb`` as the device's ops, on one clock, and costs a flag check
+when no profiler runs.  The span waits on nothing: JAX dispatch is
+asynchronous, so its host time is the time the loop spent there (for
+``wait``, the device's remaining work plus the read-back), and the device
+side of the step is read from the device plane, where ``jax.named_scope``
+names every op (``train/step.py``).  Built with a recorder (the trainer's
+``profile`` mode), the tracker also keeps each closed span in ``records``
+and emits it as a ``span`` record; without one it keeps nothing.
 
 Profile mode additionally samples per-step live-buffer bytes
 (``jax.live_arrays``), device-memory stats where the backend has them, and
-a one-shot HLO cost + blocking-collective summary per compiled fn
+a one-shot HLO cost + blocking-collective summary of the compiled step
 (``launch/hlo_analysis``).
 """
 from __future__ import annotations
@@ -21,29 +23,16 @@ from __future__ import annotations
 import contextlib
 import statistics
 import time
-from typing import Any, Iterator, Optional
+from typing import Iterator, Optional
 
 import jax
 
 from repro.obs import events
 
 
-class SpanHandle:
-    """Yielded by ``SpanTracker.span``; ``fence(x)`` registers the device
-    values the span must wait on before its clock stops."""
-
-    __slots__ = ('_fence',)
-
-    def __init__(self) -> None:
-        self._fence: Any = None
-
-    def fence(self, x: Any) -> Any:
-        self._fence = x
-        return x
-
-
 class SpanTracker:
-    """Emits one ``span`` record per closed span, with nesting metadata
+    """Named host spans on the profiler's trace; with a recorder, also one
+    ``span`` record per closed span, with nesting metadata
     (``depth``/``parent``) and a global emission order (``seq``)."""
 
     def __init__(self, recorder: Optional[events.Recorder] = None,
@@ -55,27 +44,25 @@ class SpanTracker:
         self._seq = 0
 
     @contextlib.contextmanager
-    def span(self, name: str, step: Optional[int] = None
-             ) -> Iterator[SpanHandle]:
-        handle = SpanHandle()
-        parent = self._stack[-1] if self._stack else None
-        depth = len(self._stack)
-        self._stack.append(name)
-        t0 = self._clock()
-        try:
-            yield handle
-        finally:
-            if handle._fence is not None:
-                jax.block_until_ready(handle._fence)
-            ms = (self._clock() - t0) * 1e3
-            self._stack.pop()
-            rec = {'name': name, 'ms': round(ms, 4), 'seq': self._seq,
-                   'depth': depth, 'parent': parent}
-            if step is not None:
-                rec['step'] = int(step)
-            self._seq += 1
-            self.records.append(rec)
-            if self.recorder is not None:
+    def span(self, name: str, step: Optional[int] = None) -> Iterator[None]:
+        kw = {} if step is None else {'step': int(step)}
+        with jax.profiler.TraceAnnotation(f'train.{name}', **kw):
+            if self.recorder is None:
+                yield
+                return
+            parent = self._stack[-1] if self._stack else None
+            depth = len(self._stack)
+            self._stack.append(name)
+            t0 = self._clock()
+            try:
+                yield
+            finally:
+                ms = (self._clock() - t0) * 1e3
+                self._stack.pop()
+                rec = {'name': name, 'ms': round(ms, 4), 'seq': self._seq,
+                       'depth': depth, 'parent': parent, **kw}
+                self._seq += 1
+                self.records.append(rec)
                 self.recorder.emit('span', **rec)
 
 

@@ -72,29 +72,60 @@ def _default_make_taps(model, params, capture: kvlib.CaptureConfig):
 def compute_grads_and_stats(model, params, batch,
                             capture: kvlib.CaptureConfig,
                             taps: Optional[dict] = None):
-    """Shared by train_step and abstract shape derivation."""
+    """Shared by train_step and abstract shape derivation.
+
+    The loss runs under ``named_scope('forward')`` inside the
+    differentiated function, so the compiled step names its forward ops
+    ``jvp(forward)/...`` and their transposes (the backward pass, ``remat``
+    recompute included) ``transpose(jvp(forward))/...``; the statistics'
+    finalization is ``capture/...``.  These names, with the
+    ``optimizer``/``kv``/``precondition``/``kl_clip``/``apply``/``metrics``
+    /``exchange`` scopes of the step factories and ``core/``, are what the
+    benchmark's trace reduction groups device time by."""
     if capture.needs_taps:
         if taps is None:
             taps = _default_make_taps(model, params, capture)
 
         def lf(p, t):
-            return model.loss_fn(p, t, batch, capture)
+            with jax.named_scope('forward'):
+                return model.loss_fn(p, t, batch, capture)
 
         (loss, aux), (grads, tap_grads) = jax.value_and_grad(
             lf, argnums=(0, 1), has_aux=True)(params, taps)
     else:
         def lf(p):
-            return model.loss_fn(p, None, batch, capture)
+            with jax.named_scope('forward'):
+                return model.loss_fn(p, None, batch, capture)
 
         (loss, aux), grads = jax.value_and_grad(lf, has_aux=True)(params)
         tap_grads = None
 
     stats = None
     if capture.active:
-        stats = kvlib.finalize_stats(aux['stats'], tap_grads, capture,
-                                     n_tokens=jnp.asarray(aux['n_tokens'],
-                                                          jnp.float32))
+        with jax.named_scope('capture'):
+            stats = kvlib.finalize_stats(
+                aux['stats'], tap_grads, capture,
+                n_tokens=jnp.asarray(aux['n_tokens'], jnp.float32))
     return loss, grads, stats
+
+
+def _step_metrics(loss, grads, new_opt_state) -> dict:
+    """The step's metrics: loss, global gradient norm, and the refresh,
+    pipeline and sharded-factor telemetry of the new state."""
+    with jax.named_scope('metrics'):
+        grad_norm = jnp.sqrt(sum(
+            jnp.sum(jnp.square(g.astype(jnp.float32)))
+            for g in jax.tree_util.tree_leaves(grads)))
+        metrics = {'loss': loss, 'grad_norm': grad_norm}
+        # refresh-runtime observability: cumulative refreshes / staleness
+        # of every scheduled transform in the state ({} for unscheduled
+        # opts)
+        metrics.update(schedrt.schedule_metrics(new_opt_state))
+        # realized pipeline staleness per exchange site ({} in sync mode)
+        metrics.update(pipemod.pipeline_metrics(new_opt_state))
+        # sharded-factor telemetry ({} unless a factor policy tripped)
+        metrics.update(fsh.step_metrics(new_opt_state))
+    return metrics
 
 
 def make_train_step(model, opt: GradientTransformation,
@@ -175,24 +206,17 @@ def make_train_step(model, opt: GradientTransformation,
         else:
             loss, grads, stats = grads_of(params, batch)
 
-        updates, new_opt_state = opt.update(
-            grads, opt_state, params=params,
-            extras=Extras(stats=stats, loss=loss,
-                          plan=_plan_for_stats(grads, stats), sched=sched,
-                          comm=comm, factor=factor, kernel=kernel))
-        new_params = apply_updates(params, updates)
-        grad_norm = jnp.sqrt(sum(
-            jnp.sum(jnp.square(g.astype(jnp.float32)))
-            for g in jax.tree_util.tree_leaves(grads)))
-        metrics = {'loss': loss, 'grad_norm': grad_norm}
-        # refresh-runtime observability: cumulative refreshes / staleness of
-        # every scheduled transform in the state ({} for unscheduled opts)
-        metrics.update(schedrt.schedule_metrics(new_opt_state))
-        # realized pipeline staleness per exchange site ({} in sync mode)
-        metrics.update(pipemod.pipeline_metrics(new_opt_state))
-        # sharded-factor telemetry ({} unless a factor policy tripped)
-        metrics.update(fsh.step_metrics(new_opt_state))
-        return new_params, new_opt_state, metrics
+        with jax.named_scope('optimizer'):
+            updates, new_opt_state = opt.update(
+                grads, opt_state, params=params,
+                extras=Extras(stats=stats, loss=loss,
+                              plan=_plan_for_stats(grads, stats),
+                              sched=sched, comm=comm, factor=factor,
+                              kernel=kernel))
+        with jax.named_scope('apply'):
+            new_params = apply_updates(params, updates)
+        return new_params, new_opt_state, _step_metrics(
+            loss, grads, new_opt_state)
 
     return train_step
 
@@ -245,79 +269,25 @@ def make_dp_step(model, opt: GradientTransformation,
             return jax.tree_util.tree_map(lambda m, x: m.astype(x.dtype),
                                           mean, tree)
 
-        grads = mean_over_workers(grads, 'grads/dp')
-        if stats is not None:
-            stats = mean_over_workers(stats, 'stats/dp')
-        updates, new_opt_state = opt.update(
-            grads, opt_state, params=params,
-            extras=Extras(stats=stats, loss=loss,
-                          plan=_plan_for_stats(grads, stats), sched=sched,
-                          comm=comm, factor=factor, kernel=kernel))
-        new_params = apply_updates(params, updates)
-        grad_norm = jnp.sqrt(sum(
-            jnp.sum(jnp.square(g.astype(jnp.float32)))
-            for g in jax.tree_util.tree_leaves(grads)))
-        metrics = {'loss': loss, 'grad_norm': grad_norm}
-        metrics.update(schedrt.schedule_metrics(new_opt_state))
-        metrics.update(pipemod.pipeline_metrics(new_opt_state))
-        metrics.update(fsh.step_metrics(new_opt_state))
-        return new_params, new_opt_state, metrics
+        with jax.named_scope('exchange'):
+            grads = mean_over_workers(grads, 'grads/dp')
+            if stats is not None:
+                stats = mean_over_workers(stats, 'stats/dp')
+        with jax.named_scope('optimizer'):
+            updates, new_opt_state = opt.update(
+                grads, opt_state, params=params,
+                extras=Extras(stats=stats, loss=loss,
+                              plan=_plan_for_stats(grads, stats),
+                              sched=sched, comm=comm, factor=factor,
+                              kernel=kernel))
+        with jax.named_scope('apply'):
+            new_params = apply_updates(params, updates)
+        return new_params, new_opt_state, _step_metrics(
+            loss, grads, new_opt_state)
 
     return jax.shard_map(local_step, mesh=mesh,
                          in_specs=(P(), P(), P('data')),
                          out_specs=(P(), P(), P()), check_vma=False)
-
-
-def make_phased_step(model, opt: GradientTransformation,
-                     capture: kvlib.CaptureConfig,
-                     taps_fn: Optional[Callable] = None,
-                     sched: Optional[schedrt.RefreshRuntime] = None,
-                     comm: Optional[Any] = None,
-                     factor: Optional[Any] = None,
-                     kernel: Optional[Any] = None
-                     ) -> tuple[Callable, Callable, Callable]:
-    """The train step split at phase boundaries for span-level timing
-    (``repro.obs``): grad → precondition (= optimizer update, where the
-    curvature refresh/exchange live) → apply.
-
-    Returns ``(grad_fn, update_fn, apply_fn)`` with
-      ``grad_fn(params, batch) -> (loss, grads, stats)``
-      ``update_fn(grads, stats, loss, opt_state, params)
-          -> (updates, new_opt_state, metrics)``
-      ``apply_fn(params, updates) -> new_params``
-    whose composition is semantically identical to
-    ``make_train_step(microbatches=1)``.  Each piece jits separately so a
-    host-side span with a ``block_until_ready`` fence can attribute wall
-    time per phase; nothing is donated (profile mode trades the in-place
-    update for measurability — see the README overhead caveats).
-    """
-    sched = sched if sched is not None else schedrt.RefreshRuntime()
-    make_taps = taps_caller(taps_fn)
-
-    def grad_fn(params, batch):
-        return compute_grads_and_stats(model, params, batch, capture,
-                                       make_taps(params, batch))
-
-    def update_fn(grads, stats, loss, opt_state, params):
-        updates, new_opt_state = opt.update(
-            grads, opt_state, params=params,
-            extras=Extras(stats=stats, loss=loss,
-                          plan=_plan_for_stats(grads, stats), sched=sched,
-                          comm=comm, factor=factor, kernel=kernel))
-        grad_norm = jnp.sqrt(sum(
-            jnp.sum(jnp.square(g.astype(jnp.float32)))
-            for g in jax.tree_util.tree_leaves(grads)))
-        metrics = {'loss': loss, 'grad_norm': grad_norm}
-        metrics.update(schedrt.schedule_metrics(new_opt_state))
-        metrics.update(pipemod.pipeline_metrics(new_opt_state))
-        # sharded-factor telemetry ({} unless a factor policy tripped)
-        metrics.update(fsh.step_metrics(new_opt_state))
-        return updates, new_opt_state, metrics
-
-    def apply_fn(params, updates):
-        return apply_updates(params, updates)
-
-    return grad_fn, update_fn, apply_fn
 
 
 def init_opt_state(model, opt: GradientTransformation,

@@ -14,11 +14,16 @@ Features:
     is schema-typed and versioned; comm-site attribution uses the
     recorder's run-scoped counter context instead of baselining the
     process-global table,
-  * ``profile`` mode: the step runs as phased jitted fns
-    (grad/precondition/apply) under ``block_until_ready``-fenced spans,
-    with per-step live-buffer samples and a one-shot HLO cost record per
-    fn.  Off by default — fencing serializes phases (see README
-    "Observability" for the measured overhead) and disables donation.
+  * named phases on the profiler's trace: every step of both loops is a
+    ``StepTraceAnnotation('train')`` holding four host spans,
+    ``train.data`` (``batch_at``), ``train.dispatch`` (the jitted call),
+    ``train.wait`` (the loss read-back, the one sync point) and
+    ``train.host`` (the rest); the step's device ops carry the
+    ``jax.named_scope`` names of ``train/step.py``.  Both cost nothing
+    without a running profiler, and neither waits on the device,
+  * ``profile`` mode: the same donated step and loop, plus a ``span``
+    record per span, per-step live-buffer samples and a one-shot HLO cost
+    record of the compiled step.
 
 Elasticity: checkpoints are world-agnostic (full logical arrays + the
 ``elastic`` metadata block — see docs/CHECKPOINT_FORMAT.md for the on-disk
@@ -47,8 +52,7 @@ from repro.schedule import reshard as reshard_mod
 from repro.schedule import runtime as schedrt
 from repro.train import checkpoint as ckpt
 from repro.train.step import (init_opt_state, make_dp_step,
-                              make_phased_step, make_train_step,
-                              stats_plan_of)
+                              make_train_step, stats_plan_of)
 
 
 @dataclasses.dataclass
@@ -60,8 +64,7 @@ class TrainerConfig:
     out_dir: str = 'runs/default'
     straggler_factor: float = 3.0
     donate: bool = True
-    profile: bool = False          # span-fenced phased step + memory/HLO
-                                   # records (forces donation off)
+    profile: bool = False          # span records + memory/HLO samples
 
 
 class Trainer:
@@ -94,15 +97,7 @@ class Trainer:
                                   sched=self.sched, comm=comm, factor=factor,
                                   kernel=kernel)
         self.step_fn = jax.jit(step_fn,
-                               donate_argnums=(0, 1)
-                               if cfg.donate and not cfg.profile else ())
-        self._phases = None
-        if cfg.profile:
-            # span timing needs phase boundaries; fences read nothing back
-            # but donation is off so a fenced phase's inputs stay alive
-            self._phases = tuple(jax.jit(f) for f in make_phased_step(
-                model, opt, capture, taps_fn=taps_fn, sched=self.sched,
-                comm=comm, factor=factor, kernel=kernel))
+                               donate_argnums=(0, 1) if cfg.donate else ())
         self._watchdog = obs_spans.StragglerWatchdog(cfg.straggler_factor)
         self._preempted = False
         self.metrics_path = self.out_dir / 'metrics.jsonl'
@@ -152,43 +147,36 @@ class Trainer:
             except ValueError:
                 pass  # not in main thread (tests)
 
-    # -- profile-mode step ----------------------------------------------------
+    # -- one step of either loop -------------------------------------------
 
-    def _profiled_step(self, tracker, step, data, params, opt_state):
-        """One step through the phased fns under fenced spans.  Returns the
-        same (params, opt_state, metrics) as the fused step, plus the
-        intermediates the one-shot HLO record needs."""
-        grad_fn, update_fn, apply_fn = self._phases
-        with tracker.span('step', step=step) as sp_all:
-            with tracker.span('data', step=step):
-                batch = data.batch_at(step)
-            with tracker.span('grad', step=step) as sp:
-                loss, grads, stats = grad_fn(params, batch)
-                sp.fence((loss, grads))
-            with tracker.span('precondition', step=step) as sp:
-                updates, opt_state, metrics = update_fn(grads, stats, loss,
-                                                        opt_state, params)
-                sp.fence(updates)
-            with tracker.span('apply', step=step) as sp:
-                params = apply_fn(params, updates)
-                sp.fence(params)
-            sp_all.fence(params)
-        phase_args = {'grad': (grad_fn, (params, batch)),
-                      'precondition': (update_fn, (grads, stats, loss,
-                                                   opt_state, params)),
-                      'apply': (apply_fn, (params, updates))}
-        return params, opt_state, metrics, phase_args
+    @staticmethod
+    def _run_step(tracker, step_fn, data, step, params, opt_state,
+                  check=None):
+        """Fetch, dispatch and read back one step under the ``data``,
+        ``dispatch`` and ``wait`` spans.  ``dt`` runs from the dispatch to
+        the loss on the host, as the watchdog and the step record count
+        it."""
+        with tracker.span('data', step):
+            batch = data.batch_at(step)
+            if check is not None:
+                check(batch)
+        t0 = time.perf_counter()
+        with tracker.span('dispatch', step):
+            params, opt_state, metrics = step_fn(params, opt_state, batch)
+        with tracker.span('wait', step):
+            loss = float(metrics['loss'])  # sync point
+        return params, opt_state, metrics, batch, loss, \
+            time.perf_counter() - t0
 
-    def _emit_profile(self, recorder, step, phase_args, one_shot_hlo):
+    def _emit_profile(self, recorder, step, step_fn, args, one_shot_hlo):
         rec: dict[str, Any] = {'step': step,
                                'live_buffer_mb': obs_spans.live_buffer_mb()}
         dev = obs_spans.device_bytes_in_use()
         if dev is not None:
             rec['device_bytes_in_use'] = dev
         if one_shot_hlo:
-            rec['fns'] = {
-                name: obs_spans.compiled_fn_costs(fn, *args)
-                for name, (fn, args) in phase_args.items()}
+            rec['fns'] = {'train_step':
+                          obs_spans.compiled_fn_costs(step_fn, *args)}
         recorder.emit('profile', **rec)
 
     # -- main loop ------------------------------------------------------------
@@ -230,7 +218,7 @@ class Trainer:
         base_sched = schedrt.schedule_metrics(opt_state)
         ref_base = int(base_sched['refreshes']) if base_sched else 0
 
-        if cfg.donate and not cfg.profile:
+        if cfg.donate:
             # the jitted step donates its inputs; don't delete caller-owned
             # buffers (callers may reuse the initial params across runs)
             params = jax.tree_util.tree_map(
@@ -243,97 +231,99 @@ class Trainer:
         # re-traces nothing → fall back to the previous fit's sites).
         recorder = obs_events.Recorder(self.metrics_path)
         self._watchdog.recorder = recorder
-        tracker = obs_spans.SpanTracker(recorder)
+        tracker = obs_spans.SpanTracker(recorder if cfg.profile else None)
         self._log_ownership(recorder, params, data.batch_at(start_step))
         history = []
         prev_ref = ref_base
         step = start_step
         try:
             for step in range(start_step, cfg.total_steps):
-                if self._phases is not None:
-                    t0 = time.perf_counter()
-                    params, opt_state, metrics, phase_args = \
-                        self._profiled_step(tracker, step, data, params,
-                                            opt_state)
-                    loss = float(metrics['loss'])
-                    dt = time.perf_counter() - t0
-                else:
-                    batch = data.batch_at(step)
-                    t0 = time.perf_counter()
-                    params, opt_state, metrics = self.step_fn(params,
-                                                              opt_state,
-                                                              batch)
-                    loss = float(metrics['loss'])  # sync point
-                    dt = time.perf_counter() - t0
-                if step == start_step:
-                    fresh = recorder.comm_sites()
-                    if fresh:
-                        self._run_sites = fresh
-                    self._log_comm(recorder, getattr(self, '_run_sites', {}))
-                self._watchdog.observe(step, dt)
-                history.append(loss)
-                sched_fields = obs_events.step_fields(metrics)
-                if 'refreshes' in sched_fields:
-                    cur_ref = sched_fields['refreshes']
-                    if cur_ref > prev_ref:
-                        recorder.emit('refresh', step=step,
-                                      refreshes=cur_ref,
-                                      step_time_s=round(dt, 6))
-                    prev_ref = cur_ref
-                if step % cfg.log_every == 0 or step == cfg.total_steps - 1:
-                    rec = {'step': step, 'loss': loss,
-                           'grad_norm': float(metrics['grad_norm']),
-                           'step_time_s': round(dt, 4), **sched_fields}
-                    sched_line = ''
-                    if 'refreshes' in rec:
-                        sched_line = (f" refreshes {rec['refreshes']}"
-                                      f" staleness {rec['staleness']:.3g}")
-                    if 'pipeline_lag' in rec:
-                        sched_line += f" lag {rec['pipeline_lag']}"
-                    # cumulative exchanged bytes, from THIS trainer's comm
-                    # sites: per-step sites (grads/stats) fire every
-                    # step, refresh sites once per realized refresh
-                    sites = getattr(self, '_run_sites', {})
-                    if sites:
-                        step_b = sum(v['bytes_per_call']
-                                     for s, v in sites.items()
-                                     if not s.startswith('refresh/'))
-                        refresh_b = sum(v['bytes_per_call']
-                                        for s, v in sites.items()
-                                        if s.startswith('refresh/'))
-                        rec['exchanged_mb_cum'] = round(
-                            (step_b * (step + 1 - start_step)
-                             + refresh_b * (rec.get('refreshes', ref_base)
-                                            - ref_base))
-                            / 2 ** 20, 3)
-                    if self.kernel is not None:
-                        rec['kernel_impl'] = self.kernel.impl
-                        tiles = kdispatch.choices_snapshot()
-                        if tiles:
-                            rec['kernel_tiles'] = tiles
-                    recorder.emit('step', **rec)
-                    if self._phases is not None:
-                        self._emit_profile(recorder, step, phase_args,
-                                           one_shot_hlo=(step == start_step))
-                    print(f'[trainer] step {step:6d} loss {loss:.4f} '
-                          f'({dt*1e3:.0f} ms){sched_line}', flush=True)
-                if cfg.ckpt_every and (step + 1) % cfg.ckpt_every == 0:
-                    self._ckptr.save(step + 1,
-                                     {'params': params, 'opt_state': opt_state},
-                                     {'next_step': step + 1})
-                if self._preempted:
-                    print('[trainer] preemption: synchronous checkpoint at '
-                          f'step {step + 1}', flush=True)
-                    self._ckptr.wait()
-                    ckpt.save(self.ckpt_dir, step + 1,
-                              {'params': params, 'opt_state': opt_state},
-                              {'next_step': step + 1, 'preempted': True})
-                    break
+                with jax.profiler.StepTraceAnnotation('train', step_num=step):
+                    params, opt_state, metrics, batch, loss, dt = \
+                        self._run_step(tracker, self.step_fn, data, step,
+                                       params, opt_state)
+                    with tracker.span('host', step):
+                        if step == start_step:
+                            fresh = recorder.comm_sites()
+                            if fresh:
+                                self._run_sites = fresh
+                            self._log_comm(recorder,
+                                           getattr(self, '_run_sites', {}))
+                        self._watchdog.observe(step, dt)
+                        history.append(loss)
+                        sched_fields = obs_events.step_fields(metrics)
+                        if 'refreshes' in sched_fields:
+                            cur_ref = sched_fields['refreshes']
+                            if cur_ref > prev_ref:
+                                recorder.emit('refresh', step=step,
+                                              refreshes=cur_ref,
+                                              step_time_s=round(dt, 6))
+                            prev_ref = cur_ref
+                        if step % cfg.log_every == 0 \
+                                or step == cfg.total_steps - 1:
+                            self._log_step(recorder, step, start_step,
+                                           ref_base, metrics, loss, dt,
+                                           sched_fields)
+                            if cfg.profile:
+                                self._emit_profile(
+                                    recorder, step, self.step_fn,
+                                    (params, opt_state, batch),
+                                    one_shot_hlo=(step == start_step))
+                        if cfg.ckpt_every and (step + 1) % cfg.ckpt_every == 0:
+                            self._ckptr.save(step + 1,
+                                             {'params': params,
+                                              'opt_state': opt_state},
+                                             {'next_step': step + 1})
+                        if self._preempted:
+                            print('[trainer] preemption: synchronous '
+                                  f'checkpoint at step {step + 1}',
+                                  flush=True)
+                            self._ckptr.wait()
+                            ckpt.save(self.ckpt_dir, step + 1,
+                                      {'params': params,
+                                       'opt_state': opt_state},
+                                      {'next_step': step + 1,
+                                       'preempted': True})
+                            break
         finally:
             self._ckptr.wait()
             self._watchdog.recorder = None
             recorder.close()
         return params, opt_state, history
+
+    def _log_step(self, recorder, step, start_step, ref_base, metrics, loss,
+                  dt, sched_fields):
+        """``fit``'s ``step`` record and log line."""
+        rec = {'step': step, 'loss': loss,
+               'grad_norm': float(metrics['grad_norm']),
+               'step_time_s': round(dt, 4), **sched_fields}
+        sched_line = ''
+        if 'refreshes' in rec:
+            sched_line = (f" refreshes {rec['refreshes']}"
+                          f" staleness {rec['staleness']:.3g}")
+        if 'pipeline_lag' in rec:
+            sched_line += f" lag {rec['pipeline_lag']}"
+        # cumulative exchanged bytes, from THIS trainer's comm sites:
+        # per-step sites (grads/stats) fire every step, refresh sites once
+        # per realized refresh
+        sites = getattr(self, '_run_sites', {})
+        if sites:
+            step_b = sum(v['bytes_per_call'] for s, v in sites.items()
+                         if not s.startswith('refresh/'))
+            refresh_b = sum(v['bytes_per_call'] for s, v in sites.items()
+                            if s.startswith('refresh/'))
+            rec['exchanged_mb_cum'] = round(
+                (step_b * (step + 1 - start_step)
+                 + refresh_b * (rec.get('refreshes', ref_base) - ref_base))
+                / 2 ** 20, 3)
+        if self.kernel is not None:
+            rec['kernel_impl'] = self.kernel.impl
+            tiles = kdispatch.choices_snapshot()
+            if tiles:
+                rec['kernel_tiles'] = tiles
+        recorder.emit('step', **rec)
+        print(f'[trainer] step {step:6d} loss {loss:.4f} '
+              f'({dt*1e3:.0f} ms){sched_line}', flush=True)
 
     # -- elastic outer loop ---------------------------------------------------
 
@@ -365,8 +355,9 @@ class Trainer:
 
         At W=1 the trajectory is bit-identical to :meth:`fit` (size-1
         collectives are exact); across W the global batch mean is the same
-        up to float reduction order.  ``profile`` mode is not supported
-        here (phased spans assume the single-device step).
+        up to float reduction order.  Steps carry :meth:`fit`'s trace
+        annotations (a live resize runs in the ``host`` span of the step
+        before it) and ``profile`` mode's records.
 
         Returns ``(params, opt_state, history)`` with ``history`` a list of
         ``(step, loss)`` pairs (steps matter: a resumed run starts mid-way).
@@ -374,9 +365,6 @@ class Trainer:
         from repro.launch.mesh import make_data_mesh
 
         cfg = self.cfg
-        if cfg.profile:
-            raise ValueError('profile mode is not supported by fit_elastic '
-                             '(use fit for span-fenced phase profiling)')
         self._install_signal_handlers()
         world = int(world) if world else jax.device_count()
 
@@ -470,64 +458,84 @@ class Trainer:
                         world, plan=plan, pipeline=self.sched.pipeline),
                     **extra}
 
+        def _follow_world(step):
+            if world_fn is not None:
+                want = world_fn(step)
+                if want and int(want) != world:
+                    _resize(world, int(want), step, 'live')
+
+        def _check_batch(batch):
+            nonlocal check_batch_next
+            if check_batch_next:
+                reshard_mod.check_batch_divisible(batch, world)
+                check_batch_next = False
+
+        tracker = obs_spans.SpanTracker(recorder if cfg.profile else None)
         history: list[tuple[int, float]] = []
         prev_ref = ref_base
-        first_step = True
         try:
+            if start_step < cfg.total_steps:
+                _follow_world(start_step)
             for step in range(start_step, cfg.total_steps):
-                if world_fn is not None:
-                    want = world_fn(step)
-                    if want and int(want) != world:
-                        _resize(world, int(want), step, 'live')
-                batch = data.batch_at(step)
-                if check_batch_next:
-                    reshard_mod.check_batch_divisible(batch, world)
-                    check_batch_next = False
-                t0 = time.perf_counter()
-                params, opt_state, metrics = step_fn(params, opt_state, batch)
-                loss = float(metrics['loss'])  # sync point
-                dt = time.perf_counter() - t0
-                if first_step:
-                    fresh = recorder.comm_sites()
-                    if fresh:
-                        self._run_sites = fresh
-                    self._log_comm(recorder, getattr(self, '_run_sites', {}))
-                    first_step = False
-                self._watchdog.observe(step, dt)
-                history.append((step, loss))
-                sched_fields = obs_events.step_fields(metrics)
-                if 'refreshes' in sched_fields:
-                    cur_ref = sched_fields['refreshes']
-                    if cur_ref > prev_ref:
-                        recorder.emit('refresh', step=step, refreshes=cur_ref,
-                                      step_time_s=round(dt, 6))
-                    prev_ref = cur_ref
-                if step % cfg.log_every == 0 or step == cfg.total_steps - 1:
-                    kfields = {}
-                    if self.kernel is not None:
-                        kfields['kernel_impl'] = self.kernel.impl
-                        tiles = kdispatch.choices_snapshot()
-                        if tiles:
-                            kfields['kernel_tiles'] = tiles
-                    recorder.emit('step', step=step, loss=loss,
-                                  grad_norm=float(metrics['grad_norm']),
-                                  step_time_s=round(dt, 4), **sched_fields,
-                                  **kfields)
-                    print(f'[trainer] step {step:6d} loss {loss:.4f} '
-                          f'({dt*1e3:.0f} ms) W={world}', flush=True)
-                if cfg.ckpt_every and (step + 1) % cfg.ckpt_every == 0:
-                    self._ckptr.save(step + 1,
-                                     {'params': params,
-                                      'opt_state': opt_state},
-                                     _meta(step + 1))
-                if self._preempted:
-                    print('[trainer] preemption: synchronous checkpoint at '
-                          f'step {step + 1}', flush=True)
-                    self._ckptr.wait()
-                    ckpt.save(self.ckpt_dir, step + 1,
-                              {'params': params, 'opt_state': opt_state},
-                              _meta(step + 1, preempted=True))
-                    break
+                with jax.profiler.StepTraceAnnotation('train', step_num=step):
+                    params, opt_state, metrics, batch, loss, dt = \
+                        self._run_step(tracker, step_fn, data, step, params,
+                                       opt_state, check=_check_batch)
+                    with tracker.span('host', step):
+                        if step == start_step:
+                            fresh = recorder.comm_sites()
+                            if fresh:
+                                self._run_sites = fresh
+                            self._log_comm(recorder,
+                                           getattr(self, '_run_sites', {}))
+                        self._watchdog.observe(step, dt)
+                        history.append((step, loss))
+                        sched_fields = obs_events.step_fields(metrics)
+                        if 'refreshes' in sched_fields:
+                            cur_ref = sched_fields['refreshes']
+                            if cur_ref > prev_ref:
+                                recorder.emit('refresh', step=step,
+                                              refreshes=cur_ref,
+                                              step_time_s=round(dt, 6))
+                            prev_ref = cur_ref
+                        if step % cfg.log_every == 0 \
+                                or step == cfg.total_steps - 1:
+                            kfields = {}
+                            if self.kernel is not None:
+                                kfields['kernel_impl'] = self.kernel.impl
+                                tiles = kdispatch.choices_snapshot()
+                                if tiles:
+                                    kfields['kernel_tiles'] = tiles
+                            recorder.emit(
+                                'step', step=step, loss=loss,
+                                grad_norm=float(metrics['grad_norm']),
+                                step_time_s=round(dt, 4), **sched_fields,
+                                **kfields)
+                            if cfg.profile:
+                                self._emit_profile(
+                                    recorder, step, step_fn,
+                                    (params, opt_state, batch),
+                                    one_shot_hlo=(step == start_step))
+                            print(f'[trainer] step {step:6d} loss '
+                                  f'{loss:.4f} ({dt*1e3:.0f} ms) W={world}',
+                                  flush=True)
+                        if cfg.ckpt_every and (step + 1) % cfg.ckpt_every == 0:
+                            self._ckptr.save(step + 1,
+                                             {'params': params,
+                                              'opt_state': opt_state},
+                                             _meta(step + 1))
+                        if self._preempted:
+                            print('[trainer] preemption: synchronous '
+                                  f'checkpoint at step {step + 1}',
+                                  flush=True)
+                            self._ckptr.wait()
+                            ckpt.save(self.ckpt_dir, step + 1,
+                                      {'params': params,
+                                       'opt_state': opt_state},
+                                      _meta(step + 1, preempted=True))
+                            break
+                        if step + 1 < cfg.total_steps:
+                            _follow_world(step + 1)
         finally:
             self._ckptr.wait()
             self._watchdog.recorder = None

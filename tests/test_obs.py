@@ -1,7 +1,7 @@
 """Unified telemetry layer (``repro.obs``): schema validation of every
 record type, the run-scoped Recorder, span nesting, the straggler watchdog,
-the golden-file report/diff contract, and the phased-step parity the
-profile mode rests on."""
+the golden-file report/diff contract, and the training loops' named phases
+on the profiler's trace."""
 import json
 from pathlib import Path
 
@@ -17,8 +17,7 @@ from repro.data.synthetic import ClassStream
 from repro.models import module as M
 from repro.models.simple import MLP, classifier_loss_fn
 from repro.obs import events, report, spans
-from repro.train.step import (init_opt_state, make_phased_step,
-                              make_train_step)
+from repro.train.step import init_opt_state, make_train_step
 
 DATA = Path(__file__).parent / 'data'
 FIX_A = str(DATA / 'obs_fixture_a.jsonl')
@@ -141,25 +140,43 @@ def test_recorder_writes_validates_and_scopes(tmp_path):
 # Spans + watchdog
 
 
-def test_span_nesting_order_and_fence():
+def test_span_nesting_order_and_fence(monkeypatch):
+    """Spans nest and close in order, and none waits on the device: the
+    fence of the old phased profile is gone."""
+    def no_wait(*a, **k):
+        raise AssertionError('a span waited on the device')
+
+    monkeypatch.setattr(jax, 'block_until_ready', no_wait)
     clock = iter(range(100))
-    tracker = spans.SpanTracker(clock=lambda: float(next(clock)))
-    fenced = []
-    with tracker.span('step', step=2) as outer:
-        with tracker.span('grad', step=2) as sp:
-            fenced.append(sp.fence(jnp.ones((2, 2))))
-        with tracker.span('apply', step=2):
+    rec = events.Recorder(None)
+    tracker = spans.SpanTracker(rec, clock=lambda: float(next(clock)))
+    with tracker.span('step', step=2):
+        with tracker.span('dispatch', step=2):
+            pending = jnp.ones((2, 2)) * 2          # enqueued, not awaited
+        with tracker.span('host', step=2):
             pass
-        outer.fence(fenced[0] * 2)
     names = [r['name'] for r in tracker.records]
-    assert names == ['grad', 'apply', 'step']            # closed-in order
+    assert names == ['dispatch', 'host', 'step']         # closed-in order
     by = {r['name']: r for r in tracker.records}
-    assert by['grad']['depth'] == 1 and by['grad']['parent'] == 'step'
+    assert by['dispatch']['depth'] == 1 and by['dispatch']['parent'] == 'step'
     assert by['step']['depth'] == 0 and by['step']['parent'] is None
     assert [r['seq'] for r in tracker.records] == [0, 1, 2]
     assert all(r['step'] == 2 for r in tracker.records)
     assert all(events.validate_record({'event': 'span', **r}) == []
                for r in tracker.records)
+    assert [r['name'] for r in rec.records] == names
+    assert by['dispatch']['ms'] == 1000.0               # one clock tick
+    assert float(pending[0, 0]) == 2.0
+
+
+def test_span_tracker_keeps_nothing_without_a_recorder():
+    """The default mode's tracker only annotates the profiler's trace."""
+    tracker = spans.SpanTracker()
+    for step in range(3):
+        with tracker.span('data', step=step):
+            with tracker.span('dispatch', step=step):
+                pass
+    assert tracker.records == []
 
 
 def test_straggler_watchdog_flags_injected_slow_step():
@@ -253,45 +270,10 @@ def test_bench_rows_load_and_gate(tmp_path, capsys):
 
 
 # ---------------------------------------------------------------------------
-# Phased step ≡ fused step (what profile mode runs)
-
-
-def test_phased_step_matches_fused():
-    stream = ClassStream(batch=32, dim=8, classes=4, spread=1.5, seed=0)
-    model = MLP([8, 16, 4])
-    model.loss_fn = classifier_loss_fn(model)
-    params0 = M.init_params(model.param_specs(), jax.random.PRNGKey(0))
-    opt, capture = make_optimizer('eva', lr=0.05)
-    taps_fn = (lambda p: model.make_taps(32, capture)) \
-        if capture.needs_taps else None
-
-    fused = jax.jit(make_train_step(model, opt, capture, taps_fn=taps_fn))
-    grad_fn, update_fn, apply_fn = (jax.jit(f) for f in make_phased_step(
-        model, opt, capture, taps_fn=taps_fn))
-
-    state_f = init_opt_state(model, opt, capture, params0, stream.batch_at(0),
-                             taps_fn=taps_fn)
-    state_p = jax.tree_util.tree_map(lambda x: x, state_f)
-    p_f, p_p = params0, params0
-    for i in range(3):
-        batch = stream.batch_at(i)
-        p_f, state_f, m_f = fused(p_f, state_f, batch)
-        loss, grads, stats = grad_fn(p_p, batch)
-        updates, state_p, m_p = update_fn(grads, stats, loss, state_p, p_p)
-        p_p = apply_fn(p_p, updates)
-        assert float(m_f['loss']) == pytest.approx(float(m_p['loss']),
-                                                   rel=1e-6)
-    for a, b in zip(jax.tree_util.tree_leaves(p_f),
-                    jax.tree_util.tree_leaves(p_p)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-6, atol=1e-7)
-
-
-# ---------------------------------------------------------------------------
 # Trainer profile mode end-to-end (tiny MLP, CPU-fast)
 
 
-def test_trainer_profile_mode_emits_valid_telemetry(tmp_path):
+def _mlp_trainer(tmp_path, **cfg_kw):
     from repro.train import Trainer, TrainerConfig
     stream = ClassStream(batch=16, dim=8, classes=4, spread=1.5, seed=0)
     model = MLP([8, 16, 4])
@@ -301,24 +283,93 @@ def test_trainer_profile_mode_emits_valid_telemetry(tmp_path):
     taps_fn = (lambda p: model.make_taps(16, capture)) \
         if capture.needs_taps else None
     cfg = TrainerConfig(total_steps=3, log_every=1, ckpt_every=0,
-                        out_dir=str(tmp_path / 'run'), profile=True)
-    tr = Trainer(model, opt, capture, cfg, taps_fn=taps_fn)
-    tr.fit(params, stream)
+                        out_dir=str(tmp_path / 'run'), **cfg_kw)
+    return Trainer(model, opt, capture, cfg, taps_fn=taps_fn), params, stream
 
+
+def _by_event(tmp_path):
     recs = report.load_records(str(tmp_path / 'run' / 'metrics.jsonl'))
     assert report.validate_records(recs) == []
     by_event = {}
     for r in recs:
         by_event.setdefault(events.infer_event(r), []).append(r)
+    return by_event
+
+
+def test_trainer_profile_mode_emits_valid_telemetry(tmp_path):
+    """Profile mode runs the production step, donated, in the same loop,
+    and adds the loop's span records and the step's profile samples."""
+    tr, params, stream = _mlp_trainer(tmp_path, profile=True)
+    fed = []
+
+    def spy(p, s, b):
+        fed.append(jax.tree_util.tree_leaves(p)[0])
+        return jitted(p, s, b)
+
+    jitted, tr.step_fn = tr.step_fn, spy
+    spy.lower = jitted.lower
+    tr.fit(params, stream)
+    assert len(fed) == 3 and all(x.is_deleted() for x in fed)  # donated
+
+    by_event = _by_event(tmp_path)
     assert len(by_event['step']) == 3
-    assert {'data', 'grad', 'precondition', 'apply', 'step'} <= {
-        s['name'] for s in by_event['span']}
+    names = [s['name'] for s in by_event['span']]
+    assert names == ['data', 'dispatch', 'wait', 'host'] * 3
+    assert [s['step'] for s in by_event['span']] == [0] * 4 + [1] * 4 + [2] * 4
     assert by_event['profile'], 'profile mode must emit profile records'
+    assert [list(p.get('fns', {})) for p in by_event['profile']] == [
+        ['train_step'], [], []]
+    text = report.render(report.breakdown(report.load_records(
+        str(tmp_path / 'run' / 'metrics.jsonl'))))
+    table = text.split('phase ')[1].split('\n\n')[0].splitlines()[1:]
+    assert [ln.split()[0] for ln in table] == [
+        'data', 'dispatch', 'wait', 'refresh', 'exchange', 'host']
+    shares = [float(ln.split()[2].rstrip('%')) for ln in table
+              if ln.split()[0] in ('data', 'dispatch', 'wait', 'host')]
+    assert sum(shares) == pytest.approx(100.0, abs=0.5)
     # eva exchanges its KV stats every step — the site must be attributed
     assert any('stats/eva' in r['sites'] for r in by_event['comm_exchange'])
     # the step record is a superset of the legacy fields
     step0 = by_event['step'][0]
     assert {'step', 'loss', 'grad_norm', 'step_time_s'} <= set(step0)
+
+
+def _host_events(trace_dir) -> list:
+    """(name, start, end, stats) of every host event of a profiler capture
+    whose name is ``train`` or starts ``train.``."""
+    path = sorted(Path(trace_dir).glob('**/*.xplane.pb'))[-1]
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith('/host'):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name == 'train' or e.name.startswith('train.'):
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns,
+                                {k: v for k, v in e.stats}))
+    return sorted(out, key=lambda e: e[1])
+
+
+@pytest.mark.parametrize('loop', ['fit', 'fit_elastic'])
+def test_loop_names_its_phases_on_the_profiler_trace(tmp_path, loop):
+    """Under any jax.profiler capture each step of either loop is a
+    ``train`` step event holding train.data, train.dispatch, train.wait and
+    train.host, once each and in that order; without profile mode nothing
+    is recorded beside the trace."""
+    tr, params, stream = _mlp_trainer(tmp_path)
+    with jax.profiler.trace(str(tmp_path / 'trace')):
+        getattr(tr, loop)(params, stream)
+    evs = _host_events(tmp_path / 'trace')
+    steps = [e for e in evs if e[0] == 'train']
+    assert [int(e[3]['step_num']) for e in steps] == [0, 1, 2]
+    for i, (_, t0, t1, _) in enumerate(steps):
+        inside = [e for e in evs if e[0] != 'train' and t0 <= e[1]
+                  and e[2] <= t1]
+        assert [e[0] for e in inside] == ['train.data', 'train.dispatch',
+                                          'train.wait', 'train.host']
+        assert all(int(e[3]['step']) == i for e in inside)
+    assert 'span' not in _by_event(tmp_path)
 
 
 # ---------------------------------------------------------------------------
