@@ -1,9 +1,10 @@
 """Versioned, schema-typed telemetry records + the JSONL ``Recorder``.
 
 One event model for everything the runtime emits — trainer step records,
-refresh/ownership/comm-exchange one-offs, straggler flags, phase spans and
-profile samples — replacing the hand-rolled dicts that used to be scattered
-across ``train/trainer.py``, ``comm/metrics.py`` and the benchmarks.
+refresh/ownership/comm-exchange one-offs, straggler flags, phase spans,
+the loop's read-back counts and profile samples — replacing the
+hand-rolled dicts that used to be scattered across ``train/trainer.py``,
+``comm/metrics.py`` and the benchmarks.
 
 Design rules:
 
@@ -124,7 +125,8 @@ SCHEMAS: dict[str, dict[str, Field]] = {
         'factor': Field(_NUM, unit='trigger threshold x median'),
     },
     # one host span of the training loop (profile mode): data, dispatch,
-    # wait (loss read-back) or host; nothing waits on the device for it
+    # wait (the step's read-back) or host; nothing waits on the device for
+    # it
     'span': {
         'name': Field(_STR, required=True),
         'ms': Field(_NUM, required=True, unit='ms'),
@@ -132,6 +134,15 @@ SCHEMAS: dict[str, dict[str, Field]] = {
         'seq': Field(_INT, unit='emission order'),
         'depth': Field(_INT, unit='nesting depth'),
         'parent': Field(_STR + (type(None),)),
+    },
+    # one per Trainer.fit call: its read-backs, made with the next step
+    # already dispatched (overlapped) or with nothing queued (drained)
+    'loop': {
+        'steps': Field(_INT, required=True, unit='steps read back'),
+        'overlapped': Field(_INT, required=True,
+                            unit='read-backs with the next step queued'),
+        'drained': Field(_INT, required=True,
+                         unit='read-backs with nothing queued'),
     },
     # profile-mode sample: live buffers + one-shot HLO costs of the step
     'profile': {
@@ -232,7 +243,8 @@ def validate_record(rec: Any) -> list[str]:
 
 def step_fields(metrics: dict) -> dict:
     """Typed host-side step-record fields from the jitted step's metrics
-    dict (the scheduler/pipeline scalars are traced arrays)."""
+    dict, as read back to the host (``Trainer.fit``) or still on the
+    device (each conversion then waits on it)."""
     out: dict[str, Any] = {}
     if 'refreshes' in metrics:
         out['refreshes'] = int(metrics['refreshes'])

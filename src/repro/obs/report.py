@@ -17,7 +17,10 @@ Exit codes: 0 ok · 1 schema-validation errors · 2 gated regression.
 Phase-attribution notes (honest accounting, also in the README):
   * span times exist only for profiled runs; the first step's spans are
     dropped (compile).  They are the loop's host phases (``data``,
-    ``dispatch``, ``wait`` for the device and the loss read-back, ``host``);
+    ``dispatch``, ``wait`` for the device and the step's read-back,
+    ``host``; ``fit`` reads step n back after dispatching step n+1, so its
+    ``wait`` mostly overlaps the next step's device work, and the ``loop``
+    record's overlapped share says how often);
     the device's own split (forward, backward, optimizer, ...) is in the
     profiler's trace under the step's named scopes;
   * ``refresh`` time is the firing-vs-cached step-time differential — it
@@ -170,6 +173,10 @@ def breakdown(records: list[dict]) -> dict:
     if own:
         bd['ownership'] = {'world': own[-1]['world'],
                            'owners': own[-1]['owners']}
+    loops = _of(records, 'loop')
+    if loops:
+        bd['loop'] = {k: sum(int(r[k]) for r in loops)
+                      for k in ('steps', 'overlapped', 'drained')}
     stragglers = _of(records, 'straggler')
     if stragglers:
         bd['stragglers'] = len(stragglers)
@@ -220,6 +227,12 @@ def render(bd: dict, title: str = '') -> str:
         line.append(f"stragglers {bd['stragglers']}")
     if line:
         out.append('   '.join(line))
+    if bd.get('loop', {}).get('steps'):
+        lp = bd['loop']
+        out.append(f"read-backs overlapped with the next step: "
+                   f"{lp['overlapped']} of {lp['steps']} "
+                   f"({100 * lp['overlapped'] / lp['steps']:.1f}%), "
+                   f"{lp['drained']} drained")
 
     # unified per-phase table: span-timed phases + the derived refresh and
     # byte-accounted exchange rows

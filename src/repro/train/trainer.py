@@ -14,11 +14,16 @@ Features:
     is schema-typed and versioned; comm-site attribution uses the
     recorder's run-scoped counter context instead of baselining the
     process-global table,
-  * named phases on the profiler's trace: every step of both loops is a
-    ``StepTraceAnnotation('train')`` holding four host spans,
-    ``train.data`` (``batch_at``), ``train.dispatch`` (the jitted call),
-    ``train.wait`` (the loss read-back, the one sync point) and
-    ``train.host`` (the rest); the step's device ops carry the
+  * one-step-lagged read-back: ``fit`` dispatches step n+1 before it
+    reads step n's scalars back (one ``device_get`` of a small dict, the
+    loop's one sync point), so the device runs back to back and host
+    work shorter than a step hides behind the next step; it drains first
+    only where the host needs step n's state (see :meth:`Trainer.fit`),
+  * named phases on the profiler's trace: each step is a
+    ``StepTraceAnnotation('train')`` holding host spans ``train.data``
+    (``batch_at``), ``train.dispatch`` (the jitted call), ``train.wait``
+    (the read-back) and ``train.host`` (the rest) -- in ``fit`` the wait
+    and host of the step before it; the step's device ops carry the
     ``jax.named_scope`` names of ``train/step.py``.  Both cost nothing
     without a running profiler, and neither waits on the device,
   * ``profile`` mode: the same donated step and loop, plus a ``span``
@@ -147,15 +152,15 @@ class Trainer:
             except ValueError:
                 pass  # not in main thread (tests)
 
-    # -- one step of either loop -------------------------------------------
+    # -- one synchronous step (fit_elastic) ----------------------------------
 
     @staticmethod
     def _run_step(tracker, step_fn, data, step, params, opt_state,
                   check=None):
         """Fetch, dispatch and read back one step under the ``data``,
-        ``dispatch`` and ``wait`` spans.  ``dt`` runs from the dispatch to
-        the loss on the host, as the watchdog and the step record count
-        it."""
+        ``dispatch`` and ``wait`` spans, nothing queued behind it.  ``dt``
+        runs from the dispatch to the loss on the host, as the watchdog
+        and the step record count it."""
         with tracker.span('data', step):
             batch = data.batch_at(step)
             if check is not None:
@@ -183,7 +188,31 @@ class Trainer:
 
     def fit(self, params, data: Any, start_step: int = 0,
             opt_state=None, resume: bool = True):
-        """``data`` must expose ``batch_at(step)`` (seekable)."""
+        """Train from ``start_step`` to ``cfg.total_steps``; ``data`` must
+        expose ``batch_at(step)`` (seekable).
+
+        The loop reads each step back one step late: it fetches and
+        dispatches step n+1, then reads step n's scalars (the loss, the
+        scheduler fields, ``grad_norm`` on log steps) in one
+        ``jax.device_get`` and does step n's host work (watchdog, history,
+        records), while step n+1 runs.  It reads step n back before
+        dispatching n+1 -- a *drain* -- where the host needs step n's
+        state or no step follows: a checkpoint boundary, a preemption
+        flag, a ``profile``-mode log step, the last step.  ``history``
+        and every record are those of a synchronous loop; the jitted step
+        and its donation are the same.
+
+        ``dt`` (the watchdog's and ``step_time_s``) runs from the previous
+        read-back to this one, or from the step's dispatch where that is
+        later (the first step, and the first after a drain).  On the
+        profiler's trace the ``train`` annotation of step n holds
+        ``train.data`` and ``train.dispatch`` of step n, then
+        ``train.wait`` and ``train.host`` of step n-1; a drained step's
+        wait and host lie in a ``drain`` annotation after it.  Each span
+        carries its own ``step``.  One ``loop`` record per call counts the
+        read-backs made with the next step queued (``overlapped``) and
+        with nothing queued (``drained``).
+        """
         cfg = self.cfg
         self._install_signal_handlers()
 
@@ -235,56 +264,95 @@ class Trainer:
         self._log_ownership(recorder, params, data.batch_at(start_step))
         history = []
         prev_ref = ref_base
-        step = start_step
+        last_read = 0.0                 # clock of the previous read-back
+        counts = {'overlapped': 0, 'drained': 0}
+
+        def is_log(step):
+            return step % cfg.log_every == 0 or step == cfg.total_steps - 1
+
+        def must_drain(step):
+            """Read ``step`` back before the next dispatch: the host needs
+            its state (a checkpoint, a preemption, a profile sample) or no
+            step follows."""
+            return bool(step == cfg.total_steps - 1 or self._preempted
+                        or (cfg.ckpt_every
+                            and (step + 1) % cfg.ckpt_every == 0)
+                        or (cfg.profile and is_log(step)))
+
+        def read_back(step, metrics, t0, queued):
+            """``step``'s scalars in one transfer (``wait``), then its host
+            work (``host``).  ``queued``: the next step is already
+            dispatched.  Without it ``params``/``opt_state`` are still
+            this step's, so its profile sample, checkpoint and preemption
+            run here; returns True where a preemption ends the run."""
+            nonlocal prev_ref, last_read
+            log = is_log(step)
+            with tracker.span('wait', step):
+                host = jax.device_get({k: v for k, v in metrics.items()
+                                       if log or k != 'grad_norm'})
+            t = time.perf_counter()
+            dt, last_read = t - max(t0, last_read), t
+            counts['overlapped' if queued else 'drained'] += 1
+            with tracker.span('host', step):
+                loss = float(host['loss'])
+                if step == start_step:
+                    fresh = recorder.comm_sites()
+                    if fresh:
+                        self._run_sites = fresh
+                    self._log_comm(recorder, getattr(self, '_run_sites', {}))
+                self._watchdog.observe(step, dt)
+                history.append(loss)
+                sched_fields = obs_events.step_fields(host)
+                if 'refreshes' in sched_fields:
+                    cur_ref = sched_fields['refreshes']
+                    if cur_ref > prev_ref:
+                        recorder.emit('refresh', step=step,
+                                      refreshes=cur_ref,
+                                      step_time_s=round(dt, 6))
+                    prev_ref = cur_ref
+                if log:
+                    self._log_step(recorder, step, start_step, ref_base,
+                                   host, loss, dt, sched_fields)
+                if queued:
+                    return False
+                if cfg.profile and log:
+                    self._emit_profile(recorder, step, self.step_fn,
+                                       (params, opt_state, batch),
+                                       one_shot_hlo=(step == start_step))
+                if cfg.ckpt_every and (step + 1) % cfg.ckpt_every == 0:
+                    self._ckptr.save(step + 1,
+                                     {'params': params,
+                                      'opt_state': opt_state},
+                                     {'next_step': step + 1})
+                if self._preempted:
+                    print('[trainer] preemption: synchronous checkpoint '
+                          f'at step {step + 1}', flush=True)
+                    self._ckptr.wait()
+                    ckpt.save(self.ckpt_dir, step + 1,
+                              {'params': params, 'opt_state': opt_state},
+                              {'next_step': step + 1, 'preempted': True})
+                    return True
+            return False
+
+        pending = None      # (step, metrics, t0): dispatched, not read back
         try:
             for step in range(start_step, cfg.total_steps):
                 with jax.profiler.StepTraceAnnotation('train', step_num=step):
-                    params, opt_state, metrics, batch, loss, dt = \
-                        self._run_step(tracker, self.step_fn, data, step,
-                                       params, opt_state)
-                    with tracker.span('host', step):
-                        if step == start_step:
-                            fresh = recorder.comm_sites()
-                            if fresh:
-                                self._run_sites = fresh
-                            self._log_comm(recorder,
-                                           getattr(self, '_run_sites', {}))
-                        self._watchdog.observe(step, dt)
-                        history.append(loss)
-                        sched_fields = obs_events.step_fields(metrics)
-                        if 'refreshes' in sched_fields:
-                            cur_ref = sched_fields['refreshes']
-                            if cur_ref > prev_ref:
-                                recorder.emit('refresh', step=step,
-                                              refreshes=cur_ref,
-                                              step_time_s=round(dt, 6))
-                            prev_ref = cur_ref
-                        if step % cfg.log_every == 0 \
-                                or step == cfg.total_steps - 1:
-                            self._log_step(recorder, step, start_step,
-                                           ref_base, metrics, loss, dt,
-                                           sched_fields)
-                            if cfg.profile:
-                                self._emit_profile(
-                                    recorder, step, self.step_fn,
-                                    (params, opt_state, batch),
-                                    one_shot_hlo=(step == start_step))
-                        if cfg.ckpt_every and (step + 1) % cfg.ckpt_every == 0:
-                            self._ckptr.save(step + 1,
-                                             {'params': params,
-                                              'opt_state': opt_state},
-                                             {'next_step': step + 1})
-                        if self._preempted:
-                            print('[trainer] preemption: synchronous '
-                                  f'checkpoint at step {step + 1}',
-                                  flush=True)
-                            self._ckptr.wait()
-                            ckpt.save(self.ckpt_dir, step + 1,
-                                      {'params': params,
-                                       'opt_state': opt_state},
-                                      {'next_step': step + 1,
-                                       'preempted': True})
+                    with tracker.span('data', step):
+                        batch = data.batch_at(step)
+                    t0 = time.perf_counter()
+                    with tracker.span('dispatch', step):
+                        params, opt_state, metrics = self.step_fn(
+                            params, opt_state, batch)
+                    if pending is not None:
+                        read_back(*pending, queued=True)
+                    pending = (step, metrics, t0)
+                if must_drain(step):
+                    pending = None
+                    with jax.profiler.TraceAnnotation('drain', step=step):
+                        if read_back(step, metrics, t0, queued=False):
                             break
+            recorder.emit('loop', steps=sum(counts.values()), **counts)
         finally:
             self._ckptr.wait()
             self._watchdog.recorder = None
@@ -355,9 +423,12 @@ class Trainer:
 
         At W=1 the trajectory is bit-identical to :meth:`fit` (size-1
         collectives are exact); across W the global batch mean is the same
-        up to float reduction order.  Steps carry :meth:`fit`'s trace
-        annotations (a live resize runs in the ``host`` span of the step
-        before it) and ``profile`` mode's records.
+        up to float reduction order.  Unlike :meth:`fit` it reads each
+        step back before dispatching the next (``_run_step``): a live
+        resize between steps needs the step drained.  Each ``train``
+        annotation holds its own step's four spans (a live resize runs in
+        the ``host`` span of the step before it); ``profile`` mode's
+        records are :meth:`fit`'s.
 
         Returns ``(params, opt_state, history)`` with ``history`` a list of
         ``(step, loss)`` pairs (steps matter: a resumed run starts mid-way).

@@ -45,6 +45,7 @@ VALID = {
                   'factor': 3.0},
     'span': {'name': 'grad', 'ms': 12.5, 'step': 2, 'seq': 0, 'depth': 1,
              'parent': 'step'},
+    'loop': {'steps': 6, 'overlapped': 5, 'drained': 1},
     'profile': {'step': 0, 'live_buffer_mb': 8.0, 'device_bytes_in_use': 123,
                 'fns': {'grad': {'flops': 1}}},
     'bench': {'name': 'table5/x', 'us_per_call': 10.0, 'derived': 'a=1',
@@ -314,8 +315,12 @@ def test_trainer_profile_mode_emits_valid_telemetry(tmp_path):
     by_event = _by_event(tmp_path)
     assert len(by_event['step']) == 3
     names = [s['name'] for s in by_event['span']]
+    # a profile-mode log step (here every step) drains: its own wait and
+    # host follow its dispatch, before the next step's data
     assert names == ['data', 'dispatch', 'wait', 'host'] * 3
     assert [s['step'] for s in by_event['span']] == [0] * 4 + [1] * 4 + [2] * 4
+    assert [(r['overlapped'], r['drained']) for r in by_event['loop']] == [
+        (0, 3)]
     assert by_event['profile'], 'profile mode must emit profile records'
     assert [list(p.get('fns', {})) for p in by_event['profile']] == [
         ['train_step'], [], []]
@@ -336,7 +341,7 @@ def test_trainer_profile_mode_emits_valid_telemetry(tmp_path):
 
 def _host_events(trace_dir) -> list:
     """(name, start, end, stats) of every host event of a profiler capture
-    whose name is ``train`` or starts ``train.``."""
+    whose name is ``train`` or ``drain`` or starts ``train.``."""
     path = sorted(Path(trace_dir).glob('**/*.xplane.pb'))[-1]
     out = []
     for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
@@ -344,7 +349,8 @@ def _host_events(trace_dir) -> list:
             continue
         for line in plane.lines:
             for e in line.events:
-                if e.name == 'train' or e.name.startswith('train.'):
+                if e.name in ('train', 'drain') \
+                        or e.name.startswith('train.'):
                     out.append((e.name, e.start_ns,
                                 e.start_ns + e.duration_ns,
                                 {k: v for k, v in e.stats}))
@@ -354,21 +360,35 @@ def _host_events(trace_dir) -> list:
 @pytest.mark.parametrize('loop', ['fit', 'fit_elastic'])
 def test_loop_names_its_phases_on_the_profiler_trace(tmp_path, loop):
     """Under any jax.profiler capture each step of either loop is a
-    ``train`` step event holding train.data, train.dispatch, train.wait and
-    train.host, once each and in that order; without profile mode nothing
-    is recorded beside the trace."""
+    ``train`` step event holding train.data and train.dispatch of its step,
+    then train.wait and train.host: in ``fit_elastic`` of the same step, in
+    ``fit`` of the step before (none at the first), the last step's in a
+    ``drain`` event after it.  Each span carries the step it belongs to;
+    without profile mode nothing is recorded beside the trace."""
     tr, params, stream = _mlp_trainer(tmp_path)
     with jax.profiler.trace(str(tmp_path / 'trace')):
         getattr(tr, loop)(params, stream)
     evs = _host_events(tmp_path / 'trace')
     steps = [e for e in evs if e[0] == 'train']
     assert [int(e[3]['step_num']) for e in steps] == [0, 1, 2]
-    for i, (_, t0, t1, _) in enumerate(steps):
-        inside = [e for e in evs if e[0] != 'train' and t0 <= e[1]
-                  and e[2] <= t1]
-        assert [e[0] for e in inside] == ['train.data', 'train.dispatch',
-                                          'train.wait', 'train.host']
-        assert all(int(e[3]['step']) == i for e in inside)
+    drains = [e for e in evs if e[0] == 'drain']
+    if loop == 'fit':
+        assert [int(e[3]['step']) for e in drains] == [2]
+    else:
+        assert drains == []
+    for i, (_, t0, t1, _) in enumerate(steps + drains):
+        inside = [e for e in evs if e[0] not in ('train', 'drain')
+                  and t0 <= e[1] and e[2] <= t1]
+        if loop == 'fit_elastic':
+            want = [('train.data', i), ('train.dispatch', i),
+                    ('train.wait', i), ('train.host', i)]
+        elif i == len(steps):       # the drain
+            want = [('train.wait', 2), ('train.host', 2)]
+        else:
+            want = [('train.data', i), ('train.dispatch', i)]
+            if i:
+                want += [('train.wait', i - 1), ('train.host', i - 1)]
+        assert [(e[0], int(e[3]['step'])) for e in inside] == want
     assert 'span' not in _by_event(tmp_path)
 
 
