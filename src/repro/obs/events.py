@@ -1,10 +1,10 @@
 """Versioned, schema-typed telemetry records + the JSONL ``Recorder``.
 
 One event model for everything the runtime emits — trainer step records,
-refresh/ownership/comm-exchange one-offs, straggler flags, phase spans,
-the loop's read-back counts and profile samples — replacing the
-hand-rolled dicts that used to be scattered across ``train/trainer.py``,
-``comm/metrics.py`` and the benchmarks.
+refresh/ownership/comm-exchange one-offs, the optimizer's bucket plan,
+straggler flags, phase spans, the loop's read-back counts and profile
+samples — replacing the hand-rolled dicts that used to be scattered
+across ``train/trainer.py``, ``comm/metrics.py`` and the benchmarks.
 
 Design rules:
 
@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 from pathlib import Path
 from typing import Any, Optional
 
@@ -50,6 +51,7 @@ _NUM = (int, float)
 _INT = (int,)
 _STR = (str,)
 _DICT = (dict,)
+_BOOL = (bool,)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -113,7 +115,7 @@ SCHEMAS: dict[str, dict[str, Field]] = {
         'slices_moved': Field(_INT, unit='slices with a new owner'),
     },
     # post-trace one-off: per-call-site logical exchange bytes (site dicts
-    # are validated by _validate_site; codec extras stay open)
+    # are validated by _validate_nested; codec extras stay open)
     'comm_exchange': {
         'sites': Field(_DICT, required=True),
     },
@@ -143,6 +145,12 @@ SCHEMAS: dict[str, dict[str, Field]] = {
                             unit='read-backs with the next step queued'),
         'drained': Field(_INT, required=True,
                          unit='read-backs with nothing queued'),
+    },
+    # one per Trainer.fit call, before its loop: the optimizer's bucket
+    # plan (bucket dicts are validated by _validate_nested)
+    'optimizer_plan': {
+        'buckets': Field(_DICT, required=True,
+                         unit='bucket key -> {leaves, stacked, g_bytes}'),
     },
     # profile-mode sample: live buffers + one-shot HLO costs of the step
     'profile': {
@@ -175,29 +183,41 @@ _SITE_FIELDS = {
 }
 
 
+_BUCKET_FIELDS = {
+    'leaves': Field(_INT, required=True, unit='parameter leaves'),
+    'stacked': Field(_BOOL, required=True,
+                     unit='gathered into one stack for the update'),
+    'g_bytes': Field(_INT, required=True, unit='B of gradient read per step'),
+}
+
+# events whose one dict field holds records of their own: (field, schema)
+_NESTED = {'comm_exchange': ('sites', _SITE_FIELDS),
+           'optimizer_plan': ('buckets', _BUCKET_FIELDS)}
+
+
 class SchemaError(ValueError):
     pass
 
 
 def _check(value, fld: Field, where: str) -> list[str]:
     # bool is an int subclass in Python; never a valid numeric field here
-    if isinstance(value, bool) or not isinstance(value, fld.types):
+    if (isinstance(value, bool) and bool not in fld.types) \
+            or not isinstance(value, fld.types):
         return [f'{where}: expected {"/".join(t.__name__ for t in fld.types)}'
                 f', got {type(value).__name__} ({value!r})']
     return []
 
 
-def _validate_site(site: str, rec: Any) -> list[str]:
-    where = f'comm_exchange.sites[{site!r}]'
+def _validate_nested(where: str, rec: Any, fields: dict) -> list[str]:
     if not isinstance(rec, dict):
         return [f'{where}: expected object, got {type(rec).__name__}']
     errs = []
-    for name, fld in _SITE_FIELDS.items():
+    for name, fld in fields.items():
         if name in rec:
             errs += _check(rec[name], fld, f'{where}.{name}')
         elif fld.required:
             errs.append(f'{where}: missing required field {name!r}')
-    return errs  # codec/topology extras beyond _SITE_FIELDS stay open
+    return errs  # codec/topology extras beyond the fields stay open
 
 
 def infer_event(rec: dict) -> Optional[str]:
@@ -235,9 +255,10 @@ def validate_record(rec: Any) -> list[str]:
             errs.append(f'{ev}: unknown field {key!r}')
             continue
         errs += _check(value, fld, f'{ev}.{key}')
-    if ev == 'comm_exchange' and isinstance(rec.get('sites'), dict):
-        for site, srec in rec['sites'].items():
-            errs += _validate_site(site, srec)
+    field, fields = _NESTED.get(ev, (None, None))
+    if field is not None and isinstance(rec.get(field), dict):
+        for key, sub in rec[field].items():
+            errs += _validate_nested(f'{ev}.{field}[{key!r}]', sub, fields)
     return errs
 
 
@@ -257,6 +278,17 @@ def step_fields(metrics: dict) -> dict:
         out['factor_solve_iters'] = int(metrics['factor_solve_iters'])
         out['factor_shard_bytes'] = float(metrics['factor_shard_bytes'])
     return out
+
+
+def plan_fields(plan) -> dict:
+    """The ``optimizer_plan`` record body of a bucket plan
+    (``core.bucketing.BucketPlan``): per bucket its leaf count, whether the
+    update stacks it, and the bytes of gradient it reads per step."""
+    return {'buckets': {
+        b.key: {'leaves': len(b.paths), 'stacked': b.stacked,
+                'g_bytes': len(b.paths) * math.prod(b.shape)
+                * b.dtype.itemsize}
+        for b in plan.buckets}}
 
 
 class Recorder:

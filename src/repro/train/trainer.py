@@ -109,15 +109,17 @@ class Trainer:
 
     # -- refresh-runtime observability ---------------------------------------
 
-    def _log_ownership(self, recorder, params, batch) -> None:
-        """One startup record: the per-bucket refresh-owner map a W-worker
-        data-parallel run of this model would use (W = local device count).
-        Cheap (eval_shape only); None plan (first-order) logs nothing."""
+    def _log_plan(self, recorder, params, batch) -> None:
+        """Two startup records: the optimizer's bucket plan, and the
+        per-bucket refresh-owner map a W-worker data-parallel run of this
+        model would use (W = local device count).  Cheap (eval_shape
+        only); None plan (first-order) logs nothing."""
         plan = stats_plan_of(self.model, self.capture, params, batch,
                              taps_fn=self.taps_fn)
         body = schedrt.ownership_event(plan)
         if body is None:
             return
+        recorder.emit('optimizer_plan', **obs_events.plan_fields(plan))
         recorder.emit('refresh_ownership', **body)
         print(f"[trainer] refresh ownership over W={body['world']}: "
               + ' '.join(f'{k}:{v}' for k, v in body['owners'].items()),
@@ -209,9 +211,10 @@ class Trainer:
         ``train.data`` and ``train.dispatch`` of step n, then
         ``train.wait`` and ``train.host`` of step n-1; a drained step's
         wait and host lie in a ``drain`` annotation after it.  Each span
-        carries its own ``step``.  One ``loop`` record per call counts the
-        read-backs made with the next step queued (``overlapped``) and
-        with nothing queued (``drained``).
+        carries its own ``step``.  Before the loop, one ``optimizer_plan``
+        record per call lists the optimizer's buckets; after it, one
+        ``loop`` record counts the read-backs made with the next step
+        queued (``overlapped``) and with nothing queued (``drained``).
         """
         cfg = self.cfg
         self._install_signal_handlers()
@@ -261,7 +264,7 @@ class Trainer:
         recorder = obs_events.Recorder(self.metrics_path)
         self._watchdog.recorder = recorder
         tracker = obs_spans.SpanTracker(recorder if cfg.profile else None)
-        self._log_ownership(recorder, params, data.batch_at(start_step))
+        self._log_plan(recorder, params, data.batch_at(start_step))
         history = []
         prev_ref = ref_base
         last_read = 0.0                 # clock of the previous read-back

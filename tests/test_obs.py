@@ -46,6 +46,11 @@ VALID = {
     'span': {'name': 'grad', 'ms': 12.5, 'step': 2, 'seq': 0, 'depth': 1,
              'parent': 'step'},
     'loop': {'steps': 6, 'overlapped': 5, 'drained': 1},
+    'optimizer_plan': {'buckets': {
+        'bfloat16_4x64x64': {'leaves': 3, 'stacked': True,
+                             'g_bytes': 98304},
+        'bfloat16_64x512': {'leaves': 1, 'stacked': False,
+                            'g_bytes': 65536}}},
     'profile': {'step': 0, 'live_buffer_mb': 8.0, 'device_bytes_in_use': 123,
                 'fns': {'grad': {'flops': 1}}},
     'bench': {'name': 'table5/x', 'us_per_call': 10.0, 'derived': 'a=1',
@@ -109,6 +114,29 @@ def test_site_validation_catches_corruption():
                                      'mode': 'gather-pod', 'pods': [2, 2],
                                      'ici_bytes': 6, 'dcn_bytes': 2}}}
     assert events.validate_record(ok) == []
+
+
+def test_bucket_validation_catches_corruption():
+    rec = {'event': 'optimizer_plan',
+           'buckets': {'bfloat16_64x512': {'leaves': 1, 'stacked': 1}}}
+    errs = events.validate_record(rec)
+    assert any('stacked' in e for e in errs)             # an int, not a bool
+    assert any("missing required field 'g_bytes'" in e for e in errs)
+    # a bool is still no count
+    rec['buckets']['bfloat16_64x512'] = {'leaves': True, 'stacked': False,
+                                         'g_bytes': 8}
+    assert any('leaves' in e for e in events.validate_record(rec))
+
+
+def test_plan_fields_count_each_bucket_of_a_plan():
+    from repro.core import bucketing
+    flat = {'a/w': jnp.zeros((4, 64, 64), jnp.bfloat16),
+            'b/w': jnp.zeros((4, 64, 64), jnp.bfloat16),
+            'c/w': jnp.zeros((4, 64, 64), jnp.bfloat16),
+            'head/w': jnp.zeros((64, 512), jnp.bfloat16)}
+    body = events.plan_fields(bucketing.build_plan(flat))
+    assert body == VALID['optimizer_plan']
+    assert events.validate_record({'event': 'optimizer_plan', **body}) == []
 
 
 # ---------------------------------------------------------------------------
@@ -274,13 +302,13 @@ def test_bench_rows_load_and_gate(tmp_path, capsys):
 # Trainer profile mode end-to-end (tiny MLP, CPU-fast)
 
 
-def _mlp_trainer(tmp_path, **cfg_kw):
+def _mlp_trainer(tmp_path, optimizer='eva', **cfg_kw):
     from repro.train import Trainer, TrainerConfig
     stream = ClassStream(batch=16, dim=8, classes=4, spread=1.5, seed=0)
     model = MLP([8, 16, 4])
     model.loss_fn = classifier_loss_fn(model)
     params = M.init_params(model.param_specs(), jax.random.PRNGKey(0))
-    opt, capture = make_optimizer('eva', lr=0.05)
+    opt, capture = make_optimizer(optimizer, lr=0.05)
     taps_fn = (lambda p: model.make_taps(16, capture)) \
         if capture.needs_taps else None
     cfg = TrainerConfig(total_steps=3, log_every=1, ckpt_every=0,
@@ -337,6 +365,37 @@ def test_trainer_profile_mode_emits_valid_telemetry(tmp_path):
     # the step record is a superset of the legacy fields
     step0 = by_event['step'][0]
     assert {'step', 'loss', 'grad_norm', 'step_time_s'} <= set(step0)
+
+
+@pytest.mark.parametrize('optimizer', ['eva', 'sgd'])
+def test_fit_records_the_optimizer_plan_once_per_call(tmp_path, optimizer):
+    """Each ``fit`` call records the optimizer's bucket plan once, before
+    its first step: every preconditioned leaf in one bucket, with the
+    bytes of its gradient.  A first-order optimizer has no plan."""
+    tr, params, stream = _mlp_trainer(tmp_path, optimizer=optimizer)
+    params, state, _ = tr.fit(params, stream)
+    tr.cfg.total_steps = 5
+    tr.fit(params, stream, start_step=3, opt_state=state)
+    recs = report.load_records(str(tmp_path / 'run' / 'metrics.jsonl'))
+    assert report.validate_records(recs) == []
+    kinds = [events.infer_event(r) for r in recs]
+    plans = [r for r in recs if r['event'] == 'optimizer_plan']
+    if optimizer == 'sgd':
+        assert plans == [] and kinds.count('loop') == 2
+        return
+    # one call's records end at its loop record
+    ends = [i for i, k in enumerate(kinds) if k == 'loop']
+    for a, b in zip([0] + [e + 1 for e in ends], ends):
+        call = kinds[a:b]
+        assert call.count('optimizer_plan') == 1
+        assert call.index('optimizer_plan') < call.index('step')
+    assert plans[0] == plans[1]
+    precon = {p: x for p, x in kvlib.flatten_params(params).items()
+              if x.ndim == 2}
+    assert sum(b['leaves'] for b in plans[0]['buckets'].values()) == \
+        len(precon)
+    assert sum(b['g_bytes'] for b in plans[0]['buckets'].values()) == \
+        sum(x.size * x.dtype.itemsize for x in precon.values())
 
 
 def _host_events(trace_dir) -> list:
