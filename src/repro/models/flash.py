@@ -1,9 +1,19 @@
-"""Flash attention (pure-JAX, TPU-shaped) with a custom VJP.
+"""Attention core with a custom VJP: a compiled Pallas flash kernel on the
+TPU, the pure-JAX flash below everywhere else.
 
-The naive composition (softmax(QKᵀ)·V under autodiff) saves the S×S
-probability tensor for the backward pass — at 32k context that is the
-memory roofline killer the dry-run flagged (112 GiB/layer residuals).
-This implementation:
+``flash_attention`` picks its path at trace time by what it observes
+(``attention_plan``): the TPU backend, arrays whole on one device (no
+enclosing Auto mesh of more than one device; inside ``shard_map`` they
+are per device), S_q equal to S_k, a head size the kernel takes and S a
+multiple of the kernel's block.  There it runs ``kernels/attention.py``,
+which skips causal tiles above the diagonal in every pass and builds the
+mask per tile inside the kernel.  Each trace adds its plan to the lists
+``collect`` holds open; the trainer writes the plans of a step's trace
+as one ``attention_plan`` record.
+
+The pure-JAX path (``flash_attention_xla``) is the CPU path and the
+kernel's reference.  It never saves the S×S probability tensor (the
+naive composition's residual, 112 GiB/layer at 32k context):
 
   forward : online-softmax over K/V chunks (scan), saving only
             (out, q, k, v, lse) — O(S·d), never O(S²);
@@ -11,12 +21,14 @@ This implementation:
             accumulates dQ, dK, dV — the standard flash-attention-2 split:
             dQ with a scan over KV chunks, dK/dV with a scan over Q chunks.
 
-GQA is native: queries are grouped (B, S, KV, G, Dh) and K/V are never
-repeated.  Causal masking is applied per tile; fully-masked tiles are
-skipped analytically in neither pass (baseline — a §Perf lever).
+GQA is native in both: queries are grouped (B, S, KV, G, Dh) and K/V are
+never repeated.  The pure-JAX path masks every (q_chunk × k_chunk) tile
+and computes fully masked ones too.
 """
 from __future__ import annotations
 
+import contextlib
+import dataclasses
 import functools
 import math
 from typing import Optional
@@ -24,7 +36,93 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import attention as kattention
+from repro.kernels import dispatch
+from repro.sharding.constraints import current_mesh
+
 NEG_INF = -1e30
+KERNEL_HEAD_DIMS = (64, 128)
+_COLLECTORS: list = []  # the open ``collect`` lists, innermost last
+
+
+@dataclasses.dataclass(frozen=True)
+class AttentionPlan:
+    """What one call site of ``flash_attention`` runs, per (batch, head):
+    ``tiles`` of the ``tiles_total`` (block_q × block_k) tiles of S×S."""
+    site: str           # 'B x S x H/KV x D/causal|full', per device
+    path: str           # 'pallas' | 'xla'
+    rule: str           # why this path
+    block_q: int
+    block_k: int
+    tiles: int
+    tiles_total: int
+
+
+def kernel_block(s: int) -> Optional[int]:
+    """The kernel's square block for sequence ``s``: the largest of 512,
+    256 and 128 that divides it, else None.  On a v5e at S 2048, 512 beat
+    256 and 1024 for head sizes 64 and 128 alike (PERF.md), so the head
+    size does not enter."""
+    return next((b for b in (512, 256, 128) if s % b == 0), None)
+
+
+def attention_plan(q_shape, k_shape, causal: bool, q_chunk: int,
+                   k_chunk: int) -> AttentionPlan:
+    """The path ``flash_attention`` takes for these shapes, by backend
+    and shape alone."""
+    b, sq, h, dh = q_shape
+    sk, kvh = k_shape[1], k_shape[2]
+    site = f'{b}x{sq}x{h}/{kvh}x{dh}/{"causal" if causal else "full"}'
+    mesh = current_mesh()
+    block = kernel_block(sq)
+    if dispatch.backend() != 'tpu':
+        rule = f'backend {dispatch.backend()}'
+    elif mesh is not None and mesh.size > 1:
+        rule = f'arrays split over an Auto mesh of {mesh.size} devices'
+    elif sq != sk:
+        rule = f'S_q {sq} != S_k {sk}'
+    elif dh not in KERNEL_HEAD_DIMS:
+        rule = f'head_dim {dh} not in {KERNEL_HEAD_DIMS}'
+    elif block is None:
+        rule = f'S {sq} not a multiple of 128'
+    else:
+        n = sq // block
+        tiles = n * (n + 1) // 2 if causal else n * n
+        return AttentionPlan(site, 'pallas', f'tpu, S {sq} = {n} x {block}, '
+                             f'head_dim {dh}', block, block, tiles, n * n)
+    qc, kc = min(q_chunk, sq), min(k_chunk, sk)
+    n = (sq // qc) * (sk // kc)
+    return AttentionPlan(site, 'xla', rule, qc, kc, n, n)
+
+
+@contextlib.contextmanager
+def collect():
+    """A list that gathers the plan of every ``flash_attention`` call
+    traced while the context is open."""
+    plans: list = []
+    _COLLECTORS.append(plans)
+    try:
+        yield plans
+    finally:
+        _COLLECTORS.remove(plans)
+
+
+def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                    causal: bool = True, q_chunk: int = 512,
+                    k_chunk: int = 1024) -> jnp.ndarray:
+    """q: (B,S,H,Dh); k/v: (B,S,KV,Dh) -> (B,S,H,Dh), by the path
+    ``attention_plan`` picks; ``q_chunk``/``k_chunk`` tile the pure-JAX
+    path only."""
+    plan = attention_plan(q.shape, k.shape, causal, q_chunk, k_chunk)
+    for plans in _COLLECTORS:
+        plans.append(plan)
+    if plan.path == 'xla':
+        return flash_attention_xla(q, k, v, causal, q_chunk, k_chunk)
+    heads_major = lambda x: jnp.swapaxes(x, 1, 2)
+    out = kattention.flash_attention(heads_major(q), heads_major(k),
+                                     heads_major(v), causal, plan.block_q,
+                                     plan.block_k)
+    return heads_major(out)
 
 
 def _chunk(x: jnp.ndarray, axis: int, size: int) -> jnp.ndarray:
@@ -35,9 +133,9 @@ def _chunk(x: jnp.ndarray, axis: int, size: int) -> jnp.ndarray:
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                    causal: bool = True, q_chunk: int = 512,
-                    k_chunk: int = 1024) -> jnp.ndarray:
+def flash_attention_xla(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                        causal: bool = True, q_chunk: int = 512,
+                        k_chunk: int = 1024) -> jnp.ndarray:
     """q: (B,S,H,Dh); k/v: (B,S,KV,Dh) -> (B,S,H,Dh)."""
     out, _ = _flash_fwd_inner(q, k, v, causal, q_chunk, k_chunk)
     return out
@@ -178,4 +276,4 @@ def _flash_bwd(causal, q_chunk, k_chunk, res, dout):
     return dq, dk, dv
 
 
-flash_attention.defvjp(_flash_fwd, _flash_bwd)
+flash_attention_xla.defvjp(_flash_fwd, _flash_bwd)

@@ -152,6 +152,13 @@ SCHEMAS: dict[str, dict[str, Field]] = {
         'buckets': Field(_DICT, required=True,
                          unit='bucket key -> {leaves, stacked, g_bytes}'),
     },
+    # one per trace of Trainer.fit's step: the path of each attention call
+    # site the step traced (site dicts are validated by _validate_nested)
+    'attention_plan': {
+        'sites': Field(_DICT, required=True,
+                       unit='site -> {path, rule, block_q, block_k, tiles, '
+                            'tiles_total}'),
+    },
     # profile-mode sample: live buffers + one-shot HLO costs of the step
     'profile': {
         'step': Field(_INT, required=True, unit='index'),
@@ -190,9 +197,19 @@ _BUCKET_FIELDS = {
     'g_bytes': Field(_INT, required=True, unit='B of gradient read per step'),
 }
 
+_ATTENTION_FIELDS = {
+    'path': Field(_STR, required=True, unit="'pallas' | 'xla'"),
+    'rule': Field(_STR, required=True, unit='why this path'),
+    'block_q': Field(_INT, required=True, unit='query rows per tile'),
+    'block_k': Field(_INT, required=True, unit='key rows per tile'),
+    'tiles': Field(_INT, required=True, unit='tiles computed per head'),
+    'tiles_total': Field(_INT, required=True, unit='tiles of S x S per head'),
+}
+
 # events whose one dict field holds records of their own: (field, schema)
 _NESTED = {'comm_exchange': ('sites', _SITE_FIELDS),
-           'optimizer_plan': ('buckets', _BUCKET_FIELDS)}
+           'optimizer_plan': ('buckets', _BUCKET_FIELDS),
+           'attention_plan': ('sites', _ATTENTION_FIELDS)}
 
 
 class SchemaError(ValueError):
@@ -289,6 +306,16 @@ def plan_fields(plan) -> dict:
                 'g_bytes': len(b.paths) * math.prod(b.shape)
                 * b.dtype.itemsize}
         for b in plan.buckets}}
+
+
+def attention_fields(plans) -> dict:
+    """The ``attention_plan`` record body of ``models.flash.AttentionPlan``
+    entries, one per call site (a site traced twice keeps its last)."""
+    return {'sites': {p.site: {'path': p.path, 'rule': p.rule,
+                               'block_q': p.block_q, 'block_k': p.block_k,
+                               'tiles': p.tiles,
+                               'tiles_total': p.tiles_total}
+                      for p in plans}}
 
 
 class Recorder:

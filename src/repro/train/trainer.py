@@ -51,6 +51,7 @@ import jax
 from repro.core import kv as kvlib
 from repro.core.transform import GradientTransformation
 from repro.kernels import dispatch as kdispatch
+from repro.models import flash as attn
 from repro.obs import events as obs_events
 from repro.obs import spans as obs_spans
 from repro.schedule import reshard as reshard_mod
@@ -124,6 +125,20 @@ class Trainer:
         print(f"[trainer] refresh ownership over W={body['world']}: "
               + ' '.join(f'{k}:{v}' for k, v in body['owners'].items()),
               flush=True)
+
+    @staticmethod
+    def _log_attention(recorder, plans) -> None:
+        """One ``attention_plan`` record for a trace of the step: the path
+        each attention call site took (nothing when the dispatch traced no
+        attention, as a compiled step's does not)."""
+        if not plans:
+            return
+        body = obs_events.attention_fields(plans)
+        recorder.emit('attention_plan', **body)
+        print('[trainer] attention: ' + ' '.join(
+            f"{site}:{p['path']}/{p['block_q']}x{p['block_k']}"
+            f"/{p['tiles']}of{p['tiles_total']}"
+            for site, p in sorted(body['sites'].items())), flush=True)
 
     def _log_comm(self, recorder, sites) -> None:
         """One record after the step is traced: the per-call-site logical
@@ -212,7 +227,8 @@ class Trainer:
         ``train.wait`` and ``train.host`` of step n-1; a drained step's
         wait and host lie in a ``drain`` annotation after it.  Each span
         carries its own ``step``.  Before the loop, one ``optimizer_plan``
-        record per call lists the optimizer's buckets; after it, one
+        record per call lists the optimizer's buckets; a dispatch that
+        traces the step writes one ``attention_plan`` record; after it, one
         ``loop`` record counts the read-backs made with the next step
         queued (``overlapped``) and with nothing queued (``drained``).
         """
@@ -344,9 +360,11 @@ class Trainer:
                     with tracker.span('data', step):
                         batch = data.batch_at(step)
                     t0 = time.perf_counter()
-                    with tracker.span('dispatch', step):
+                    with tracker.span('dispatch', step), \
+                            attn.collect() as traced:
                         params, opt_state, metrics = self.step_fn(
                             params, opt_state, batch)
+                    self._log_attention(recorder, traced)
                     if pending is not None:
                         read_back(*pending, queued=True)
                     pending = (step, metrics, t0)

@@ -51,6 +51,9 @@ VALID = {
                              'g_bytes': 98304},
         'bfloat16_64x512': {'leaves': 1, 'stacked': False,
                             'g_bytes': 65536}}},
+    'attention_plan': {'sites': {'4x2048x14/2x64/causal': {
+        'path': 'pallas', 'rule': 'tpu, S 2048 = 4 x 512, head_dim 64',
+        'block_q': 512, 'block_k': 512, 'tiles': 10, 'tiles_total': 16}}},
     'profile': {'step': 0, 'live_buffer_mb': 8.0, 'device_bytes_in_use': 123,
                 'fns': {'grad': {'flops': 1}}},
     'bench': {'name': 'table5/x', 'us_per_call': 10.0, 'derived': 'a=1',
@@ -137,6 +140,20 @@ def test_plan_fields_count_each_bucket_of_a_plan():
     body = events.plan_fields(bucketing.build_plan(flat))
     assert body == VALID['optimizer_plan']
     assert events.validate_record({'event': 'optimizer_plan', **body}) == []
+
+
+def test_attention_fields_keep_one_entry_per_site():
+    from repro.models.flash import AttentionPlan
+    plan = AttentionPlan('4x2048x14/2x64/causal', 'pallas',
+                         'tpu, S 2048 = 4 x 512, head_dim 64', 512, 512, 10, 16)
+    body = events.attention_fields([plan, plan])
+    assert body == VALID['attention_plan']
+    assert events.validate_record({'event': 'attention_plan', **body}) == []
+    bad = {'event': 'attention_plan',
+           'sites': {'s': {'path': 'pallas', 'rule': 'r', 'tiles': '10'}}}
+    errs = events.validate_record(bad)
+    assert any('tiles' in e for e in errs)
+    assert any("missing required field 'block_q'" in e for e in errs)
 
 
 # ---------------------------------------------------------------------------
@@ -504,3 +521,34 @@ def test_kfac_scan_stacked_step_runs():
     for _ in range(2):
         params, state, m = step(params, state, batch)
     assert np.isfinite(float(m['loss']))
+
+
+def test_fit_records_the_attention_plan_of_a_traced_step(tmp_path):
+    """The dispatch that traces the step writes one ``attention_plan``
+    record, before the step's own record; a second ``fit`` call reuses the
+    compiled step and writes none.  On the CPU the pure path runs."""
+    from repro.configs import demo_lm
+    from repro.data import LMStream
+    from repro.models import build_model
+    from repro.train import Trainer, TrainerConfig
+    cfg = demo_lm('small').replace(attn_impl='flash', q_chunk=16, k_chunk=16)
+    model = build_model(cfg)
+    params = M.init_params(model.param_specs(), jax.random.PRNGKey(0))
+    data = LMStream(vocab=cfg.vocab, seq_len=32, batch=4, seed=1)
+    opt, capture = make_optimizer('eva', lr=0.05)
+    tr = Trainer(model, opt, capture,
+                 TrainerConfig(total_steps=2, log_every=1, ckpt_every=0,
+                               out_dir=str(tmp_path / 'run')))
+    params, state, _ = tr.fit(params, data)
+    tr.cfg.total_steps = 3
+    tr.fit(params, data, start_step=2, opt_state=state)
+    by_event = _by_event(tmp_path)
+    plans = by_event['attention_plan']
+    assert len(plans) == 1
+    site = f'4x32x{cfg.n_heads}/{cfg.n_kv_heads}x{cfg.head_dim}/causal'
+    assert plans[0]['sites'] == {site: {
+        'path': 'xla', 'rule': 'backend cpu', 'block_q': 16, 'block_k': 16,
+        'tiles': 4, 'tiles_total': 4}}
+    kinds = [events.infer_event(r) for r in report.load_records(
+        str(tmp_path / 'run' / 'metrics.jsonl'))]
+    assert kinds.index('attention_plan') < kinds.index('step')

@@ -1,6 +1,6 @@
-"""Compile rehearsal for TPU v5e: the Pallas kernels and the qwen2-0.5b Eva
-train step, compiled by the TPU compiler for a described (not attached)
-v5e:2x2 topology.  Nothing runs; a compile that passes is not a chip run.
+"""Compile rehearsal for TPU v5e: the Pallas kernels and the Eva train steps
+of qwen2-0.5b and glm4-9b-l4v8, compiled by the TPU compiler for a
+described (not attached) v5e:2x2 topology.  Nothing runs; a compile that passes is not a chip run.
 
 The topology is described inside a module fixture, never at import, so
 every pytest-xdist worker collects the same tests and only the worker that
@@ -10,6 +10,7 @@ skip in silence).
 """
 import importlib.util
 import os
+import re
 from pathlib import Path
 
 import jax
@@ -119,12 +120,22 @@ def test_largest_tuned_tile_fits_vmem(op, one_chip):
     assert 'tpu_custom_call' in compiled.as_text()
 
 
-@pytest.mark.parametrize('path', ['default', 'fused'])
-def test_qwen2_eva_train_step_fits_hbm(path, one_chip, monkeypatch):
-    """The whole qwen2-0.5b Eva step at the smoke's batch x 2048, donated
-    params and optimizer state, within one v5e's HBM.  The fused path is
-    steered to compiled Pallas here (dispatch sees the CPU backend)."""
-    from repro.configs import get_config
+ATTENTION_KERNELS = ('flash_fwd', 'flash_dq', 'flash_dkv')
+
+
+def _kernel_names(compiled) -> set:
+    """Names of the custom calls in a compiled program, without the
+    ``.<n>`` the compiler appends (the profiler's trace names ops so)."""
+    names = re.findall(r'%([\w.-]+) = [^\n]*custom-call\(',
+                       compiled.as_text())
+    return {re.sub(r'\.\d+$', '', n) for n in names}
+
+
+def _compile_train_step(arch, batch_size, one_chip, monkeypatch, fused):
+    """The Eva train step at ``batch_size`` x 2048, donated params and
+    optimizer state, compiled for one v5e.  The backend is steered to the
+    TPU (dispatch sees the CPU here), so the step takes the chip's paths:
+    the attention kernel, and compiled Pallas ``eva_fused`` when fused."""
     from repro.core import make_optimizer
     from repro.kernels import dispatch
     from repro.models import build_model
@@ -132,17 +143,12 @@ def test_qwen2_eva_train_step_fits_hbm(path, one_chip, monkeypatch):
     from repro.schedule.runtime import RefreshRuntime
     from repro.train.step import abstract_opt_state, make_train_step
 
-    batch_size = _smoke().SMOKE_BATCH
-    model = build_model(get_config('qwen2-0.5b'))
+    monkeypatch.setattr(dispatch, 'backend', lambda: 'tpu')
+    model = build_model(arch)
     params = jax.eval_shape(lambda: M.init_params(model.param_specs(),
                                                   jax.random.PRNGKey(0)))
-    kernel = None
-    opt_kwargs = {}
-    if path == 'fused':
-        monkeypatch.setattr(dispatch, 'backend', lambda: 'tpu')
-        kernel = dispatch.KernelConfig(impl='auto')
-        opt_kwargs['fused'] = True
-    opt, capture = make_optimizer('eva', lr=0.05, **opt_kwargs)
+    kernel = dispatch.KernelConfig(impl='auto') if fused else None
+    opt, capture = make_optimizer('eva', lr=0.05, fused=fused)
     tok = jax.ShapeDtypeStruct((batch_size, 2048), jnp.int32)
     batch = {'tokens': tok, 'labels': tok}
     sched = RefreshRuntime()
@@ -151,9 +157,38 @@ def test_qwen2_eva_train_step_fits_hbm(path, one_chip, monkeypatch):
     on_chip = lambda t: jax.tree_util.tree_map(
         lambda x: _spec(one_chip, x.shape, x.dtype), t)
     step = make_train_step(model, opt, capture, sched=sched, kernel=kernel)
-    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+    return jax.jit(step, donate_argnums=(0, 1)).lower(
         on_chip(params), on_chip(state), on_chip(batch)).compile()
+
+
+def _check_step(compiled, fused):
     peak = compiled.memory_analysis().peak_memory_in_bytes
     assert peak <= HBM_BYTES, f'{peak / 2**30:.2f} GiB > 15.75 GiB'
-    n_kernels = compiled.as_text().count('tpu_custom_call')
-    assert (n_kernels > 0) == (path == 'fused')
+    names = _kernel_names(compiled)
+    assert set(ATTENTION_KERNELS) <= names, names
+    assert ('eva_fused_stacked' in names) == fused, names
+
+
+@pytest.mark.parametrize('path', ['default', 'fused'])
+def test_qwen2_eva_train_step_fits_hbm(path, one_chip, monkeypatch):
+    """The whole qwen2-0.5b Eva step at the smoke's batch x 2048 within
+    one v5e's HBM, the attention kernel in it, ``eva_fused`` only on the
+    fused path."""
+    from repro.configs import get_config
+    compiled = _compile_train_step(get_config('qwen2-0.5b'),
+                                   _smoke().SMOKE_BATCH, one_chip,
+                                   monkeypatch, path == 'fused')
+    _check_step(compiled, path == 'fused')
+
+
+def test_glm4_eva_train_step_fits_hbm(one_chip, monkeypatch):
+    """glm4-9b-l4v8's step as its benchmark cell runs it (batch 3 x 2048,
+    Eva's default path), heads of 128 through the attention kernel."""
+    import json
+    from repro.configs import get_config
+    cfg = json.loads((ROOT / 'bench' / 'configs' / 'glm4-9b-l4v8.json')
+                     .read_text())
+    arch = get_config(cfg['program_arch']).replace(
+        **{k: cfg[k] for k in cfg['reduced']})
+    _check_step(_compile_train_step(arch, 3, one_chip, monkeypatch, False),
+                False)
