@@ -44,7 +44,6 @@ if ('--refresh-sharding' in sys.argv     # must precede the first jax import
             _flags + ' --xla_force_host_platform_device_count=4').strip()
 
 import argparse
-import math
 
 import jax
 import jax.numpy as jnp
@@ -447,57 +446,6 @@ def run_pipeline(check_overlap: bool = False) -> None:
               'dot dependence cone')
 
 
-def run_kernels(check_speedup: bool = False) -> None:
-    """Kernel dispatch microbench: the pure-XLA ``ref.py`` path vs
-    interpret-mode Pallas (the pre-dispatch CPU default) per op × shape,
-    through the same ``kernels.dispatch`` wrappers the optimizers call.
-    The geomean xla speedup is the number the dispatch layer's
-    CPU-``'auto'``-resolves-to-``'xla'`` rule banks every step;
-    ``--check-speedup`` gates it at ≥1.5× for CI."""
-    from repro.kernels import dispatch
-
-    key = jax.random.PRNGKey(0)
-    shapes = [(128, 128), (512, 384), (1000, 513)]
-    ops = ('bilinear', 'matvec', 'rank1_update', 'eva_fused')
-    speedups = []
-    for d_in, d_out in shapes:
-        ks = jax.random.split(jax.random.fold_in(key, d_in), 3)
-        g = jax.random.normal(ks[0], (d_in, d_out), jnp.float32)
-        a = jax.random.normal(ks[1], (d_in,), jnp.float32)
-        b = jax.random.normal(ks[2], (d_out,), jnp.float32)
-        m = jnp.zeros((1, d_in, d_out), jnp.float32)
-
-        def cases(impl):
-            return {
-                'bilinear': lambda: dispatch.bilinear(g, a, b, impl=impl),
-                'matvec': lambda: dispatch.matvec(g, a, impl=impl),
-                'rank1_update': lambda: dispatch.rank1_update(
-                    g, a, b, jnp.float32(0.37), jnp.float32(2.5), impl=impl),
-                'eva_fused': lambda: dispatch.eva_fused_stacked(
-                    g[None], a[None], b[None], 0.03, m, 0.9, impl=impl)[0],
-            }
-
-        for op in ops:
-            t_xla = time_fn(jax.jit(cases('xla')[op]))
-            t_int = time_fn(jax.jit(cases('pallas_interpret')[op]))
-            sp = t_int / max(t_xla, 1e-9)
-            speedups.append(sp)
-            emit(f'table5/kernels/{op}/{d_in}x{d_out}/xla', t_xla,
-                 'impl=xla')
-            emit(f'table5/kernels/{op}/{d_in}x{d_out}/interpret', t_int,
-                 f'impl=pallas_interpret;xla_speedup={sp:.2f}x')
-    geo = math.exp(sum(math.log(s) for s in speedups) / len(speedups))
-    emit('table5/kernels/summary', 0.0,
-         f'xla_speedup_geomean={geo:.2f}x;cells={len(speedups)};'
-         f'min_speedup={min(speedups):.2f}x')
-    if check_speedup and geo < 1.5:
-        raise SystemExit(f'kernel dispatch cell: xla geomean speedup '
-                         f'{geo:.2f}x < 1.5x over interpret')
-    if check_speedup:
-        print(f'# speedup check passed: xla {geo:.2f}x over interpret '
-              '(geomean)')
-
-
 def run() -> None:
     # --- transformer section ---
     cfg = demo_lm('small')
@@ -550,12 +498,6 @@ def main() -> None:
     ap.add_argument('--check-overlap', action='store_true',
                     help='with --pipeline: fail (exit 1) unless the onestep '
                          'collectives are outside the dot dependence cone')
-    ap.add_argument('--kernels', action='store_true',
-                    help='only the kernel dispatch microbench (xla ref path '
-                         'vs interpret-mode Pallas per op/shape)')
-    ap.add_argument('--check-speedup', action='store_true',
-                    help='with --kernels: fail (exit 1) unless the xla path '
-                         'is >=1.5x faster than interpret (geomean)')
     ap.add_argument('--json', default=None, metavar='PATH',
                     help='also write the emitted rows to PATH as JSON '
                          '(CI benchmark artifacts)')
@@ -569,8 +511,6 @@ def main() -> None:
         run_factor_sharding()
     elif args.pipeline:
         run_pipeline(check_overlap=args.check_overlap)
-    elif args.kernels:
-        run_kernels(check_speedup=args.check_speedup)
     else:
         run()
     if args.json:

@@ -13,12 +13,13 @@ Phases (one chip, in this order):
   device   — JAX must report a TPU.
   trainer  — ``repro.launch.train.main`` on qwen2-0.5b (published widths,
              random weights from seed 0) with Eva, seq 2048: once on the
-             default path, once with ``--fused --kernel-impl auto`` so
-             compiled Pallas runs inside a real step.  Losses finite and
-             falling.
-  kernels  — every op the kernel dispatch routes, forced to compiled Pallas
-             at qwen2-0.5b's preconditioned bucket shapes in bf16 and f32,
-             against ``kernels/ref.py`` (tolerances at ``TOL``).
+             default path (inline jnp), once with ``--fused``, where the
+             backend rule runs compiled Pallas ``eva_fused`` inside a real
+             step.  Losses finite and falling.
+  kernels  — the two fused Eva kernels through ``kernels.dispatch``,
+             compiled for the chip, at qwen2-0.5b's preconditioned bucket
+             shapes in bf16 and f32, against ``kernels/ref.py``
+             (tolerance ``TOL``).
 
 Phases (``--four-chips``): W=4 against W=1 on the same global batch — Eva
 on qwen2-0.5b through ``Trainer.fit_elastic``, and K-FAC on demo-base
@@ -39,18 +40,11 @@ ROOT = Path(__file__).resolve().parent
 OUT = ROOT / 'runs' / 'chip_smoke'
 
 GAMMA, MU = 0.03, 0.9
-# Kernel-vs-reference tolerances.  Reductions (bilinear, matvec) are
-# compared per output against 1e-4 x the l2 norm of their terms: even plain
-# recursive f32 summation of the 4.3M-term buckets rounds at most at
-# ~eps*sqrt(n/2)*|terms|_2 ~ 9e-5, tiled accumulation far below that, while
-# a wrong tile or slice is off by O(1).  The rank-one update is elementwise:
-# its error is the rounding of the output dtype, relative to the magnitude
-# |s|(|g| + |c a b|) of what it rounds (2^-7 = one bf16 ulp, 2^-21 = four
-# f32 ulps).  Fused outputs carry the coefficient's reduction error into
-# every element (1e-5 of max |out|); their aux sums are held to 1e-5 of the
-# sum of the magnitudes of their terms.
-TOL = {'reduce': 1e-4, 'bfloat16': 2.0 ** -7, 'float32': 2.0 ** -21,
-       'fused': 1e-5}
+# Kernel-vs-reference tolerance.  Fused outputs carry the coefficient's
+# reduction error into every element, so they are held to 1e-5 of max |out|;
+# their aux sums to 1e-5 of the sum of the magnitudes of their terms.  A
+# wrong tile or slice is off by O(1).
+TOL = 1e-5
 # x 2048 tokens: the largest batch whose step the TPU compiler fits in one
 # v5e's 15.75 GiB (peak 14.37 GiB at 4; it refuses 5 with 17.29G used)
 SMOKE_BATCH = 4
@@ -105,95 +99,40 @@ def qwen_kernel_shapes():
 
 
 def _kernel_cases(L, m, n, dt, key):
-    """name -> (dispatch op, kernel fn, reference fn, error fn, args)."""
+    """name -> (kernel fn, reference fn, args)."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import dispatch as kd
     from repro.kernels import ref
 
-    ks = jax.random.split(key, 6)
+    ks = jax.random.split(key, 4)
     g = jax.random.normal(ks[0], (L, m, n), jnp.float32).astype(dt)
     a = jax.random.normal(ks[1], (L, m), jnp.float32)
     b = jax.random.normal(ks[2], (L, n), jnp.float32)
-    cols = jax.random.normal(ks[3], (L, 4, m), jnp.float32)
-    mom = jax.random.normal(ks[4], (L, m, n), jnp.float32)
-    coeff = jax.random.uniform(ks[5], (L,), jnp.float32)
-    scale = jnp.full((L,), 1.0 / GAMMA, jnp.float32)
-    sq = lambda x: x.astype(jnp.float32) ** 2
-
-    def reduction_err(k, r, sq_terms):
-        return jnp.max(jnp.abs(k - r) / jnp.sqrt(sq_terms))
-
-    def rank1_err(gg, aa, bb, c, s):
-        c4 = jnp.asarray(c)[..., None, None]
-        s4 = jnp.asarray(s)[..., None, None]
-        mag = jnp.abs(s4) * (jnp.abs(gg.astype(jnp.float32))
-                             + jnp.abs(c4 * aa[..., :, None] * bb[..., None, :]))
-        return lambda k, r: jnp.max(jnp.abs(k.astype(jnp.float32)
-                                            - r.astype(jnp.float32)) / mag)
-
-    def fused_err(terms_of):
-        def err(k, r):
-            (ko, ka), (ro, ra) = k, r
-            e_out = jnp.max(jnp.abs(ko - ro)) / jnp.max(jnp.abs(ro))
-            e_aux = jnp.max(jnp.abs(ka - ra) / terms_of(ro))
-            return jnp.maximum(e_out, e_aux)
-        return err
-
-    def aux_terms(g32):
-        return lambda o: jnp.stack([jnp.sum(jnp.abs(o * g32), (-2, -1)),
-                                    jnp.sum(o * o, (-2, -1)),
-                                    jnp.sum(g32 * g32, (-2, -1))], -1)
-
-    g32 = g.astype(jnp.float32)
+    mom = jax.random.normal(ks[3], (L, m, n), jnp.float32)
     return {
-        'bilinear_stacked': (
-            'bilinear', kd.bilinear_stacked, ref.bilinear_ref,
-            lambda k, r: reduction_err(k, r, ref.bilinear_ref(sq(g), sq(a), sq(b))),
-            (g, a, b)),
-        'bilinear': (
-            'bilinear', kd.bilinear, ref.bilinear_ref,
-            lambda k, r: reduction_err(k, r, ref.bilinear_ref(sq(g[0]), sq(a[0]),
-                                                              sq(b[0]))),
-            (g[0], a[0], b[0])),
-        'matvec_stacked': (
-            'matvec', kd.matvec_stacked, ref.matvec_ref,
-            lambda k, r: reduction_err(k, r, ref.matvec_ref(sq(g), sq(a))),
-            (g, a)),
-        'matvec': (
-            'matvec', kd.matvec, ref.matvec_ref,
-            lambda k, r: reduction_err(k, r, ref.matvec_ref(sq(g[0]), sq(a[0]))),
-            (g[0], a[0])),
-        'matvec_cols_stacked': (
-            'matvec_cols', kd.matvec_cols_stacked, ref.matvec_cols_ref,
-            lambda k, r: reduction_err(k, r, ref.matvec_cols_ref(sq(g),
-                                                                 sq(cols))),
-            (g, cols)),
-        'matvec_cols': (
-            'matvec_cols', kd.matvec_cols, ref.matvec_cols_ref,
-            lambda k, r: reduction_err(k, r, ref.matvec_cols_ref(sq(g[0]),
-                                                                 sq(cols[0]))),
-            (g[0], cols[0])),
-        'rank1_update_stacked': (
-            'rank1_update', kd.rank1_update_stacked, ref.rank1_update_ref,
-            rank1_err(g, a, b, coeff, scale), (g, a, b, coeff, scale)),
-        'rank1_update': (
-            'rank1_update', kd.rank1_update, ref.rank1_update_ref,
-            rank1_err(g[0], a[0], b[0], coeff[0], scale[0]),
-            (g[0], a[0], b[0], coeff[0], scale[0])),
         'eva_fused_stacked': (
-            'eva_fused',
-            lambda g_, a_, b_, m_, impl: kd.eva_fused_stacked(
-                g_, a_, b_, GAMMA, m_, MU, impl=impl),
+            lambda g_, a_, b_, m_: kd.eva_fused(g_, a_, b_, GAMMA, m_, MU),
             lambda g_, a_, b_, m_: ref.eva_fused_ref(g_, a_, b_, GAMMA, m_, MU),
-            fused_err(aux_terms(g32)), (g, a, b, mom)),
+            (g, a, b, mom)),
         'eva_f_fused_stacked': (
-            'eva_f_fused',
-            lambda g_, a_, m_, impl: kd.eva_f_fused_stacked(
-                g_, a_, GAMMA, m_, MU, impl=impl),
+            lambda g_, a_, m_: kd.eva_f_fused(g_, a_, GAMMA, m_, MU),
             lambda g_, a_, m_: ref.eva_f_fused_ref(g_, a_, GAMMA, m_, MU),
-            fused_err(aux_terms(g32)), (g, a, mom)),
+            (g, a, mom)),
     }
+
+
+def _fused_err(g, k, r):
+    """Output error relative to max |out|; aux error relative to the sum of
+    the magnitudes of each partial's terms."""
+    import jax.numpy as jnp
+    (ko, ka), (ro, ra) = k, r
+    g32 = g.astype(jnp.float32)
+    terms = jnp.stack([jnp.sum(jnp.abs(ro * g32), (-2, -1)),
+                       jnp.sum(ro * ro, (-2, -1)),
+                       jnp.sum(g32 * g32, (-2, -1))], -1)
+    e_out = jnp.max(jnp.abs(ko - ro)) / jnp.max(jnp.abs(ro))
+    return jnp.maximum(e_out, jnp.max(jnp.abs(ka - ra) / terms))
 
 
 def kernel_phase():
@@ -201,6 +140,8 @@ def kernel_phase():
     import jax.numpy as jnp
     from repro.kernels import dispatch as kd
 
+    check(kd.backend() == 'tpu', f'backend {kd.backend()!r} takes the jnp '
+          'twins, not the kernels')
     shapes = qwen_kernel_shapes()
     print(f'[kernels] qwen2-0.5b buckets (L, d_in, d_out): {shapes}',
           flush=True)
@@ -210,24 +151,19 @@ def kernel_phase():
         for dt in (jnp.bfloat16, jnp.float32):
             key, sub = jax.random.split(key)
             dname = jnp.dtype(dt).name
-            tol_el = TOL[dname]
-            for name, (op, kern, reff, err_fn, args) in _kernel_cases(
+            for name, (kern, reff, args) in _kernel_cases(
                     L, m, n, dt, sub).items():
-                k = jax.jit(lambda *x, kern=kern: kern(*x, impl='pallas'))(*args)
+                compiled = jax.jit(kern).lower(*args).compile()
+                check(name in compiled.as_text(),
+                      f'{name}: no such kernel in the compiled call')
+                k = compiled(*args)
                 with jax.default_matmul_precision('highest'):
                     r = jax.jit(reff)(*args)
-                err = float(err_fn(k, r))
-                choice = kd.choices_snapshot()[op]
-                check(choice.startswith('pallas ') and '/interpret' not in
-                      choice, f'{name} resolved to {choice!r}, not compiled '
-                      'pallas')
-                tol = tol_el if name.startswith('rank1') else \
-                    TOL['fused'] if 'fused' in name else TOL['reduce']
+                err = float(_fused_err(args[0], k, r))
                 print(f'[kernels] {name:20s} {L}x{m}x{n} {dname:8s} '
-                      f'{choice.split(" @")[0]:16s} err {err:.3e} '
-                      f'(tol {tol:.1e})', flush=True)
-                check(math.isfinite(err) and err <= tol,
-                      f'{name} {L}x{m}x{n} {dname}: err {err} > tol {tol}')
+                      f'err {err:.3e} (tol {TOL:.1e})', flush=True)
+                check(math.isfinite(err) and err <= TOL,
+                      f'{name} {L}x{m}x{n} {dname}: err {err} > tol {TOL}')
                 n_ok += 1
     print(f'[kernels] {n_ok} compiled pallas calls within tolerance',
           flush=True)
@@ -252,7 +188,7 @@ def trainer_phase():
             '--batch', str(SMOKE_BATCH), '--steps', str(TRAIN_STEPS),
             '--ckpt-every', '0', '--log-every', '1']
     for tag, extra in (('default', []),
-                       ('fused', ['--fused', '--kernel-impl', 'auto'])):
+                       ('fused', ['--fused'])):
         out = OUT / tag
         shutil.rmtree(out, ignore_errors=True)
         losses = train.main(base + extra + ['--out-dir', str(out)])
@@ -262,12 +198,12 @@ def trainer_phase():
               f'{tag}: non-finite loss {losses}')
         check(losses[-1] < losses[0],
               f'{tag}: loss did not fall: {losses[0]} -> {losses[-1]}')
-        impl = 'inline jnp (no dispatch)'
+        impl = 'inline jnp'
         if extra:
-            choice = kd.choices_snapshot()['eva_fused']
-            impl = f"{steps[-1]['kernel_impl']} -> eva_fused {choice}"
-            check(choice.startswith('pallas ') and '/interpret' not in
-                  choice, f'{tag}: eva_fused resolved to {choice!r}')
+            impl = (f'backend {kd.backend()} -> '
+                    + ('compiled Pallas eva_fused_stacked, '
+                       f'{kd.BLOCK}-wide blocks' if kd.backend() == 'tpu'
+                       else 'jnp twin eva_fused_ref'))
         print(f'[trainer] {tag}: batch {SMOKE_BATCH}x2048, losses '
               + ' '.join(f'{x:.4f}' for x in losses), flush=True)
         print(f'[trainer] {tag}: step times after the first (s) {times}',

@@ -26,7 +26,6 @@ from repro.core.clipping import finish_kl_clip, kl_clip_trace
 from repro.core.transform import (Extras, GradientTransformation, chain,
                                   add_decayed_weights, ema_trace,
                                   scale_by_schedule, tree_vdot)
-from repro.kernels import dispatch
 from repro.schedule import (pipeline as pipemod, policy as schedpol,
                             runtime as schedrt)
 
@@ -141,9 +140,8 @@ def _kv_step(state, updates, extras, *, fields, site, policy, interval,
 
 
 def eva_preconditioner(gamma: float = 0.03, kv_decay: float = 0.95,
-                       use_pallas: bool = False, interval: int = 1,
-                       policy: Optional[schedpol.RefreshPolicy] = None,
-                       impl: Optional[str] = None
+                       interval: int = 1,
+                       policy: Optional[schedpol.RefreshPolicy] = None
                        ) -> GradientTransformation:
     """Bucketed P = (G − (b̄ᵀGā)/(γ+‖ā‖²‖b̄‖²)·āb̄ᵀ)/γ with EMA'd KVs.
 
@@ -162,10 +160,7 @@ def eva_preconditioner(gamma: float = 0.03, kv_decay: float = 0.95,
         flat, plan, used, parts = _kv_step(
             state, updates, extras, fields=fields, site='stats/eva',
             policy=policy, interval=interval, kv_decay=kv_decay)
-        k_impl = dispatch.impl_from_extras(
-            extras, pre._kernel_impl(use_pallas, impl))
-        out = pre.precondition_tree(flat, used, 'eva', gamma, plan=plan,
-                                    impl=k_impl)
+        out = pre.precondition_tree(flat, used, 'eva', gamma, plan=plan)
         return kvlib.unflatten_params(out), EvaState(**parts)
 
     return GradientTransformation(init, update)
@@ -173,13 +168,12 @@ def eva_preconditioner(gamma: float = 0.03, kv_decay: float = 0.95,
 
 def eva_fused_update(lr=0.1, gamma: float = 0.03, kv_decay: float = 0.95,
                      kl_kappa: float = 1e-3, momentum: float = 0.9,
-                     fold_kl: bool = True, impl: Optional[str] = None,
-                     interval: int = 1,
+                     fold_kl: bool = True, interval: int = 1,
                      policy: Optional[schedpol.RefreshPolicy] = None
                      ) -> GradientTransformation:
     """Preconditioner + KL trust region + heavy-ball as ONE transform.
 
-    Each bucket runs a single ``eva_fused`` dispatch (``kernels/fused.py``)
+    Each bucket runs a single ``eva_fused`` launch (``kernels/dispatch.py``)
     that preconditions, folds ``m ← μ·m + P``, and emits the ⟨u,g⟩ partials
     the Eq. 16 clip needs — the separate kl_clip_trace tree passes
     disappear.  ``fold_kl=False`` (set when weight decay runs before the
@@ -200,11 +194,10 @@ def eva_fused_update(lr=0.1, gamma: float = 0.03, kv_decay: float = 0.95,
         flat, plan, used, parts = _kv_step(
             state, updates, extras, fields=fields, site='stats/eva',
             policy=policy, interval=interval, kv_decay=kv_decay)
-        k_impl = dispatch.impl_from_extras(extras, impl)
         out_flat, partials = pre.precondition_tree_fused(
             flat, used, 'eva', gamma, plan=plan,
             trace=kvlib.flatten_params(state.trace), momentum=momentum,
-            fold_momentum=True, impl=k_impl)
+            fold_momentum=True)
         u = kvlib.unflatten_params(out_flat)
         if fold_kl:
             kl = sum(partials[p][0] for p in sorted(partials))
@@ -219,18 +212,16 @@ def eva_fused_update(lr=0.1, gamma: float = 0.03, kv_decay: float = 0.95,
 def eva(lr=0.1, gamma: float = 0.03, kv_decay: float = 0.95,
         kl_kappa: float = 1e-3, momentum: float = 0.9,
         weight_decay: float = 0.0, nesterov: bool = False,
-        use_pallas: bool = False, interval: int = 1,
+        interval: int = 1,
         policy: Optional[schedpol.RefreshPolicy] = None,
-        fused: bool = False,
-        kernel_impl: Optional[str] = None) -> GradientTransformation:
+        fused: bool = False) -> GradientTransformation:
     """The full Eva optimizer as evaluated in the paper (§5).
 
     ``fused=True`` collapses preconditioner + KL clip + momentum into one
     kernel launch per bucket (``eva_fused_update``); it requires the
     non-nesterov trust-region tail, so nesterov / ``kl_kappa=None`` configs
-    fall back to the composed chain.  ``kernel_impl`` is the dispatch
-    request for the kernel ops (overridable per step via
-    ``Extras.kernel``).
+    fall back to the composed chain.  The backend picks the kernel
+    (``kernels/dispatch.py``).
     """
     parts = []
     if weight_decay:
@@ -240,14 +231,12 @@ def eva(lr=0.1, gamma: float = 0.03, kv_decay: float = 0.95,
     if fused and kl_kappa is not None and not nesterov:
         parts.append(eva_fused_update(
             lr, gamma, kv_decay, kl_kappa, momentum,
-            fold_kl=(weight_decay == 0.0),
-            impl=kernel_impl or pre._kernel_impl(use_pallas, None),
-            interval=interval, policy=policy))
+            fold_kl=(weight_decay == 0.0), interval=interval,
+            policy=policy))
         parts.append(scale_by_schedule(lr if callable(lr) else (lambda _: lr)))
         return chain(*parts)
-    parts.append(eva_preconditioner(gamma, kv_decay, use_pallas=use_pallas,
-                                    interval=interval, policy=policy,
-                                    impl=kernel_impl))
+    parts.append(eva_preconditioner(gamma, kv_decay, interval=interval,
+                                    policy=policy))
     if kl_kappa is not None:
         # momentum lives INSIDE the trust region (see clipping.kl_clip_trace)
         parts.append(kl_clip_trace(kl_kappa, lr, momentum, nesterov=nesterov))

@@ -6,7 +6,7 @@ bucket, bucket-level KV EMA, distributed psum hook.  KV-snapshot refresh is
 scheduled through ``repro.schedule`` (same knob as the baselines).
 
 ``eva_f(fused=True)`` runs the preconditioner as one ``eva_f_fused``
-dispatch per bucket, folding the ⟨p,g⟩ inner product the KL normalizer
+launch per bucket, folding the ⟨p,g⟩ inner product the KL normalizer
 needs into the kernel epilogue; the normalize + EMA tail itself stays a
 single jnp pass (its global scale depends on every bucket, so it cannot
 live inside a per-bucket launch — see ``kernels/fused.py``)."""
@@ -21,7 +21,6 @@ from repro.core.eva import _kv_init, _kv_step, _zeros_like_spec
 from repro.core.transform import (Extras, GradientTransformation, chain,
                                   add_decayed_weights, ema_trace,
                                   scale_by_schedule, tree_vdot)
-from repro.kernels import dispatch
 from repro.schedule import policy as schedpol
 
 
@@ -39,9 +38,8 @@ _FIELDS = ('a_mean',)
 
 
 def eva_f_preconditioner(gamma: float = 0.03, kv_decay: float = 0.95,
-                         use_pallas: bool = False, interval: int = 1,
-                         policy: Optional[schedpol.RefreshPolicy] = None,
-                         impl: Optional[str] = None
+                         interval: int = 1,
+                         policy: Optional[schedpol.RefreshPolicy] = None
                          ) -> GradientTransformation:
 
     def init(params, extras: Extras | None = None):
@@ -53,10 +51,7 @@ def eva_f_preconditioner(gamma: float = 0.03, kv_decay: float = 0.95,
         flat, plan, used, parts = _kv_step(
             state, updates, extras, fields=_FIELDS, site='stats/eva_f',
             policy=policy, interval=interval, kv_decay=kv_decay)
-        k_impl = dispatch.impl_from_extras(
-            extras, pre._kernel_impl(use_pallas, impl))
-        out = pre.precondition_tree(flat, used, 'eva_f', gamma, plan=plan,
-                                    impl=k_impl)
+        out = pre.precondition_tree(flat, used, 'eva_f', gamma, plan=plan)
         return kvlib.unflatten_params(out), EvaFState(**parts)
 
     return GradientTransformation(init, update)
@@ -64,7 +59,7 @@ def eva_f_preconditioner(gamma: float = 0.03, kv_decay: float = 0.95,
 
 def eva_f_fused_update(gamma: float = 0.03, kv_decay: float = 0.95,
                        momentum: float = 0.9, fold_kl: bool = True,
-                       impl: Optional[str] = None, interval: int = 1,
+                       interval: int = 1,
                        policy: Optional[schedpol.RefreshPolicy] = None
                        ) -> GradientTransformation:
     """Preconditioner + KL normalize + EMA momentum as ONE transform.
@@ -88,10 +83,8 @@ def eva_f_fused_update(gamma: float = 0.03, kv_decay: float = 0.95,
         flat, plan, used, parts = _kv_step(
             state, updates, extras, fields=_FIELDS, site='stats/eva_f',
             policy=policy, interval=interval, kv_decay=kv_decay)
-        k_impl = dispatch.impl_from_extras(extras, impl)
         out_flat, partials = pre.precondition_tree_fused(
-            flat, used, 'eva_f', gamma, plan=plan, fold_momentum=False,
-            impl=k_impl)
+            flat, used, 'eva_f', gamma, plan=plan, fold_momentum=False)
         p = kvlib.unflatten_params(out_flat)
         if fold_kl:
             pg = sum(partials[k][0] for k in sorted(partials))
@@ -106,23 +99,19 @@ def eva_f_fused_update(gamma: float = 0.03, kv_decay: float = 0.95,
 
 def eva_f(lr=0.1, gamma: float = 0.03, kv_decay: float = 0.95,
           momentum: float = 0.9, weight_decay: float = 0.0,
-          use_pallas: bool = False, interval: int = 1,
+          interval: int = 1,
           policy: Optional[schedpol.RefreshPolicy] = None,
-          fused: bool = False,
-          kernel_impl: Optional[str] = None) -> GradientTransformation:
+          fused: bool = False) -> GradientTransformation:
     parts = []
     if weight_decay:
         parts.append(add_decayed_weights(weight_decay))
     if fused:
         parts.append(eva_f_fused_update(
             gamma, kv_decay, momentum, fold_kl=(weight_decay == 0.0),
-            impl=kernel_impl or pre._kernel_impl(use_pallas, None),
             interval=interval, policy=policy))
     else:
         parts.append(eva_f_preconditioner(gamma, kv_decay,
-                                          use_pallas=use_pallas,
-                                          interval=interval, policy=policy,
-                                          impl=kernel_impl))
+                                          interval=interval, policy=policy))
         parts.append(kl_normalize())
         parts.append(ema_trace(momentum))
     parts.append(scale_by_schedule(lr if callable(lr) else (lambda _: lr)))

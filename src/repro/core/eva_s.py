@@ -31,7 +31,6 @@ from repro.core.eva import (_eva_cached_init, _refresh_snapshot,
 from repro.core.transform import (Extras, GradientTransformation, chain,
                                   add_decayed_weights, ema_trace,
                                   scale_by_schedule)
-from repro.kernels import dispatch
 from repro.schedule import policy as schedpol, runtime as schedrt
 
 
@@ -82,10 +81,10 @@ def _kv_step_s(state, updates, extras, *, policy, interval, kv_decay,
 
 
 def eva_s_preconditioner(gamma: float = 0.03, kv_decay: float = 0.95,
-                         use_pallas: bool = False, interval: int = 1,
+                         interval: int = 1,
                          policy: Optional[schedpol.RefreshPolicy] = None,
-                         predicate=default_precon_predicate,
-                         impl: Optional[str] = None) -> GradientTransformation:
+                         predicate=default_precon_predicate
+                         ) -> GradientTransformation:
 
     def init(params, extras: Extras | None = None):
         return EvaSState(**_kv_init_s(params, extras, policy, interval,
@@ -96,10 +95,7 @@ def eva_s_preconditioner(gamma: float = 0.03, kv_decay: float = 0.95,
         flat, plan, used, parts = _kv_step_s(
             state, updates, extras, policy=policy, interval=interval,
             kv_decay=kv_decay, predicate=predicate)
-        k_impl = dispatch.impl_from_extras(
-            extras, pre._kernel_impl(use_pallas, impl))
-        out = pre.precondition_tree(flat, used, 'eva_s', gamma, plan=plan,
-                                    impl=k_impl)
+        out = pre.precondition_tree(flat, used, 'eva_s', gamma, plan=plan)
         return kvlib.unflatten_params(out), EvaSState(**parts)
 
     return GradientTransformation(init, update)
@@ -107,7 +103,7 @@ def eva_s_preconditioner(gamma: float = 0.03, kv_decay: float = 0.95,
 
 def eva_s_fused_update(gamma: float = 0.03, kv_decay: float = 0.95,
                        momentum: float = 0.9, fold_graft: bool = True,
-                       impl: Optional[str] = None, interval: int = 1,
+                       interval: int = 1,
                        policy: Optional[schedpol.RefreshPolicy] = None,
                        predicate=default_precon_predicate
                        ) -> GradientTransformation:
@@ -131,10 +127,8 @@ def eva_s_fused_update(gamma: float = 0.03, kv_decay: float = 0.95,
         flat, plan, used, parts = _kv_step_s(
             state, updates, extras, policy=policy, interval=interval,
             kv_decay=kv_decay, predicate=predicate)
-        k_impl = dispatch.impl_from_extras(extras, impl)
         out_flat, partials = pre.precondition_tree_fused(
-            flat, used, 'eva_s', gamma, plan=plan, fold_momentum=False,
-            impl=k_impl)
+            flat, used, 'eva_s', gamma, plan=plan, fold_momentum=False)
         pp = {p: partials[p][1] for p in partials}
         if fold_graft:
             gg = {p: partials[p][2] for p in partials}
@@ -153,23 +147,19 @@ def eva_s_fused_update(gamma: float = 0.03, kv_decay: float = 0.95,
 
 def eva_s(lr=0.1, gamma: float = 0.03, kv_decay: float = 0.95,
           momentum: float = 0.9, weight_decay: float = 0.0,
-          use_pallas: bool = False, interval: int = 1,
+          interval: int = 1,
           policy: Optional[schedpol.RefreshPolicy] = None,
-          fused: bool = False,
-          kernel_impl: Optional[str] = None) -> GradientTransformation:
+          fused: bool = False) -> GradientTransformation:
     parts = []
     if weight_decay:
         parts.append(add_decayed_weights(weight_decay))
     if fused:
         parts.append(eva_s_fused_update(
             gamma, kv_decay, momentum, fold_graft=(weight_decay == 0.0),
-            impl=kernel_impl or pre._kernel_impl(use_pallas, None),
             interval=interval, policy=policy))
     else:
         parts.append(eva_s_preconditioner(gamma, kv_decay,
-                                          use_pallas=use_pallas,
-                                          interval=interval, policy=policy,
-                                          impl=kernel_impl))
+                                          interval=interval, policy=policy))
         parts.append(graft_to_grad_magnitude())
         parts.append(ema_trace(momentum))
     parts.append(scale_by_schedule(lr if callable(lr) else (lambda _: lr)))

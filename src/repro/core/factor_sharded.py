@@ -69,15 +69,11 @@ class FactorShardConfig:
       arch; the launcher exposes ``--head-threshold``.
     solver / solve_iters: iterative scheme for 'shard' ('cg' valid for
       power −1 only; Shampoo's −1/4 root always takes the binomial series).
-    use_pallas: route band partials through the column-blocked Pallas
-      matvec kernels (``kernels/matvec.py``); default is the identical
-      einsum form.
     """
     head_policy: str = 'dense'
     shard_threshold: int = 65536
     solver: str = 'cg'
     solve_iters: int = 32
-    use_pallas: bool = False
 
     def __post_init__(self):
         if self.head_policy not in POLICIES:
@@ -158,8 +154,8 @@ def _band(m: jnp.ndarray, world: int, rank) -> jnp.ndarray:
     return jax.lax.dynamic_slice_in_dim(m, rank * blk, blk, axis=-2)
 
 
-def _matvec_partial(band: jnp.ndarray, y: jnp.ndarray, world: int, rank,
-                    use_pallas: bool = False) -> jnp.ndarray:
+def _matvec_partial(band: jnp.ndarray, y: jnp.ndarray, world: int,
+                    rank) -> jnp.ndarray:
     """Partial of ``y @ M`` from this worker's row band (M symmetric, so
     the row band is the transposed column block): contracts only the owned
     columns of ``y`` — FLOPs 1/W — and returns a full-width (..., R, d)
@@ -173,12 +169,6 @@ def _matvec_partial(band: jnp.ndarray, y: jnp.ndarray, world: int, rank,
         width = [(0, 0)] * (y.ndim - 1) + [(0, pad)]
         y = jnp.pad(y, width)
     y_blk = jax.lax.dynamic_slice_in_dim(y, rank * blk, blk, axis=-1)
-    if use_pallas:
-        from repro.kernels import matvec as kmv
-        if band.ndim == 2 and y_blk.ndim == 2:
-            return kmv.matvec_cols(band, y_blk)
-        if band.ndim == 3 and y_blk.ndim == 3:
-            return kmv.matvec_cols_stacked(band, y_blk)
     return jnp.einsum('...ri,...ij->...rj', y_blk, band)
 
 
@@ -220,8 +210,7 @@ def solve_damped_power(m: jnp.ndarray, y: jnp.ndarray, gamma, power: float,
     extra = {'solve_iters': iters, 'factor_shard_bytes': int(shard_bytes)}
 
     def mv(v):
-        part = _matvec_partial(band, v, world, rank,
-                               use_pallas=cfg.use_pallas)
+        part = _matvec_partial(band, v, world, rank)
         return exchange.psum_partials(part, axes, world, site=site,
                                       calls=iters, extra=extra)
 

@@ -5,18 +5,11 @@ leading dims are layer stacks / experts and every formula broadcasts over
 them, which is what lets a whole ``lax.scan``-stacked model be preconditioned
 in one fused XLA region instead of a per-layer Python loop.
 
-Kernel routing: ``impl=`` hands the two hot operations (bilinear form +
-rank-1 update) to the dispatch layer (``repro.kernels.dispatch``), which
-picks compiled Pallas / interpret Pallas / the pure-XLA ``ref.py`` path per
-(op, backend, shape, dtype).  ``use_pallas=True`` is the historical alias
-for ``impl='pallas'``.  ``impl=None`` keeps the inline broadcast-jnp path
-below — mathematically identical (the kernels are asserted against these
-functions in tests).
+The rank-one formulas here are the unfused path on every backend.  Only
+``precondition_tree_fused`` calls a kernel, through the backend rule of
+``repro.kernels.dispatch``.
 """
 from __future__ import annotations
-
-from functools import partial
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -32,19 +25,9 @@ def _f32(x):
 # (paper layout ΔW ∝ b̄ āᵀ is for (d_out,d_in) weights; ours is transposed)
 
 
-def _kernel_impl(use_pallas: bool, impl: Optional[str]) -> Optional[str]:
-    """Back-compat shim: ``use_pallas=True`` is ``impl='pallas'``."""
-    return impl or ('pallas' if use_pallas else None)
-
-
 def eva_precondition(g: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray,
-                     gamma: float, use_pallas: bool = False,
-                     impl: Optional[str] = None) -> jnp.ndarray:
+                     gamma: float) -> jnp.ndarray:
     """g: (..., d_in, d_out); a: (..., d_in); b: (..., d_out)."""
-    impl = _kernel_impl(use_pallas, impl)
-    if impl:
-        from repro.kernels import ops as kops
-        return kops.eva_precondition(g, a, b, gamma, impl=impl)
     g32, a32, b32 = _f32(g), _f32(a), _f32(b)
     dot = jnp.einsum('...io,...i,...o->...', g32, a32, b32)
     denom = gamma + jnp.sum(a32 * a32, -1) * jnp.sum(b32 * b32, -1)
@@ -57,14 +40,9 @@ def eva_precondition(g: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray,
 # Eva-f (Eq. 21): P = (G - ā (āᵀ G) / (γ + ‖ā‖²)) / γ
 
 
-def eva_f_precondition(g: jnp.ndarray, a: jnp.ndarray, gamma: float,
-                       use_pallas: bool = False,
-                       impl: Optional[str] = None) -> jnp.ndarray:
+def eva_f_precondition(g: jnp.ndarray, a: jnp.ndarray,
+                       gamma: float) -> jnp.ndarray:
     """g: (..., d_in, d_out); a: (..., d_in)."""
-    impl = _kernel_impl(use_pallas, impl)
-    if impl:
-        from repro.kernels import ops as kops
-        return kops.eva_f_precondition(g, a, gamma, impl=impl)
     g32, a32 = _f32(g), _f32(a)
     u = jnp.einsum('...io,...i->...o', g32, a32)          # āᵀG  (..., d_out)
     denom = gamma + jnp.sum(a32 * a32, -1)
@@ -83,13 +61,8 @@ def grad_kvs(g: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
 
 
 def eva_s_precondition(g: jnp.ndarray, v_in: jnp.ndarray, v_out: jnp.ndarray,
-                       gamma: float, use_pallas: bool = False,
-                       impl: Optional[str] = None) -> jnp.ndarray:
+                       gamma: float) -> jnp.ndarray:
     """Same rank-one form as Eva with (v_in, v_out) in place of (ā, b̄)."""
-    impl = _kernel_impl(use_pallas, impl)
-    if impl:
-        from repro.kernels import ops as kops
-        return kops.eva_precondition(g, v_in, v_out, gamma, impl=impl)
     g32, vi, vo = _f32(g), _f32(v_in), _f32(v_out)
     dot = jnp.einsum('...io,...i,...o->...', g32, vi, vo)
     denom = gamma + jnp.sum(vi * vi, -1) * jnp.sum(vo * vo, -1)
@@ -161,8 +134,7 @@ def shampoo_precondition(g: jnp.ndarray, m_in: jnp.ndarray, m_out: jnp.ndarray,
 
 
 def precondition_tree(updates: dict, aux: dict, method: str, gamma: float, *,
-                      plan=None, use_pallas: bool = False,
-                      impl: Optional[str] = None) -> dict:
+                      plan=None) -> dict:
     """Precondition a flat ``{path: grad}`` tree with ONE vectorized call
     per parameter bucket (paper §3-§4: the formulas broadcast, so same-shape
     layers batch into a single launch instead of a per-path Python loop).
@@ -185,12 +157,6 @@ def precondition_tree(updates: dict, aux: dict, method: str, gamma: float, *,
         ``*_cached`` variant applying precomputed operators.
       plan: ``bucketing.BucketPlan`` built at ``init_opt_state`` time;
         derived (memoized) from ``aux``'s paths when omitted.
-      use_pallas: route the rank-one methods through the grid-folded Pallas
-        kernels (one launch per bucket, ``kernels/ops.py``) — alias for
-        ``impl='pallas'``.
-      impl: kernel dispatch request for the rank-one methods
-        (``kernels/dispatch.py``: 'auto' | 'pallas' | 'pallas_interpret' |
-        'xla'); ``None`` keeps the inline broadcast-jnp formulas above.
 
     Bucket layout & version support: buckets group paths by (shape, dtype)
     with a new stacking axis 0 (``bucketing.build_plan``); scan-stacked
@@ -227,14 +193,11 @@ def precondition_tree(updates: dict, aux: dict, method: str, gamma: float, *,
         and cached-operator formulas broadcast over it, the LAPACK methods
         fuse it with one ``lax.map`` (or apply directly per item)."""
         if method == 'eva':
-            return eva_precondition(g, st.a_mean, st.b_mean, gamma,
-                                    use_pallas=use_pallas, impl=impl)
+            return eva_precondition(g, st.a_mean, st.b_mean, gamma)
         if method == 'eva_f':
-            return eva_f_precondition(g, st.a_mean, gamma,
-                                      use_pallas=use_pallas, impl=impl)
+            return eva_f_precondition(g, st.a_mean, gamma)
         if method == 'eva_s':
-            return eva_s_precondition(g, st.a_mean, st.b_mean, gamma,
-                                      use_pallas=use_pallas, impl=impl)
+            return eva_s_precondition(g, st.a_mean, st.b_mean, gamma)
         if method == 'foof':
             if not stacked:
                 return foof_precondition(g, st.a_outer, gamma)
@@ -283,13 +246,13 @@ def precondition_tree(updates: dict, aux: dict, method: str, gamma: float, *,
 def precondition_tree_fused(updates: dict, aux: dict, method: str,
                             gamma: float, *, plan=None, trace=None,
                             momentum: float = 0.0,
-                            fold_momentum: bool = False,
-                            impl: Optional[str] = None):
+                            fold_momentum: bool = False):
     """Fused precondition → update-epilogue over a flat gradient tree.
 
-    One ``eva_fused``/``eva_f_fused`` dispatch per bucket instead of the
-    bilinear + rank1_update pair plus separate momentum/inner-product tree
-    passes (``kernels/fused.py``).  Rank-one methods only ('eva' | 'eva_f' |
+    One ``dispatch.eva_fused``/``eva_f_fused`` call per bucket instead of
+    the formulas above plus separate momentum/inner-product tree passes:
+    the compiled Pallas kernel on the TPU, its jnp twin elsewhere
+    (``kernels/dispatch.py``).  Rank-one methods only ('eva' | 'eva_f' |
     'eva_s'); paths outside the plan pass through with the same epilogue
     applied in jnp.
 
@@ -308,7 +271,7 @@ def precondition_tree_fused(updates: dict, aux: dict, method: str,
     that, see ``core/eva.py``).
     """
     from repro.core import bucketing
-    from repro.kernels import ops as kops
+    from repro.kernels import dispatch
 
     if method not in ('eva', 'eva_f', 'eva_s'):
         raise ValueError(f'precondition_tree_fused: rank-one methods only, '
@@ -331,10 +294,10 @@ def precondition_tree_fused(updates: dict, aux: dict, method: str,
 
     def run(g, st, m):
         if method == 'eva_f':
-            return kops.eva_f_fused(g, st.a_mean, gamma, m, mu,
-                                    fold_momentum=fold_momentum, impl=impl)
-        return kops.eva_fused(g, st.a_mean, st.b_mean, gamma, m, mu,
-                              fold_momentum=fold_momentum, impl=impl)
+            return dispatch.eva_f_fused(g, st.a_mean, gamma, m, mu,
+                                        fold_momentum=fold_momentum)
+        return dispatch.eva_fused(g, st.a_mean, st.b_mean, gamma, m, mu,
+                                  fold_momentum=fold_momentum)
 
     with jax.named_scope('precondition'):
         out, partials = {}, {}

@@ -51,11 +51,6 @@ class Extras:
         (``head_policy='shard'|'exclude'|'dense'``, the sub-slice
         ``shard_threshold`` and the iterative-solver knobs).  Omitting it
         keeps every factor on the dense legacy path, bit-exactly.
-      kernel: optional ``repro.kernels.dispatch.KernelConfig`` — the
-        launcher-level kernel knobs (impl request 'auto' | 'pallas' |
-        'pallas_interpret' | 'xla', autotune-cache path).  Omitting it
-        leaves each preconditioner on its own ``impl``/``use_pallas``
-        arguments (the historical behavior).
     """
 
     raw_grads: Any = None
@@ -66,7 +61,6 @@ class Extras:
     sched: Any = None
     comm: Any = None
     factor: Any = None
-    kernel: Any = None
 
 
 class GradientTransformation(NamedTuple):

@@ -1,3 +1,3 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels for the chip's hot paths (attention, Eva's fused update),
+their jnp twins (``ref``), and the backend rule that picks them
+(``dispatch``)."""

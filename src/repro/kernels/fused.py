@@ -1,20 +1,26 @@
 """Pallas kernels: fused Eva precondition -> update epilogue, one launch.
 
 The composed bucket hot path costs ~4 gradient-sized HBM round trips after
-the stats are ready: ``bilinear`` reads G, ``rank1_update`` reads G and
-writes P, the momentum trace reads (m, P) and writes m, and the KL trust
+the stats are ready: the bilinear form reads G, the rank-one update reads G
+and writes P, the momentum trace reads (m, P) and writes m, and the KL trust
 region reads (m, G) again for the inner product.  These kernels do all of
 it in ONE pass over G per bucket:
 
   phase 0  accumulate the reduction (aᵀGb for Eva / aᵀG for Eva-f) into a
-           tiny VMEM-resident output, visiting tiles in exactly the same
-           order as the standalone ``bilinear``/``matvec`` kernels — the
-           reduction is bit-identical to the composed path;
-  phase 1  re-stream G: compute the rank-one tile P = s·(G − c·abᵀ)
-           (bit-identical to ``rank1_update``), optionally fold the
-           heavy-ball momentum ``out = μ·m + P``, write the f32 output
-           tile, and accumulate the epilogue partials
+           tiny VMEM-resident output, tile by tile in grid order;
+  phase 1  re-stream G: compute the rank-one tile P = s·(G − c·abᵀ),
+           optionally fold the heavy-ball momentum ``out = μ·m + P``, write
+           the f32 output tile, and accumulate the epilogue partials
            ``aux = [⟨out,G⟩, ⟨out,out⟩, ⟨G,G⟩]`` per stack item.
+
+A leading stack of L independent problems (a parameter bucket, see
+``core/bucketing``) rides the grid's leading axis: one launch per bucket,
+and each item's tiles run the same program in the same order as an
+unstacked (L=1) launch, so stacked and per-item results agree bit-for-bit.
+The tile contractions are written as an elementwise multiply + reduction,
+not ``jnp.dot``: reduction lowering is stable across grid-loop contexts,
+where dot_general on CPU may pick different blocked algorithms inside and
+outside a loop and break that bit-equality.
 
 The trust-region scale ν (Eq. 16) depends on the GLOBAL ⟨u,g⟩ across every
 parameter, so it cannot be applied inside a per-bucket launch; the aux
@@ -35,11 +41,10 @@ scalars in SMEM, and ``aux`` in lanes 0..2 of a (1, 128) block; the output
 and momentum blocks stay parked on tile (0, 0) through phase 0, so phase 0
 neither fetches m nor writes back an untouched output tile.
 
-"Bit-identical" above holds per tile formula; across a whole launch the
-in-kernel coeff division (``dot/denom``) can contract differently from the
-host-side division of the composed path, so end-to-end agreement with the
-composed chain is within 1 f32 ulp of the update scale (γ·|Δ| < 1e-6),
-not universally bit-exact — see tests/test_fused.py.
+Against the composed jnp chain of ``core/precondition.py`` the in-kernel
+coeff division (``dot/denom``) and the multiply by 1/γ round differently,
+so the two agree within 1 f32 ulp of the update scale (γ·|Δ| < 1e-6), not
+bit for bit — see tests/test_fused.py.
 """
 from __future__ import annotations
 
@@ -50,10 +55,47 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.bilinear import _tile_bilinear, as_col, as_row, pad_mat
-from repro.kernels.matvec import _tile_matvec
-from repro.kernels.rank1_update import SMEM_SPEC, _rank1_tile
 from repro.kernels.tiles import LANE, fit_tiles
+
+SMEM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _tile_bilinear(g, a_col, b_row):
+    """Contract one (bm, bn) tile against its (bm, 1) / (1, bn) slices ->
+    scalar f32."""
+    return jnp.sum((a_col * g) * b_row)
+
+
+def _tile_matvec(g, a_col):
+    """(bm, bn) tile × (bm, 1) slice -> (1, bn) partial products, f32."""
+    return jnp.sum(a_col * g, axis=0, keepdims=True)
+
+
+def _rank1_tile(g, a_col, b_row, coeff, scale):
+    return scale * (g - coeff * (a_col * b_row))
+
+
+def as_col(v, pad=0):
+    """(..., d) vector -> (..., d + pad, 1) f32 column, zero-padded."""
+    v = v.astype(jnp.float32)
+    if pad:
+        v = jnp.pad(v, [(0, 0)] * (v.ndim - 1) + [(0, pad)])
+    return v[..., :, None]
+
+
+def as_row(v, pad=0):
+    """(..., d) vector -> (..., 1, d + pad) f32 row, zero-padded."""
+    v = v.astype(jnp.float32)
+    if pad:
+        v = jnp.pad(v, [(0, 0)] * (v.ndim - 1) + [(0, pad)])
+    return v[..., None, :]
+
+
+def pad_mat(g, pad_in, pad_out):
+    """Zero-pad the trailing (d_in, d_out) dims of ``g``."""
+    if pad_in or pad_out:
+        g = jnp.pad(g, [(0, 0)] * (g.ndim - 2) + [(0, pad_in), (0, pad_out)])
+    return g
 
 
 def _epilogue_tile(g, p, m, mu, fold, o_ref, aux_ref):

@@ -1,12 +1,10 @@
-"""Pure-jnp oracles for the Pallas kernels — and the XLA dispatch path.
+"""Pure-jnp twins of the fused Pallas kernels (``kernels/fused.py``).
 
-Historically these were only ``assert_allclose`` targets for the kernel
-tests; the dispatch layer (``kernels/dispatch.py``) now routes production
-calls here when ``impl='xla'`` (the CPU default), so every op accepts both
-the single-matrix layout and the stacked/broadcast layout via ellipsis
-einsums.  Layouts match ``repro.core.precondition``: g (..., d_in, d_out),
-a (..., d_in), b (..., d_out).  Reductions are f32 regardless of input
-dtype, like the kernels.
+``kernels/dispatch.py`` runs these on every backend but the TPU, and the
+tests hold the kernels to them.  Every op accepts any leading stack dims
+via ellipsis einsums.  Layouts match ``repro.core.precondition``: g
+(..., d_in, d_out), a (..., d_in), b (..., d_out).  Reductions are f32
+regardless of input dtype, like the kernels.
 """
 from __future__ import annotations
 
@@ -20,49 +18,10 @@ def matvec_ref(g: jnp.ndarray, a: jnp.ndarray) -> jnp.ndarray:
                       a.astype(jnp.float32))
 
 
-def matvec_cols_ref(g: jnp.ndarray, a: jnp.ndarray) -> jnp.ndarray:
-    """Column-blocked partial matvec U_w = A_w G_w (factor sharding).
-
-    g (..., m, n) row band; a (..., R, m) owned columns -> (..., R, n) f32
-    partials (see ``kernels/matvec.py::matvec_cols``)."""
-    return jnp.einsum('...mn,...rm->...rn', g.astype(jnp.float32),
-                      a.astype(jnp.float32))
-
-
 def bilinear_ref(g: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """aᵀ G b — scalar per leading index.  -> (...) f32."""
     return jnp.einsum('...io,...i,...o->...', g.astype(jnp.float32),
                       a.astype(jnp.float32), b.astype(jnp.float32))
-
-
-def rank1_update_ref(g: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray,
-                     coeff, scale) -> jnp.ndarray:
-    """P = scale · (G − coeff · a bᵀ); coeff/scale scalar or (...,)."""
-    g32 = g.astype(jnp.float32)
-    coeff = jnp.asarray(coeff, jnp.float32)[..., None, None]
-    scale = jnp.asarray(scale, jnp.float32)[..., None, None]
-    out = scale * (g32 - coeff * (a.astype(jnp.float32)[..., :, None] *
-                                  b.astype(jnp.float32)[..., None, :]))
-    return out.astype(g.dtype)
-
-
-def eva_precondition_ref(g, a, b, gamma: float) -> jnp.ndarray:
-    """Full fused Eva preconditioning (Eq. 13), the composition target."""
-    dot = bilinear_ref(g, a, b)
-    a32, b32 = a.astype(jnp.float32), b.astype(jnp.float32)
-    denom = gamma + jnp.sum(a32 * a32, -1) * jnp.sum(b32 * b32, -1)
-    return rank1_update_ref(g, a, b, dot / denom,
-                            jnp.full_like(denom, 1.0 / gamma))
-
-
-def eva_f_precondition_ref(g, a, gamma: float) -> jnp.ndarray:
-    """Eva-f (Eq. 21): P = (G − a (aᵀG) / (γ+‖a‖²)) / γ."""
-    u = matvec_ref(g, a)
-    a32 = a.astype(jnp.float32)
-    denom = gamma + jnp.sum(a32 * a32, -1)
-    g32 = g.astype(jnp.float32)
-    outer = a32[..., :, None] * u[..., None, :]
-    return ((g32 - outer / denom[..., None, None]) / gamma).astype(g.dtype)
 
 
 def _fused_epilogue(g32, p, m, mu, fold_momentum):

@@ -1,4 +1,4 @@
-"""Tile fitting shared by the kernels and the dispatch layer.
+"""Tile fitting for the fused Eva kernels (``kernels/fused.py``).
 
 The kernels pad each operand up to a multiple of the block size and slice
 the pad back off.  Two rules, by whether the kernel is compiled for the TPU:
@@ -19,9 +19,6 @@ the pad back off.  Two rules, by whether the kernel is compiled for the TPU:
 
       d=4864, block=512, align=128 -> 256 (19 tiles, pad 0)
       d=896,  block=512, align=16  -> 448 (2 tiles, pad 0)
-
-Kept dependency-free (no jax import) so both the kernel modules and
-``dispatch`` can use it without an import cycle.
 """
 from __future__ import annotations
 

@@ -56,15 +56,6 @@ def main(argv: Optional[Sequence[str]] = None) -> list:
                          '(vocab-scale factors by default)')
     ap.add_argument('--solve-iters', type=int, default=32,
                     help="iterations of the head-policy='shard' solve")
-    ap.add_argument('--kernel-impl', default=None,
-                    choices=['auto', 'pallas', 'pallas_interpret', 'xla'],
-                    help='kernel dispatch impl for the Eva hot-path ops '
-                         '(kernels.dispatch); default: leave the optimizer '
-                         'on its own use_pallas behavior')
-    ap.add_argument('--autotune', action='store_true',
-                    help='benchmark tile/impl candidates for this model\'s '
-                         'preconditioned shapes, write the winner cache to '
-                         'the run dir and dispatch through it')
     ap.add_argument('--fused', action='store_true',
                     help='fused precondition→update epilogue: one kernel '
                          'launch per bucket for eva/eva_f/eva_s, single-'
@@ -125,27 +116,8 @@ def main(argv: Optional[Sequence[str]] = None) -> list:
     tc = TrainerConfig(total_steps=args.steps, log_every=args.log_every,
                        ckpt_every=args.ckpt_every, profile=args.profile,
                        out_dir=f'{args.out_dir}/{cfg.name}-{args.opt}')
-    kernel = None
-    if args.kernel_impl or args.autotune:
-        from repro.kernels import autotune as ktune
-        from repro.kernels.dispatch import KernelConfig
-        cache_path = None
-        if args.autotune:
-            # tune the distinct 2-D trailing shapes the preconditioner will
-            # actually dispatch (bucketed layers share a shape = one entry)
-            tuned = sorted({tuple(int(d) for d in shapes[p].shape[-2:])
-                            for p in model.precon_paths()
-                            if p in shapes and shapes[p].ndim >= 2})
-            print(f'[launch] autotuning {len(tuned)} shapes: {tuned}')
-            cache = ktune.tune(tuned)
-            cache_path = str(ktune.write(
-                cache, f'{tc.out_dir}/tile_cache.json'))
-            print(f'[launch] autotune cache -> {cache_path}')
-        kernel = KernelConfig(impl=args.kernel_impl or 'auto',
-                              autotune_cache=cache_path,
-                              autotune=args.autotune)
     trainer = Trainer(model, opt, capture, tc, taps_fn=taps_fn,
-                      factor=factor, kernel=kernel)
+                      factor=factor)
     try:
         if args.elastic:
             return trainer.fit_elastic(init(), data,

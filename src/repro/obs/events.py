@@ -77,11 +77,6 @@ SCHEMAS: dict[str, dict[str, Field]] = {
         'grad_norm': Field(_NUM),
         'step_time_s': Field(_NUM, unit='s'),
         'exchanged_mb_cum': Field(_NUM, unit='MiB'),
-        # kernel dispatch telemetry (optional fields: no version bump) —
-        # the requested impl and the latest per-op resolved tile choices
-        # (kernels.dispatch.choices_snapshot)
-        'kernel_impl': Field(_STR, unit="requested impl ('auto'|...)"),
-        'kernel_tiles': Field(_DICT, unit='op -> resolved impl+tiles'),
         **_declared(_schedrt),
         **_declared(_pipemod),
         **_declared(_fsh),

@@ -135,8 +135,7 @@ def make_train_step(model, opt: GradientTransformation,
                     microbatches: int = 1,
                     sched: Optional[schedrt.RefreshRuntime] = None,
                     comm: Optional[Any] = None,
-                    factor: Optional[Any] = None,
-                    kernel: Optional[Any] = None) -> Callable:
+                    factor: Optional[Any] = None) -> Callable:
     """Build the pure train step.  ``taps_fn(params)`` overrides tap creation
     (needed for full-tap K-FAC on the simple models).
 
@@ -154,11 +153,6 @@ def make_train_step(model, opt: GradientTransformation,
     threaded through ``Extras.factor``: the per-factor oversized-Kronecker
     policy (``head_policy='shard'|'exclude'|'dense'``).  None keeps every
     factor on the dense legacy path, bit-exactly.
-
-    ``kernel`` is a ``repro.kernels.dispatch.KernelConfig`` threaded
-    through ``Extras.kernel``: the per-step kernel impl request
-    (auto/pallas/xla dispatch + autotune-cache tiles).  None keeps the
-    optimizers on their own ``use_pallas``/``kernel_impl`` defaults.
 
     ``microbatches > 1`` runs gradient accumulation: the global batch is
     split on dim 0 and scanned, summing grads (f32) and averaging KV stats.
@@ -211,8 +205,7 @@ def make_train_step(model, opt: GradientTransformation,
                 grads, opt_state, params=params,
                 extras=Extras(stats=stats, loss=loss,
                               plan=_plan_for_stats(grads, stats),
-                              sched=sched, comm=comm, factor=factor,
-                              kernel=kernel))
+                              sched=sched, comm=comm, factor=factor))
         with jax.named_scope('apply'):
             new_params = apply_updates(params, updates)
         return new_params, new_opt_state, _step_metrics(
@@ -226,8 +219,7 @@ def make_dp_step(model, opt: GradientTransformation,
                  taps_fn: Optional[Callable] = None,
                  sched: Optional[schedrt.RefreshRuntime] = None,
                  comm: Optional[Any] = None,
-                 factor: Optional[Any] = None,
-                 kernel: Optional[Any] = None) -> Callable:
+                 factor: Optional[Any] = None) -> Callable:
     """Explicit data-parallel train step over ``mesh``'s ``'data'`` axis —
     the elastic trainer's engine (``train/trainer.py::Trainer.fit_elastic``).
 
@@ -278,8 +270,7 @@ def make_dp_step(model, opt: GradientTransformation,
                 grads, opt_state, params=params,
                 extras=Extras(stats=stats, loss=loss,
                               plan=_plan_for_stats(grads, stats),
-                              sched=sched, comm=comm, factor=factor,
-                              kernel=kernel))
+                              sched=sched, comm=comm, factor=factor))
         with jax.named_scope('apply'):
             new_params = apply_updates(params, updates)
         return new_params, new_opt_state, _step_metrics(
@@ -295,14 +286,13 @@ def init_opt_state(model, opt: GradientTransformation,
                    taps_fn: Optional[Callable] = None,
                    sched: Optional[schedrt.RefreshRuntime] = None,
                    comm: Optional[Any] = None,
-                   factor: Optional[Any] = None,
-                   kernel: Optional[Any] = None):
+                   factor: Optional[Any] = None):
     """Materialized optimizer state (examples/trainer).  ``batch`` may be
     arrays or ShapeDtypeStructs — stats shapes come from eval_shape."""
     sched = sched if sched is not None else schedrt.RefreshRuntime()
     if not capture.active:
         return opt.init(params, Extras(sched=sched, comm=comm,
-                                       factor=factor, kernel=kernel))
+                                       factor=factor))
     make_taps = taps_caller(taps_fn)
 
     def stats_of(p, b):
@@ -315,8 +305,7 @@ def init_opt_state(model, opt: GradientTransformation,
         lambda s: jnp.zeros(s.shape, s.dtype), stats_shapes)
     return opt.init(params, Extras(stats=zero_stats,
                                    plan=_plan_for_stats(params, zero_stats),
-                                   sched=sched, comm=comm, factor=factor,
-                                   kernel=kernel))
+                                   sched=sched, comm=comm, factor=factor))
 
 
 def stats_plan_of(model, capture: kvlib.CaptureConfig, params, batch,
@@ -341,10 +330,9 @@ def abstract_opt_state(model, opt: GradientTransformation,
                        taps_fn: Optional[Callable] = None,
                        sched: Optional[schedrt.RefreshRuntime] = None,
                        comm: Optional[Any] = None,
-                       factor: Optional[Any] = None,
-                       kernel: Optional[Any] = None):
+                       factor: Optional[Any] = None):
     """ShapeDtypeStruct pytree of the optimizer state (dry-run path)."""
     def init_fn(p, b):
         return init_opt_state(model, opt, capture, p, b, taps_fn, sched=sched,
-                              comm=comm, factor=factor, kernel=kernel)
+                              comm=comm, factor=factor)
     return jax.eval_shape(init_fn, params_abstract, batch_specs)

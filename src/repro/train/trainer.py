@@ -50,7 +50,6 @@ import jax
 
 from repro.core import kv as kvlib
 from repro.core.transform import GradientTransformation
-from repro.kernels import dispatch as kdispatch
 from repro.models import flash as attn
 from repro.obs import events as obs_events
 from repro.obs import spans as obs_spans
@@ -89,19 +88,16 @@ class Trainer:
         # per-factor oversized-Kronecker policy (core.factor_sharded);
         # None = every factor dense, the bit-exact legacy path
         self.factor = factor
-        # kernel dispatch request (kernels.dispatch.KernelConfig); a cache
-        # path installs its autotuned tiles before anything traces
-        self.kernel = kernel
-        if kernel is not None and kernel.autotune_cache:
-            from repro.kernels import dispatch as _dispatch
-            _dispatch.install_cache(kernel.autotune_cache)
+        # ``kernel`` (a kernels.dispatch.KernelConfig) selects nothing: the
+        # backend picks the kernel.  It is accepted because
+        # bench/harness.py passes the one its traffic files name.
+        del kernel
         self.out_dir = Path(cfg.out_dir)
         self.out_dir.mkdir(parents=True, exist_ok=True)
         self.ckpt_dir = self.out_dir / 'ckpt'
         self._ckptr = ckpt.AsyncCheckpointer(self.ckpt_dir, cfg.keep_ckpts)
         step_fn = make_train_step(model, opt, capture, taps_fn=taps_fn,
-                                  sched=self.sched, comm=comm, factor=factor,
-                                  kernel=kernel)
+                                  sched=self.sched, comm=comm, factor=factor)
         self.step_fn = jax.jit(step_fn,
                                donate_argnums=(0, 1) if cfg.donate else ())
         self._watchdog = obs_spans.StragglerWatchdog(cfg.straggler_factor)
@@ -246,8 +242,7 @@ class Trainer:
                                                 taps_fn=self.taps_fn,
                                                 sched=self.sched,
                                                 comm=self.comm,
-                                                factor=self.factor,
-                                                kernel=self.kernel)}
+                                                factor=self.factor)}
                 state, meta = ckpt.restore(self.ckpt_dir, latest, template)
                 params, opt_state = state['params'], state['opt_state']
                 start_step = meta.get('next_step', latest)
@@ -257,8 +252,7 @@ class Trainer:
             opt_state = init_opt_state(self.model, self.opt, self.capture,
                                        params, data.batch_at(start_step),
                                        taps_fn=self.taps_fn, sched=self.sched,
-                                       comm=self.comm, factor=self.factor,
-                                       kernel=self.kernel)
+                                       comm=self.comm, factor=self.factor)
 
         # refresh count already in the (possibly restored) state — the
         # cumulative exchanged-bytes estimate below must count only THIS
@@ -405,11 +399,6 @@ class Trainer:
                 (step_b * (step + 1 - start_step)
                  + refresh_b * (rec.get('refreshes', ref_base) - ref_base))
                 / 2 ** 20, 3)
-        if self.kernel is not None:
-            rec['kernel_impl'] = self.kernel.impl
-            tiles = kdispatch.choices_snapshot()
-            if tiles:
-                rec['kernel_tiles'] = tiles
         recorder.emit('step', **rec)
         print(f'[trainer] step {step:6d} loss {loss:.4f} '
               f'({dt*1e3:.0f} ms){sched_line}', flush=True)
@@ -469,7 +458,7 @@ class Trainer:
             return init_opt_state(self.model, self.opt, self.capture, params,
                                   data.batch_at(step), taps_fn=self.taps_fn,
                                   sched=self.sched, comm=self.comm,
-                                  factor=self.factor, kernel=self.kernel)
+                                  factor=self.factor)
 
         opt_state = None
         world_from = world
@@ -526,8 +515,7 @@ class Trainer:
             if w_to not in step_fns:
                 dp = make_dp_step(self.model, self.opt, self.capture, mesh,
                                   taps_fn=self.taps_fn, sched=self.sched,
-                                  comm=self.comm, factor=self.factor,
-                                  kernel=self.kernel)
+                                  comm=self.comm, factor=self.factor)
                 step_fns[w_to] = jax.jit(
                     dp, donate_argnums=(0, 1) if cfg.donate else ())
             step_fn = step_fns[w_to]
@@ -592,17 +580,10 @@ class Trainer:
                             prev_ref = cur_ref
                         if step % cfg.log_every == 0 \
                                 or step == cfg.total_steps - 1:
-                            kfields = {}
-                            if self.kernel is not None:
-                                kfields['kernel_impl'] = self.kernel.impl
-                                tiles = kdispatch.choices_snapshot()
-                                if tiles:
-                                    kfields['kernel_tiles'] = tiles
                             recorder.emit(
                                 'step', step=step, loss=loss,
                                 grad_norm=float(metrics['grad_norm']),
-                                step_time_s=round(dt, 4), **sched_fields,
-                                **kfields)
+                                step_time_s=round(dt, 4), **sched_fields)
                             if cfg.profile:
                                 self._emit_profile(
                                     recorder, step, step_fn,

@@ -1,6 +1,6 @@
 """Bucketed ``precondition_tree`` must be BIT-IDENTICAL (atol=0) to the
 per-layer loop over the ``precondition`` formulas, for every method, on
-mixed-shape trees, scan-stacked leading dims, and the Pallas interpret path.
+mixed-shape trees, scan-stacked leading dims, and the fused route.
 
 This is the contract that lets the optimizers batch same-shape layers into
 one launch without changing a single ulp of the training trajectory.
@@ -13,6 +13,7 @@ import pytest
 from repro.core import bucketing
 from repro.core import kv as kvlib
 from repro.core import precondition as pre
+from repro.kernels import dispatch
 
 GAMMA = 0.03
 
@@ -24,7 +25,7 @@ SHAPES = {
     'head/w': (16, 4),
     'stack/attn/w': (3, 12, 8),   # lax.scan-stacked layers
     'stack/mlp/w': (3, 12, 8),
-    'odd/w': (7, 5),              # non-128-aligned (pallas padding path)
+    'odd/w': (7, 5),              # non-128-aligned (kernel padding path)
 }
 
 
@@ -50,17 +51,15 @@ def _make_tree(seed=0):
 
 
 PER_LAYER = {
-    'eva': lambda g, st, use_pallas: pre.eva_precondition(
-        g, st.a_mean, st.b_mean, GAMMA, use_pallas=use_pallas),
-    'eva_f': lambda g, st, use_pallas: pre.eva_f_precondition(
-        g, st.a_mean, GAMMA, use_pallas=use_pallas),
-    'eva_s': lambda g, st, use_pallas: pre.eva_s_precondition(
-        g, st.a_mean, st.b_mean, GAMMA, use_pallas=use_pallas),
-    'foof': lambda g, st, use_pallas: pre.foof_precondition(
-        g, st.a_outer, GAMMA),
-    'kfac': lambda g, st, use_pallas: pre.kfac_precondition(
+    'eva': lambda g, st: pre.eva_precondition(
+        g, st.a_mean, st.b_mean, GAMMA),
+    'eva_f': lambda g, st: pre.eva_f_precondition(g, st.a_mean, GAMMA),
+    'eva_s': lambda g, st: pre.eva_s_precondition(
+        g, st.a_mean, st.b_mean, GAMMA),
+    'foof': lambda g, st: pre.foof_precondition(g, st.a_outer, GAMMA),
+    'kfac': lambda g, st: pre.kfac_precondition(
         g, st.a_outer, st.b_outer, GAMMA),
-    'shampoo': lambda g, st, use_pallas: pre.shampoo_precondition(
+    'shampoo': lambda g, st: pre.shampoo_precondition(
         g, st.a_outer, st.b_outer, GAMMA),
 }
 
@@ -77,19 +76,43 @@ def _assert_bit_identical(out, ref):
 @pytest.mark.parametrize('method', ALL_METHODS)
 def test_bucketed_matches_per_layer_loop(method):
     grads, aux = _make_tree()
-    ref = {p: PER_LAYER[method](grads[p], aux[p], False) for p in grads}
+    ref = {p: PER_LAYER[method](grads[p], aux[p]) for p in grads}
     out = pre.precondition_tree(grads, aux, method, GAMMA)
     _assert_bit_identical(out, ref)
 
 
 @pytest.mark.parametrize('method', ['eva', 'eva_f', 'eva_s'])
-def test_bucketed_matches_per_layer_loop_pallas(method):
-    """use_pallas=True (interpret on CPU): the grid-folded stacked kernels
-    must match per-path kernel calls bit-for-bit."""
+@pytest.mark.parametrize('min_size', [1, 2, 99])
+def test_bucketed_fused_matches_per_layer_loop(method, min_size):
+    """The fused route (``precondition_tree_fused``) gives per path what one
+    ``dispatch.eva_fused``/``eva_f_fused`` call per path gives, whichever
+    buckets are stacked.  Here that is the jnp twin, whose CPU reductions
+    XLA may order differently for a stack than for one item (1 f32 ulp);
+    the compiled kernels are bit-identical stacked and per item
+    (tests/test_kernels.py)."""
     grads, aux = _make_tree(seed=1)
-    ref = {p: PER_LAYER[method](grads[p], aux[p], True) for p in grads}
-    out = pre.precondition_tree(grads, aux, method, GAMMA, use_pallas=True)
-    _assert_bit_identical(out, ref)
+    fold = method == 'eva'
+    trace = {p: jax.random.normal(jax.random.PRNGKey(i), g.shape)
+             for i, (p, g) in enumerate(sorted(grads.items()))}
+    plan = bucketing.build_plan(grads, min_bucket_size=min_size)
+    out, partials = pre.precondition_tree_fused(
+        grads, aux, method, GAMMA, plan=plan, trace=trace, momentum=0.9,
+        fold_momentum=fold)
+    mu = 0.9 if fold else 0.0
+    for p, g in grads.items():
+        st = aux[p]
+        if method == 'eva_f':
+            o, ax = dispatch.eva_f_fused(g, st.a_mean, GAMMA, trace[p], mu,
+                                         fold_momentum=fold)
+        else:
+            o, ax = dispatch.eva_fused(g, st.a_mean, st.b_mean, GAMMA,
+                                       trace[p], mu, fold_momentum=fold)
+        np.testing.assert_allclose(np.asarray(out[p]), np.asarray(o),
+                                   rtol=1e-6, atol=1e-5, err_msg=p)
+        np.testing.assert_allclose(
+            np.asarray(partials[p]),
+            np.asarray(ax.reshape(-1, 3).sum(axis=0)), rtol=1e-6,
+            err_msg=p)
 
 
 @pytest.mark.parametrize('method', ['eva', 'kfac'])
@@ -126,7 +149,7 @@ def test_dtype_segregation():
     out = pre.precondition_tree(grads, aux, 'eva', GAMMA, plan=plan)
     assert out['a/w'].dtype == jnp.float32
     assert out['b/w'].dtype == jnp.bfloat16
-    ref = {p: PER_LAYER['eva'](grads[p], aux[p], False) for p in grads}
+    ref = {p: PER_LAYER['eva'](grads[p], aux[p]) for p in grads}
     _assert_bit_identical(out, ref)
 
 
@@ -198,7 +221,7 @@ def test_min_bucket_size_output_bit_identical(method, min_size):
                                       aux[p].b_outer) for p in grads}
     else:
         out = pre.precondition_tree(grads, aux, method, GAMMA, plan=plan)
-        ref = {p: PER_LAYER[method](grads[p], aux[p], False) for p in grads}
+        ref = {p: PER_LAYER[method](grads[p], aux[p]) for p in grads}
     _assert_bit_identical(out, ref)
 
 
@@ -212,7 +235,7 @@ def test_min_bucket_size_lapack_methods_allclose(min_size, method):
     optimizers themselves only use the *_cached application, which is
     exact — see test_min_bucket_size_output_bit_identical.)"""
     grads, aux = _make_tree(seed=7)
-    ref = {p: PER_LAYER[method](grads[p], aux[p], False) for p in grads}
+    ref = {p: PER_LAYER[method](grads[p], aux[p]) for p in grads}
     plan = bucketing.build_plan(grads, min_bucket_size=min_size)
     out = pre.precondition_tree(grads, aux, method, GAMMA, plan=plan)
     for p in ref:
@@ -227,7 +250,7 @@ def test_min_bucket_size_with_bucketed_state_aux():
     plan = bucketing.build_plan(grads, min_bucket_size=99)  # all unstacked
     aux_b = bucketing.gather_tree(plan, aux)  # state layout: always stacked
     out = pre.precondition_tree(grads, aux_b, 'eva', GAMMA, plan=plan)
-    ref = {p: PER_LAYER['eva'](grads[p], aux[p], False) for p in grads}
+    ref = {p: PER_LAYER['eva'](grads[p], aux[p]) for p in grads}
     _assert_bit_identical(out, ref)
 
 

@@ -95,6 +95,21 @@ def test_subslice_ownership_partitions_rows():
     assert not any(k.endswith('/in') for k in desc)
 
 
+@pytest.mark.parametrize('shape', [(64, 48), (200, 136), (512, 384)])
+@pytest.mark.parametrize('world', [1, 2, 4])
+def test_matvec_partials_sum_to_matmul(shape, world):
+    """Band partials over W row bands sum to the full Y @ M (zero-pad rows
+    of the last band contribute zero) — the factor-sharding invariant."""
+    m, n = shape
+    ks = jax.random.split(jax.random.PRNGKey(3), 2)
+    mat = jax.random.normal(ks[0], (m, n), jnp.float32)
+    y = jax.random.normal(ks[1], (5, m), jnp.float32)
+    total = sum(fsh._matvec_partial(fsh._band(mat, world, w), y, world, w)
+                for w in range(world))
+    np.testing.assert_allclose(np.asarray(total), np.asarray(y @ mat),
+                               atol=1e-4 * m ** 0.5, rtol=1e-4)
+
+
 # ---------------------------------------------------------------------------
 # Optimizer-level equivalence (single device; the solve's psum is a no-op)
 

@@ -1,233 +1,102 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (deliverable c)."""
+"""The fused Eva Pallas kernels (``kernels/fused.py``) in interpret mode
+against their jnp twins (``kernels/ref.py``): shape × dtype sweeps and the
+tile-boundary grids."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import precondition as pre
-from repro.kernels import ops, ref
-from repro.kernels.bilinear import bilinear
-from repro.kernels.matvec import matvec
-from repro.kernels.rank1_update import rank1_update
+from repro.kernels import fused, ref
 
+GAMMA = 0.03
+MU = 0.9
 SHAPES = [(8, 8), (64, 48), (128, 128), (200, 136), (512, 384), (1000, 513)]
 DTYPES = [jnp.float32, jnp.bfloat16]
+KERNELS = ['eva', 'eva_f']
 
 
-def _mk(shape, dtype, key=0):
-    ks = jax.random.split(jax.random.PRNGKey(key), 3)
-    g = jax.random.normal(ks[0], shape, jnp.float32).astype(dtype)
-    a = jax.random.normal(ks[1], (shape[0],), jnp.float32).astype(dtype)
-    b = jax.random.normal(ks[2], (shape[1],), jnp.float32).astype(dtype)
-    return g, a, b
+def _mk(L, shape, dtype=jnp.float32, key=0):
+    ks = jax.random.split(jax.random.PRNGKey(key), 4)
+    g = jax.random.normal(ks[0], (L,) + shape, jnp.float32).astype(dtype)
+    a = jax.random.normal(ks[1], (L, shape[0]), jnp.float32).astype(dtype)
+    b = jax.random.normal(ks[2], (L, shape[1]), jnp.float32).astype(dtype)
+    m = jax.random.normal(ks[3], (L,) + shape, jnp.float32)
+    return g, a, b, m
+
+
+def _kernel(kind, g, a, b, m, fold=True, block=128):
+    kw = dict(fold_momentum=fold, block_in=block, block_out=block)
+    if kind == 'eva':
+        return fused.eva_fused_stacked(g, a, b, GAMMA, m, MU, **kw)
+    return fused.eva_f_fused_stacked(g, a, GAMMA, m, MU, **kw)
+
+
+def _twin(kind, g, a, b, m, fold=True):
+    if kind == 'eva':
+        return ref.eva_fused_ref(g, a, b, GAMMA, m, MU, fold_momentum=fold)
+    return ref.eva_f_fused_ref(g, a, GAMMA, m, MU, fold_momentum=fold)
+
+
+def _assert_close(got, want):
+    (out, aux), (r_out, r_aux) = got, want
+    np.testing.assert_allclose(np.asarray(out), np.asarray(r_out),
+                               atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(aux), np.asarray(r_aux),
+                               rtol=2e-5, atol=1e-4)
 
 
 @pytest.mark.parametrize('shape', SHAPES)
 @pytest.mark.parametrize('dtype', DTYPES)
-def test_rank1_update(shape, dtype):
-    g, a, b = _mk(shape, dtype)
-    out = rank1_update(g, a, b, jnp.float32(0.37), jnp.float32(2.5),
-                       block_in=128, block_out=128)
-    want = ref.rank1_update_ref(g, a, b, 0.37, 2.5)
-    tol = 1e-5 if dtype == jnp.float32 else 3e-2
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(want, np.float32), atol=tol, rtol=tol)
-
-
-@pytest.mark.parametrize('shape', SHAPES)
-@pytest.mark.parametrize('dtype', DTYPES)
-def test_matvec(shape, dtype):
-    g, a, _ = _mk(shape, dtype)
-    out = matvec(g, a, block_in=128, block_out=128)
-    want = ref.matvec_ref(g, a)
-    tol = 1e-4 if dtype == jnp.float32 else 2e-1
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                               atol=tol * shape[0] ** 0.5, rtol=tol)
-
-
-@pytest.mark.parametrize('shape', SHAPES)
-@pytest.mark.parametrize('dtype', DTYPES)
-def test_bilinear(shape, dtype):
-    g, a, b = _mk(shape, dtype)
-    out = bilinear(g, a, b, block_in=128, block_out=128)
-    want = ref.bilinear_ref(g, a, b)
-    tol = 1e-4 if dtype == jnp.float32 else 2e-1
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                               atol=tol * (shape[0] * shape[1]) ** 0.5, rtol=tol)
-
-
-@pytest.mark.parametrize('shape', [(64, 48), (256, 200)])
-def test_fused_eva_matches_core_math(shape):
-    """ops.eva_precondition (pallas) == precondition.eva_precondition (jnp)."""
-    g, a, b = _mk(shape, jnp.float32)
-    out = ops.eva_precondition(g, a, b, gamma=0.03)
-    want = pre.eva_precondition(g, a, b, gamma=0.03)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                               atol=1e-4, rtol=1e-4)
-
-
-@pytest.mark.parametrize('shape', [(64, 48), (256, 200)])
-def test_fused_eva_f_matches_core_math(shape):
-    g, a, _ = _mk(shape, jnp.float32)
-    out = ops.eva_f_precondition(g, a, gamma=0.03)
-    want = pre.eva_f_precondition(g, a, gamma=0.03)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                               atol=1e-4, rtol=1e-4)
-
-
-def test_stacked_vmap():
-    """Leading layer/expert stack dims fold through vmap."""
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    g = jax.random.normal(ks[0], (3, 2, 64, 48))
-    a = jax.random.normal(ks[1], (3, 2, 64))
-    b = jax.random.normal(ks[2], (3, 2, 48))
-    out = ops.eva_precondition(g, a, b, gamma=0.1)
-    want = pre.eva_precondition(g, a, b, gamma=0.1)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                               atol=1e-4, rtol=1e-4)
-
-
-@pytest.mark.parametrize('shape', [(64, 48), (200, 136), (512, 384)])
-@pytest.mark.parametrize('world', [1, 2, 4])
-def test_matvec_cols_partials_sum_to_matmul(shape, world):
-    """Band partials over W row bands sum to the full A @ G (zero-pad rows
-    of the last band contribute zero) — the factor-sharding invariant."""
-    from repro.kernels.matvec import matvec_cols
-
-    m, n = shape
-    ks = jax.random.split(jax.random.PRNGKey(3), 2)
-    g = jax.random.normal(ks[0], (m, n), jnp.float32)
-    a = jax.random.normal(ks[1], (5, m), jnp.float32)
-    blk = -(-m // world)
-    gp = jnp.pad(g, ((0, world * blk - m), (0, 0)))
-    ap = jnp.pad(a, ((0, 0), (0, world * blk - m)))
-    total = sum(matvec_cols(gp[w * blk:(w + 1) * blk],
-                            ap[:, w * blk:(w + 1) * blk],
-                            block_in=128, block_out=128)
-                for w in range(world))
-    want = a @ g
-    np.testing.assert_allclose(np.asarray(total), np.asarray(want),
-                               atol=1e-4 * m ** 0.5, rtol=1e-4)
-
-
-def test_matvec_cols_stacked_matches_per_item():
-    """The bucket-stacked variant equals per-factor matvec_cols calls."""
-    from repro.kernels.matvec import matvec_cols, matvec_cols_stacked
-
-    ks = jax.random.split(jax.random.PRNGKey(4), 2)
-    g = jax.random.normal(ks[0], (3, 100, 136), jnp.float32)
-    a = jax.random.normal(ks[1], (3, 4, 100), jnp.float32)
-    out = matvec_cols_stacked(g, a, block_in=64, block_out=64)
-    for l in range(3):
-        one = matvec_cols(g[l], a[l], block_in=64, block_out=64)
-        np.testing.assert_array_equal(np.asarray(out[l]), np.asarray(one))
+@pytest.mark.parametrize('fold', [False, True])
+@pytest.mark.parametrize('kind', KERNELS)
+def test_fused_matches_ref_twin(kind, fold, dtype, shape):
+    """Each fused kernel, momentum folded and not, as an L=1 stack."""
+    g, a, b, m = _mk(1, shape, dtype)
+    _assert_close(_kernel(kind, g, a, b, m, fold),
+                  _twin(kind, g, a, b, m, fold))
 
 
 # ---------------------------------------------------------------------------
 # tile-boundary edge cases: non-divisible dims + stack depths.  The hard
 # guarantee is stacked ≡ per-item (identical tile programs, bit-exact);
-# agreement vs the einsum refs is tight-tolerance — XLA contracts the
+# agreement with the einsum twins is tight-tolerance — XLA contracts the
 # broadcast formulas with different FMA/reduction order, so bit-identity
-# vs ref.py does not hold even for single-tile launches.
+# with ref.py does not hold even for single-tile launches.
 
 ODD_SHAPES = [(7, 5), (65, 33), (129, 127)]
 BLOCKS = [32, 512]  # multi-tile with padding remainder / single padded tile
 
 
-def _mk_stacked(L, shape, key=7):
-    ks = jax.random.split(jax.random.PRNGKey(key), 3)
-    g = jax.random.normal(ks[0], (L,) + shape, jnp.float32)
-    a = jax.random.normal(ks[1], (L, shape[0]), jnp.float32)
-    b = jax.random.normal(ks[2], (L, shape[1]), jnp.float32)
-    return g, a, b
-
-
 @pytest.mark.parametrize('shape', ODD_SHAPES)
 @pytest.mark.parametrize('block', BLOCKS)
-def test_tile_boundary_vs_ref(shape, block):
-    g, a, b = _mk(shape, jnp.float32, key=11)
-    np.testing.assert_allclose(
-        np.asarray(bilinear(g, a, b, block_in=block, block_out=block)),
-        np.asarray(ref.bilinear_ref(g, a, b)),
-        atol=1e-4 * (shape[0] * shape[1]) ** 0.5, rtol=1e-5)
-    np.testing.assert_allclose(
-        np.asarray(matvec(g, a, block_in=block, block_out=block)),
-        np.asarray(ref.matvec_ref(g, a)),
-        atol=1e-4 * shape[0] ** 0.5, rtol=1e-5)
-    np.testing.assert_allclose(
-        np.asarray(rank1_update(g, a, b, jnp.float32(0.37), jnp.float32(2.5),
-                                block_in=block, block_out=block)),
-        np.asarray(ref.rank1_update_ref(g, a, b, 0.37, 2.5)),
-        atol=1e-5, rtol=1e-5)
+@pytest.mark.parametrize('kind', KERNELS)
+def test_tile_boundary_vs_ref(kind, block, shape):
+    g, a, b, m = _mk(1, shape, key=11)
+    _assert_close(_kernel(kind, g, a, b, m, block=block),
+                  _twin(kind, g, a, b, m))
 
 
 @pytest.mark.parametrize('shape', ODD_SHAPES)
 @pytest.mark.parametrize('block', BLOCKS)
 @pytest.mark.parametrize('L', [1, 3])
-def test_tile_boundary_stacked_bit_identical_to_per_item(shape, block, L):
-    from repro.kernels.bilinear import bilinear_stacked
-    from repro.kernels.matvec import matvec_stacked
-    from repro.kernels.rank1_update import rank1_update_stacked
-
-    g, a, b = _mk_stacked(L, shape)
-    coeff = jnp.linspace(0.1, 0.9, L)
-    scale = jnp.linspace(1.5, 2.5, L)
-    dot_s = bilinear_stacked(g, a, b, block_in=block, block_out=block)
-    mv_s = matvec_stacked(g, a, block_in=block, block_out=block)
-    r1_s = rank1_update_stacked(g, a, b, coeff, scale,
-                                block_in=block, block_out=block)
+@pytest.mark.parametrize('kind', KERNELS)
+def test_tile_boundary_stacked_bit_identical_to_per_item(kind, L, block,
+                                                         shape):
+    g, a, b, m = _mk(L, shape, key=7)
+    out, aux = _kernel(kind, g, a, b, m, block=block)
     for l in range(L):
-        np.testing.assert_array_equal(
-            np.asarray(dot_s[l]),
-            np.asarray(bilinear(g[l], a[l], b[l],
-                                block_in=block, block_out=block)))
-        np.testing.assert_array_equal(
-            np.asarray(mv_s[l]),
-            np.asarray(matvec(g[l], a[l],
-                              block_in=block, block_out=block)))
-        np.testing.assert_array_equal(
-            np.asarray(r1_s[l]),
-            np.asarray(rank1_update(g[l], a[l], b[l], coeff[l], scale[l],
-                                    block_in=block, block_out=block)))
+        s = slice(l, l + 1)
+        one_out, one_aux = _kernel(kind, g[s], a[s], b[s], m[s], block=block)
+        np.testing.assert_array_equal(np.asarray(out[s]), np.asarray(one_out))
+        np.testing.assert_array_equal(np.asarray(aux[s]), np.asarray(one_aux))
 
 
 @pytest.mark.parametrize('shape', ODD_SHAPES)
-def test_tile_boundary_block_size_invariance(shape):
-    """Padding remainder tiles must not leak into the result: the same op
-    at block 32 vs one padded tile agrees to f32 reduction order."""
-    g, a, b = _mk(shape, jnp.float32, key=13)
-    np.testing.assert_allclose(
-        np.asarray(bilinear(g, a, b, block_in=32, block_out=32)),
-        np.asarray(bilinear(g, a, b, block_in=512, block_out=512)),
-        atol=1e-4 * (shape[0] * shape[1]) ** 0.5, rtol=1e-5)
-    np.testing.assert_allclose(
-        np.asarray(matvec(g, a, block_in=32, block_out=32)),
-        np.asarray(matvec(g, a, block_in=512, block_out=512)),
-        atol=1e-4 * shape[0] ** 0.5, rtol=1e-5)
-    # rank1 is elementwise: tile layout cannot change any element
-    np.testing.assert_array_equal(
-        np.asarray(rank1_update(g, a, b, jnp.float32(0.37), jnp.float32(2.5),
-                                block_in=32, block_out=32)),
-        np.asarray(rank1_update(g, a, b, jnp.float32(0.37), jnp.float32(2.5),
-                                block_in=512, block_out=512)))
-
-
-def test_optimizer_use_pallas_flag():
-    """eva(use_pallas=True) == eva(use_pallas=False) end-to-end."""
-    from repro.core import kv as kvlib
-    from repro.core.eva import eva
-    from repro.core.transform import Extras
-
-    params = {'lin': {'w': jax.random.normal(jax.random.PRNGKey(0), (32, 16))}}
-    grads = {'lin': {'w': jax.random.normal(jax.random.PRNGKey(1), (32, 16))}}
-    stats = {'lin/w': kvlib.LayerStats(
-        a_mean=jax.random.normal(jax.random.PRNGKey(2), (32,)),
-        b_mean=jax.random.normal(jax.random.PRNGKey(3), (16,)))}
-    outs = []
-    for flag in (False, True):
-        opt = eva(lr=0.1, use_pallas=flag)
-        state = opt.init(params, Extras(stats=stats))
-        upd, _ = opt.update(grads, state, params=params, extras=Extras(stats=stats))
-        outs.append(upd['lin']['w'])
-    np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(outs[1]),
-                               atol=1e-5, rtol=1e-5)
+@pytest.mark.parametrize('kind', KERNELS)
+def test_tile_boundary_block_size_invariance(kind, shape):
+    """Padding remainder tiles must not leak into the result: the same
+    kernel at block 32 and as one padded tile agrees to f32 reduction
+    order."""
+    g, a, b, m = _mk(1, shape, key=13)
+    _assert_close(_kernel(kind, g, a, b, m, block=32),
+                  _kernel(kind, g, a, b, m, block=512))

@@ -1,4 +1,4 @@
-"""Compile rehearsal for TPU v5e: the Pallas kernels and the Eva train steps
+"""Compile rehearsal for TPU v5e: the fused Eva kernels and the Eva train steps
 of qwen2-0.5b and glm4-9b-l4v8, compiled by the TPU compiler for a
 described (not attached) v5e:2x2 topology.  Nothing runs; a compile that passes is not a chip run.
 
@@ -18,10 +18,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import bilinear as kbil
 from repro.kernels import fused as kfused
-from repro.kernels import matvec as kmv
-from repro.kernels import rank1_update as kr1
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -79,14 +76,6 @@ def _kernel_call(op, L, m, n, dtype, sh, block=512):
     a, b = _spec(sh, (L, m)), _spec(sh, (L, n))
     mom = _spec(sh, (L, m, n))
     calls = {
-        'bilinear_stacked': (lambda g, a, b: kbil.bilinear_stacked(
-            g, a, b, **kw), (g, a, b)),
-        'matvec_stacked': (lambda g, a: kmv.matvec_stacked(g, a, **kw),
-                           (g, a)),
-        'matvec_cols_stacked': (lambda g, c: kmv.matvec_cols_stacked(
-            g, c, **kw), (g, _spec(sh, (L, 4, m)))),
-        'rank1_update_stacked': (lambda g, a, b, c, s: kr1.rank1_update_stacked(
-            g, a, b, c, s, **kw), (g, a, b, _spec(sh, (L,)), _spec(sh, (L,)))),
         'eva_fused_stacked': (lambda g, a, b, m_: kfused.eva_fused_stacked(
             g, a, b, 0.03, m_, 0.9, **kw), (g, a, b, mom)),
         'eva_f_fused_stacked': (lambda g, a, m_: kfused.eva_f_fused_stacked(
@@ -96,8 +85,7 @@ def _kernel_call(op, L, m, n, dtype, sh, block=512):
     return jax.jit(fn).lower(*args).compile()
 
 
-KERNELS = ['bilinear_stacked', 'matvec_stacked', 'matvec_cols_stacked',
-           'rank1_update_stacked', 'eva_fused_stacked', 'eva_f_fused_stacked']
+KERNELS = ['eva_fused_stacked', 'eva_f_fused_stacked']
 
 
 def test_bucket_shapes_are_qwen2s():
@@ -109,13 +97,13 @@ def test_bucket_shapes_are_qwen2s():
 def test_kernel_compiles_at_qwen2_shape(op, shape, one_chip):
     compiled = _kernel_call(op, *shape, jnp.bfloat16, one_chip)
     assert 'tpu_custom_call' in compiled.as_text()
+    assert op in _kernel_names(compiled)
 
 
-@pytest.mark.parametrize('op', ['eva_fused_stacked', 'eva_f_fused_stacked',
-                                'rank1_update_stacked'])
+@pytest.mark.parametrize('op', KERNELS)
 def test_largest_tuned_tile_fits_vmem(op, one_chip):
-    """The autotuner's largest candidate (512x512) in f32, the widest
-    operands: Mosaic refuses a kernel whose buffers exceed VMEM."""
+    """The largest tile the 512 request can give (512x512) in f32, the
+    widest operands: Mosaic refuses a kernel whose buffers exceed VMEM."""
     compiled = _kernel_call(op, 2, 1024, 1024, jnp.float32, one_chip)
     assert 'tpu_custom_call' in compiled.as_text()
 
@@ -147,16 +135,15 @@ def _compile_train_step(arch, batch_size, one_chip, monkeypatch, fused):
     model = build_model(arch)
     params = jax.eval_shape(lambda: M.init_params(model.param_specs(),
                                                   jax.random.PRNGKey(0)))
-    kernel = dispatch.KernelConfig(impl='auto') if fused else None
     opt, capture = make_optimizer('eva', lr=0.05, fused=fused)
     tok = jax.ShapeDtypeStruct((batch_size, 2048), jnp.int32)
     batch = {'tokens': tok, 'labels': tok}
     sched = RefreshRuntime()
     state = abstract_opt_state(model, opt, capture, params, batch,
-                               sched=sched, kernel=kernel)
+                               sched=sched)
     on_chip = lambda t: jax.tree_util.tree_map(
         lambda x: _spec(one_chip, x.shape, x.dtype), t)
-    step = make_train_step(model, opt, capture, sched=sched, kernel=kernel)
+    step = make_train_step(model, opt, capture, sched=sched)
     return jax.jit(step, donate_argnums=(0, 1)).lower(
         on_chip(params), on_chip(state), on_chip(batch)).compile()
 
