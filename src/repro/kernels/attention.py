@@ -20,6 +20,11 @@ accumulation; the 1/√D scale multiplies the f32 scores; max, sums,
 exponentials and the log-sum-exp are f32; P and dS enter their matmuls in
 the inputs' dtype.  The log-sum-exp (the forward's residual) and
 rowsum(dO·O) travel as (B, H, 1, S) f32 rows.
+
+The forward's output and log-sum-exp leave the custom VJP's forward under
+the ``checkpoint_name``s in ``RESIDUAL_NAMES``, so a ``remat`` policy that
+saves those names (the models' ``'dots'``) keeps them for the backward
+instead of launching ``flash_fwd`` a second time to rebuild them.
 """
 from __future__ import annotations
 
@@ -29,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -38,6 +44,7 @@ FIRST, LAST, MASKED = 1, 2, 4          # tile flags
 NN = (((1,), (0,)), ((), ()))          # x @ y
 NT = (((1,), (1,)), ((), ()))          # x @ yᵀ
 VMEM_LIMIT = 96 * 2 ** 20    # 16 heads of 128 outgrow the 16 MiB default
+RESIDUAL_NAMES = ('flash_out', 'flash_lse')   # o, lse as remat sees them
 
 
 def tile_tables(n_q: int, n_k: int, block_q: int, block_k: int,
@@ -293,6 +300,8 @@ def flash_attention(q, k, v, causal: bool, block_q: int, block_k: int,
 
 def _vjp_fwd(q, k, v, causal, block_q, block_k, interpret):
     o, lse = _forward(q, k, v, causal, block_q, block_k, interpret)
+    o_name, lse_name = RESIDUAL_NAMES
+    o, lse = checkpoint_name(o, o_name), checkpoint_name(lse, lse_name)
     return o, (q, k, v, o, lse)
 
 

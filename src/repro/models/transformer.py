@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.core import kv as kvlib
+from repro.kernels import attention as kattention
 from repro.models import module as M
 from repro.models.attention import attention_block, attention_spec
 from repro.models.layers import embed, embed_spec, linear, linear_spec, make_norm, mlp, mlp_spec
@@ -29,10 +30,21 @@ from repro.sharding.constraints import shard_activations
 
 
 def _remat_policy(name: str):
+    """The ``jax.checkpoint`` policy of ``cfg.remat``: ``'full'`` saves
+    nothing; ``'dots'`` saves what the MXU produced, the matmul outputs and
+    the attention kernel's output and log-sum-exp
+    (``kernels.attention.RESIDUAL_NAMES``), whose only rebuild is a second
+    ``flash_fwd`` launch; ``'none'`` (None) does not checkpoint.  The
+    pure-JAX attention path (``models/flash.py``: the CPU, and the TPU
+    where the kernel cannot run) names no residual, so there ``'dots'``
+    keeps recomputing its forward and spends no memory on it."""
+    cp = jax.checkpoint_policies
     if name == 'full':
-        return jax.checkpoint_policies.nothing_saveable
+        return cp.nothing_saveable
     if name == 'dots':
-        return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+        return cp.save_from_both_policies(
+            cp.dots_with_no_batch_dims_saveable,
+            cp.save_only_these_names(*kattention.RESIDUAL_NAMES))
     return None
 
 

@@ -1,15 +1,17 @@
 """The Pallas attention kernel (``kernels/attention.py``), run in interpret
 mode on the CPU against the pure-JAX ``flash_attention_xla`` on outputs and
-on dq, dk, dv; its tile tables; and the rule by which ``flash_attention``
-picks it."""
+on dq, dk, dv; its tile tables; the forward residuals the models' ``remat``
+policy keeps; and the rule by which ``flash_attention`` picks it."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend import core as jcore
 
 from repro.kernels import attention as kattention
 from repro.kernels import dispatch
 from repro.models import flash
+from repro.models.transformer import _remat_policy
 
 # (batch, S, heads, kv heads, head_dim, block): qwen2-0.5b's GQA 14/2 at
 # head_dim 64 and glm4-9b's 32/2 at head_dim 128
@@ -56,6 +58,61 @@ def test_kernel_in_bf16_rounds_p_and_ds_once():
         assert got.dtype == jnp.bfloat16
         err = jnp.max(jnp.abs(got.astype(jnp.float32) - want))
         assert float(err) <= 2e-2 * float(jnp.max(jnp.abs(want)))
+
+
+def _kernel_calls(jaxpr) -> list:
+    """Names of the ``pallas_call``s in a jaxpr and its sub-jaxprs, in
+    order."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == 'pallas_call':
+            names.append(eqn.params['name'])
+            continue
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (list, tuple)) else (p,):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    names += _kernel_calls(sub.jaxpr)
+                elif isinstance(sub, jcore.Jaxpr):
+                    names += _kernel_calls(sub)
+    return names
+
+
+def _remat_grad(policy):
+    """Gradient of a checkpointed kernel call (GQA 4/2, S 256, blocks of
+    128) under the models' ``remat`` policy ``policy``."""
+    f = lambda q, k, v: jnp.sum(jnp.sin(kattention.flash_attention(
+        q, k, v, True, 128, 128, True).astype(jnp.float32)))
+    return jax.grad(jax.checkpoint(f, policy=_remat_policy(policy)),
+                    argnums=(0, 1, 2))
+
+
+def _remat_inputs():
+    ks = jax.random.split(jax.random.PRNGKey(1), 3)
+    return (jax.random.normal(ks[0], (1, 4, 256, 64), jnp.bfloat16),
+            jax.random.normal(ks[1], (1, 2, 256, 64), jnp.bfloat16),
+            jax.random.normal(ks[2], (1, 2, 256, 64), jnp.bfloat16))
+
+
+@pytest.mark.parametrize('policy,forwards', [('dots', 1), ('full', 2)])
+def test_remat_policy_keeps_the_kernels_residuals(policy, forwards):
+    """``'dots'`` saves o and lse under ``RESIDUAL_NAMES``, so the backward
+    launches no second ``flash_fwd``; ``'full'`` saves nothing and rebuilds
+    them with one."""
+    jaxpr = jax.make_jaxpr(_remat_grad(policy))(*_remat_inputs()).jaxpr
+    calls = _kernel_calls(jaxpr)
+    assert calls.count('flash_fwd') == forwards, calls
+    assert calls.count('flash_dq') == calls.count('flash_dkv') == 1, calls
+
+
+def test_saved_residuals_give_the_recomputed_gradients():
+    """Saving o and lse hands the backward the values the recompute
+    would: dq, dk and dv are bitwise those of ``'full'``."""
+    inputs = _remat_inputs()
+    for got, want in zip(_remat_grad('dots')(*inputs),
+                         _remat_grad('full')(*inputs)):
+        assert got.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
 
 
 @pytest.mark.parametrize('by', ['q', 'k'])

@@ -8,6 +8,7 @@ runs this file loads the TPU library.  Keep every such compile in this one
 file (a second file could land on another worker, where its fixture would
 skip in silence).
 """
+import collections
 import importlib.util
 import os
 import re
@@ -111,12 +112,17 @@ def test_largest_tuned_tile_fits_vmem(op, one_chip):
 ATTENTION_KERNELS = ('flash_fwd', 'flash_dq', 'flash_dkv')
 
 
-def _kernel_names(compiled) -> set:
-    """Names of the custom calls in a compiled program, without the
-    ``.<n>`` the compiler appends (the profiler's trace names ops so)."""
+def _kernel_counts(compiled) -> collections.Counter:
+    """How often each custom call appears in a compiled program, by name
+    without the ``.<n>`` the compiler appends (the profiler's trace names
+    ops so)."""
     names = re.findall(r'%([\w.-]+) = [^\n]*custom-call\(',
                        compiled.as_text())
-    return {re.sub(r'\.\d+$', '', n) for n in names}
+    return collections.Counter(re.sub(r'\.\d+$', '', n) for n in names)
+
+
+def _kernel_names(compiled) -> set:
+    return set(_kernel_counts(compiled))
 
 
 def _compile_train_step(arch, batch_size, one_chip, monkeypatch, fused):
@@ -151,9 +157,13 @@ def _compile_train_step(arch, batch_size, one_chip, monkeypatch, fused):
 def _check_step(compiled, fused):
     peak = compiled.memory_analysis().peak_memory_in_bytes
     assert peak <= HBM_BYTES, f'{peak / 2**30:.2f} GiB > 15.75 GiB'
-    names = _kernel_names(compiled)
+    counts = _kernel_counts(compiled)
+    names = set(counts)
     assert set(ATTENTION_KERNELS) <= names, names
     assert ('eva_fused_stacked' in names) == fused, names
+    # remat='dots' keeps the forward's o and lse: the backward's layer scan
+    # launches no second flash_fwd
+    assert counts['flash_fwd'] == 1, counts
 
 
 @pytest.mark.parametrize('path', ['default', 'fused'])
